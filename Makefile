@@ -1,12 +1,13 @@
 # Developer entry points (ref: the reference repo's makefile test/coverage
-# targets). Everything runs on the virtual CPU mesh unless noted.
+# targets). Everything runs on the virtual CPU mesh unless noted; the
+# targets marked "on the chip" exit non-zero without a TPU.
 
 PY ?= python
 
-.PHONY: test test-all test-slow bench dryrun smoke queue fit-overhead \
-	telemetry-smoke analysis lint verify-plans kernel-audit chaos \
-	serve-smoke perf-gate nsa-needle-smoke plan-cache-smoke \
-	straggler-smoke
+.PHONY: test test-all test-slow bench dryrun smoke chip-smoke \
+	chip-smoke-rehearse fit-overhead telemetry-smoke analysis lint \
+	verify-plans kernel-audit chaos serve-smoke perf-gate \
+	nsa-needle-smoke plan-cache-smoke straggler-smoke
 
 test: analysis chaos serve-smoke plan-cache-smoke straggler-smoke  ## fast tier: the correctness surface in < 5 min on one core
 	$(PY) -m pytest tests/ -x -q -m "not slow"
@@ -29,17 +30,20 @@ kernel-audit:  ## K1-K5 kernel contract audit over the golden config corpus (CPU
 test-slow:  ## only the slow tier (training / 262k-131k oracles / property)
 	$(PY) -m pytest tests/ -q -m slow
 
-bench:  ## the driver's headline benchmark (TPU when reachable)
+chip-smoke:  ## on the chip: the CP training path end to end, one process, last line {"ok": true, "device": ...}
+	$(PY) chip_smoke.py
+
+chip-smoke-rehearse:  ## toy-size CPU rehearsal of chip_smoke.py (4 virtual devices, interpreted kernels; never prints the pass line)
+	$(PY) chip_smoke.py --rehearse-cpu 4
+
+bench:  ## on the chip: the one kernel-level headline number (ROADMAP A0 replaces it)
 	$(PY) bench.py
 
 dryrun:  ## 8-virtual-device multi-chip training-step validation
 	$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
-smoke:  ## kernel correctness on the attached TPU chip
+smoke:  ## on the chip: kernel census, one compile-and-compare case per pallas_call site
 	$(PY) scripts/tpu_smoke.py
-
-queue:  ## background chip-window experiment poller
-	nohup bash scripts/tpu_window_queue.sh > /dev/null 2>&1 & echo "queue pid $$!"
 
 fit-overhead:  ## fit tile_policy.OVERHEAD_ELEMS from recorded sweeps
 	$(PY) scripts/fit_tile_overhead.py

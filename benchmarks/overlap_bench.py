@@ -43,7 +43,6 @@ def main() -> int:
                 flags
                 + f" --xla_force_host_platform_device_count={args.devices}"
             ).strip()
-        os.environ.setdefault("MAGI_ATTENTION_PALLAS_INTERPRET", "1")
 
     import jax
 
@@ -67,6 +66,10 @@ def main() -> int:
     w = jnp.asarray(rng.standard_normal((S, HQ, D)), dtype)
     mesh = Mesh(np.array(jax.devices()[:n]), axis_names=("cp",))
 
+    dev = jax.devices()[0]
+    print(f"device: platform={dev.platform} device_kind={dev.device_kind!r} "
+          f"count={n}" + ("  (CPU mesh: these are not device times)"
+                          if dev.platform == "cpu" else ""))
     print(f"| degree | fwd ms | {'fwd+bwd ms |' if args.backward else ''}")
     print(f"|---|---|{'---|' if args.backward else ''}")
 

@@ -5,8 +5,9 @@ fixed per-device seqlen (cp_benchmark.md:384-404). This environment has ONE
 TPU chip, so that curve cannot be measured; this script produces the honest
 substitute: an analytical projection that combines
 
-- the MEASURED single-chip kernel throughput (``.bench_last_tpu.json``,
-  written by bench.py on real silicon; override with --tflops),
+- the MEASURED single-chip kernel throughput (``--tflops``: the "value"
+  a ``python bench.py`` run on the chip just printed — there is no stored
+  default, a model fed a stale number states it as if it were current),
 - the EXACT planned wire bytes per rank from the comm planner (the same
   plans the runtime executes, ragged tier = zero padding), and
 - a stated ICI bandwidth assumption (v5e: 2 bidirectional 3D-torus links
@@ -21,13 +22,12 @@ Baselines under identical assumptions: ring/allgather CP ships all
 non-local KV regardless of mask; Ulysses all-to-alls q,k,v,o head-sharded
 (cp capped by kv heads).
 
-    python benchmarks/scaling_model.py [--tflops 50] [--write-doc]
+    python benchmarks/scaling_model.py --tflops 50 [--write-doc]
 """
 
 from __future__ import annotations
 
 import argparse
-import json
 import sys
 from pathlib import Path
 
@@ -228,9 +228,9 @@ def baseline_config_row(name, cp, s, hq, hk, d, speeds, ici_gbps):
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--tflops", type=float, default=None,
-                    help="measured single-chip fwd+bwd TFLOP/s (default: "
-                         "read .bench_last_tpu.json)")
+    ap.add_argument("--tflops", type=float, required=True,
+                    help="measured single-chip fwd+bwd TFLOP/s: the value "
+                         "a chip run of bench.py printed")
     ap.add_argument("--ici-gbps", type=float, default=90.0)
     ap.add_argument("--s-dev", type=int, default=8192,
                     help="per-device seqlen (reference grid: 8k on H100)")
@@ -245,18 +245,6 @@ def main() -> int:
 
     kernel_tflops = args.tflops
     source = f"--tflops {args.tflops}"
-    if kernel_tflops is None:
-        cache = ROOT / ".bench_last_tpu.json"
-        if cache.exists():
-            data = json.loads(cache.read_text())
-            kernel_tflops = float(data["value"])
-            source = (
-                f".bench_last_tpu.json ({data.get('backend')}, "
-                f"blocks {data.get('block_q')}x{data.get('block_k')})"
-            )
-        else:
-            kernel_tflops = 10.03
-            source = "docs/tpu_results.md (pre-optimization measurement)"
 
     target = round(0.5 * PEAK, 1)  # FA3-class MFU, the BASELINE north star
     speeds = {"meas": kernel_tflops, "target": target}

@@ -32,7 +32,6 @@ def main() -> int:
         os.environ["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count={args.devices}"
         ).strip()
-    os.environ.setdefault("MAGI_ATTENTION_PALLAS_INTERPRET", "1")
 
     import jax
 

@@ -29,7 +29,6 @@ def main() -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    os.environ.setdefault("MAGI_ATTENTION_PALLAS_INTERPRET", "1")
 
     import jax.numpy as jnp
     import numpy as np

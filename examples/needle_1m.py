@@ -43,7 +43,6 @@ def main() -> int:
     args = ap.parse_args()
 
     if args.smoke:
-        os.environ.setdefault("MAGI_ATTENTION_PALLAS_INTERPRET", "1")
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     import jax

@@ -29,11 +29,12 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("MAGI_ATTENTION_PALLAS_INTERPRET", "1")
 
 import jax
 
-if os.environ.get("MAGI_EXAMPLE_TPU") != "1":
+# the 8-device virtual CPU mesh by default (kernels interpreted); pass
+# --tpu to run on the attached TPUs with the kernels compiled
+if "--tpu" not in sys.argv:
     jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np

@@ -25,21 +25,22 @@ def main() -> int:
     ap.add_argument("--tp", type=int, default=1, help="tensor-parallel size")
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--seqlen", type=int, default=512)
-    ap.add_argument("--cpu", action="store_true", default=True)
+    ap.add_argument("--tpu", action="store_true",
+                    help="run on the attached TPUs (kernels compiled) "
+                         "instead of the virtual CPU mesh")
     args = ap.parse_args()
 
-    if args.cpu:
+    if not args.tpu:
         flags = os.environ.get("XLA_FLAGS", "")
         if "host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 flags
                 + f" --xla_force_host_platform_device_count={args.devices}"
             ).strip()
-        os.environ.setdefault("MAGI_ATTENTION_PALLAS_INTERPRET", "1")
 
     import jax
 
-    if args.cpu:
+    if not args.tpu:
         jax.config.update("jax_platforms", "cpu")
 
     import numpy as np

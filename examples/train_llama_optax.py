@@ -40,15 +40,13 @@ def main() -> int:
     import jax
 
     if not args.tpu:
-        # force CPU without probing the TPU plugin (backend init can hang
-        # when the chip is unreachable)
+        # the virtual CPU mesh: kernels run in the Pallas interpreter
         flags = os.environ.get("XLA_FLAGS", "")
         if "host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 flags
                 + f" --xla_force_host_platform_device_count={args.devices}"
             ).strip()
-        os.environ.setdefault("MAGI_ATTENTION_PALLAS_INTERPRET", "1")
         jax.config.update("jax_platforms", "cpu")
 
     import jax.numpy as jnp

@@ -47,7 +47,6 @@ def main() -> int:
                 flags
                 + f" --xla_force_host_platform_device_count={args.devices}"
             ).strip()
-        os.environ.setdefault("MAGI_ATTENTION_PALLAS_INTERPRET", "1")
         jax.config.update("jax_platforms", "cpu")
 
     import jax.numpy as jnp

@@ -19,7 +19,6 @@ from __future__ import annotations
 import csv
 import datetime
 import os
-import subprocess
 
 HISTORY_DIR = os.path.join(
     os.path.dirname(
@@ -34,10 +33,31 @@ HISTORY_DIR = os.path.join(
 # bwd: dq pass 3 matmuls + dkv pass 4 vs fwd's 2)
 HW_FWD_BWD_RATIO = 4.5 / 3.5
 
-# nominal bf16 peak of the one attached chip (TPU v5 lite), TFLOP/s — the
-# ONE definition every harness's MFU figures use (silicon measures ~105%
-# of it on a 4096^3 matmul: true_rate.csv mm4096)
-PEAK_TFLOPS = 197.0
+# Published peaks of one chip, keyed by the ``device_kind`` JAX reports
+# (source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB
+# of HBM at 819 GB/s). The ONE table every measuring harness divides by; a
+# device that is not in it is an error, never a default.
+DEVICE_PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {"bf16_tflops": 197.0, "hbm_gbps": 819.0, "hbm_gb": 16.0},
+}
+
+
+def peak_tflops(device_kind: str) -> float:
+    """bf16 peak TFLOP/s of the device a measurement just ran on
+    (``jax.devices()[0].device_kind``); raises on an unknown kind."""
+    try:
+        return DEVICE_PEAKS[device_kind]["bf16_tflops"]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device_kind {device_kind!r}: add it to "
+            f"perf_report.DEVICE_PEAKS with its source (known: "
+            f"{sorted(DEVICE_PEAKS)})"
+        ) from None
+
+
+# the v5e figure, for the host-side MODELS of that chip (scaling_model,
+# roofline); anything that measures calls peak_tflops(device_kind) instead
+PEAK_TFLOPS = DEVICE_PEAKS["TPU v5 lite"]["bf16_tflops"]
 
 # silicon-MEASURED matmul ceiling of the attached chip (true_rate.csv
 # mm4096 slope: 207.98 TF/s ≈ 105.6% of nominal) — the ONE anchor for
@@ -59,68 +79,52 @@ def credible_floor_ms(
     return flops / (ceiling_tflops * 1e9)
 
 
-def _git_rev() -> str:
-    try:
-        return subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"],
-            capture_output=True, text=True, timeout=10,
-            cwd=os.path.dirname(HISTORY_DIR),
-        ).stdout.strip() or "unknown"
-    except Exception:
-        return "unknown"
-
-
 def append_row(name: str, row: dict) -> str:
     """Append one measurement to ``benchmarks/history/<name>.csv``.
 
-    Adds ``utc`` and ``commit`` columns automatically. The header is the
-    union of all keys ever seen for this file (the file is rewritten with
-    an extended header when a new key appears — files are small).
-    Never raises: history is best-effort and must not cost a measurement.
+    Adds the ``utc`` column; ``commit`` is the caller's to pass in ``row``
+    (a chip run's copy of the tree is not a git repository) and reads
+    ``unknown`` otherwise. The header is the union of all keys ever seen
+    for this file (the file is rewritten with an extended header when a
+    new key appears — files are small).
     """
-    try:
-        os.makedirs(HISTORY_DIR, exist_ok=True)
-        path = os.path.join(HISTORY_DIR, f"{name}.csv")
-        full = {
-            "utc": datetime.datetime.now(datetime.timezone.utc).strftime(
-                "%Y-%m-%d %H:%M:%S"
-            ),
-            "commit": _git_rev(),
-            **row,
-        }
-        # MAGI_ATTENTION_TELEMETRY=1: stamp the row with the run's comm /
-        # balance context (tel_* columns) so a perf number carries the plan
-        # that produced it. Empty dict (no extra columns) when off.
-        from .. import telemetry
+    os.makedirs(HISTORY_DIR, exist_ok=True)
+    path = os.path.join(HISTORY_DIR, f"{name}.csv")
+    full = {
+        "utc": datetime.datetime.now(datetime.timezone.utc).strftime(
+            "%Y-%m-%d %H:%M:%S"
+        ),
+        "commit": "unknown",
+        **row,
+    }
+    # MAGI_ATTENTION_TELEMETRY=1: stamp the row with the run's comm /
+    # balance context (tel_* columns) so a perf number carries the plan
+    # that produced it. Empty dict (no extra columns) when off.
+    from .. import telemetry
 
-        full.update(
-            {k: v for k, v in telemetry.flat_summary().items()
-             if k not in full}
-        )
-        rows: list[dict] = []
-        header: list[str] = []
-        if os.path.exists(path):
-            with open(path, newline="") as f:
-                reader = csv.DictReader(f)
-                header = list(reader.fieldnames or [])
-                rows = list(reader)
-        new_keys = [k for k in full if k not in header]
-        if new_keys:
-            header = header + new_keys
-            with open(path, "w", newline="") as f:
-                w = csv.DictWriter(f, fieldnames=header, restval="")
-                w.writeheader()
-                for r in rows:
-                    w.writerow(r)
-                w.writerow(full)
-        else:
-            with open(path, "a", newline="") as f:
-                csv.DictWriter(f, fieldnames=header, restval="").writerow(
-                    full
-                )
-        return path
-    except Exception:
-        return ""
+    full.update(
+        {k: v for k, v in telemetry.flat_summary().items() if k not in full}
+    )
+    rows: list[dict] = []
+    header: list[str] = []
+    if os.path.exists(path):
+        with open(path, newline="") as f:
+            reader = csv.DictReader(f)
+            header = list(reader.fieldnames or [])
+            rows = list(reader)
+    new_keys = [k for k in full if k not in header]
+    if new_keys:
+        header = header + new_keys
+        with open(path, "w", newline="") as f:
+            w = csv.DictWriter(f, fieldnames=header, restval="")
+            w.writeheader()
+            for r in rows:
+                w.writerow(r)
+            w.writerow(full)
+    else:
+        with open(path, "a", newline="") as f:
+            csv.DictWriter(f, fieldnames=header, restval="").writerow(full)
+    return path
 
 
 def history_report(name: str, key_cols: list[str], value_col: str) -> str:

@@ -78,6 +78,17 @@ def get_lib() -> ctypes.CDLL:
         return lib
 
 
+def host_backend() -> str:
+    """Which implementation plans on this host: ``native`` when the C++
+    library built and loaded, else ``python`` with the reason (a missing
+    ``g++`` selects the Python planners — same results, slower)."""
+    try:
+        get_lib()
+    except ImportError as e:
+        return f"python ({e})"
+    return "native"
+
+
 def _declare(lib: ctypes.CDLL) -> None:
     i64, i32p, i64p = ctypes.c_int64, ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int64)
     lib.magi_band_area.restype = i64

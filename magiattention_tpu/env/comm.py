@@ -107,15 +107,14 @@ def plan_broadcast_deadline_ms() -> int:
 def is_ragged_grpcoll_enable() -> bool:
     """Use ``jax.lax.ragged_all_to_all`` for GroupCast — true per-pair split
     sizes, zero padding on the wire (the TPU counterpart of the reference's
-    native grpcoll kernel tier, csrc/comm/grpcoll/). Default: auto — on when
-    the backend supports the op (TPU), off on CPU (XLA:CPU lacks it).
+    native grpcoll kernel tier, csrc/comm/grpcoll/).
 
-    The auto branch NEVER forces backend initialization: this is consulted
-    at *planning* time (solver pick_lowering), and host-side planning
-    scripts with no devices must not block on (possibly hung) TPU plugin
-    init. If no backend is initialized yet, auto resolves to the portable
-    tiers; every real execution flow builds a Mesh of live devices first,
-    so the backend is initialized by the time plans are made there."""
+    ``MAGI_ATTENTION_RAGGED_GRPCOLL`` = ``1`` / ``0`` decides outright;
+    unset (``auto``) the tier is on exactly when the devices the plan will
+    run on are TPUs — ``jax.default_backend()``, the platform every Mesh
+    over ``jax.devices()`` is built from (XLA:CPU does not implement the
+    op). The executed tier of each stage is reported by the runtime
+    (``DistAttnRuntime._cast_kinds``; INFO log ``comm plan stage``)."""
     import os
 
     v = os.environ.get("MAGI_ATTENTION_RAGGED_GRPCOLL", "auto").lower()
@@ -123,20 +122,6 @@ def is_ragged_grpcoll_enable() -> bool:
         return True
     if v in ("0", "false", "off"):
         return False
-    try:
-        from jax._src import xla_bridge
-
-        if not xla_bridge._backends:  # not initialized — stay portable
-            return False
-    except Exception:
-        # private-API drift: jax.default_backend() below is only
-        # exception-safe, not init-safe — it would force (possibly hung)
-        # TPU plugin init from a host-side planning script, the exact
-        # regression the _backends probe exists to prevent. Stay portable.
-        return False
     import jax
 
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend init failure: fall back to portable tiers
-        return False
+    return jax.default_backend() == "tpu"

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.compat import shard_map
+from jax import shard_map
 
 from ..comm.primitives import cast_rows, reduce_rows
 from ..env import comm as env_comm

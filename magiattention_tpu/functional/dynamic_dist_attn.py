@@ -28,7 +28,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..comm.primitives import cast_rows, reduce_rows

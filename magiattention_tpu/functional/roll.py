@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.compat import shard_map
+from jax import shard_map
 
 from ..meta.collection.dispatch_meta import DispatchMeta
 

@@ -53,7 +53,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .ffa import (
-    _CompilerParams,
     _lane_tile,
     _should_interpret,
     EMPTY_THRESH,
@@ -191,7 +190,7 @@ def _bsp_fwd_pallas(chunk_tbl, q_r, k_c, v_c, scale: float, interpret: bool):
             jax.ShapeDtypeStruct((hk, n_qb, r, NUM_LANES), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -392,7 +391,7 @@ def _bsp_bwd_pallas(chunk_tbl, q_r, k_c, v_c, do_r, lse_r, delta_r,
         # operands 8/9 (dkz/dvz, counting the 2 scalar-prefetch args) donate
         # their zeroed buffers to outputs 1/2 (dk/dv)
         input_output_aliases={8: 1, 9: 2},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # the chunk axis must be sequential (scratch accumulation) AND
             # the q-block axis too: dk/dv windows are revisited across
             # q-blocks of the same head, in b-major grid order

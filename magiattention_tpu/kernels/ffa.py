@@ -88,12 +88,6 @@ def _registry_mod():
     return _registry
 NUM_LANES = 128
 NUM_SUBLANES = 8
-# jax < 0.5 exposes the TPU compiler params as TPUCompilerParams; newer
-# versions renamed it. Resolve once so the kernel layer imports (and the
-# CPU/interpret parity suite runs) on either.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 # exp2-domain softmax (softcap-free path): folding log2(e) into the q
 # pre-scale turns every exp(x) into a bare exp2, deleting the per-element
 # multiply Mosaic otherwise emits inside exp (flash_attention's idiom)
@@ -467,7 +461,7 @@ def _ffa_fwd_pallas(params: FFAParams, work_qt, work_kt, meta, q_t, k_t, v_t):
             lse_shape,
         ] + ([lse_shape] if emit_ml else []),
         interpret=params.interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -697,7 +691,7 @@ def _ffa_fwd_pallas_gqa(
             jax.ShapeDtypeStruct((hk, g, sqp, NUM_LANES), jnp.float32),
         ],
         interpret=params.interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -933,7 +927,7 @@ def _ffa_bwd_dq_pallas(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((hq, sqp, d), jnp.float32)],
         interpret=params.interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(work_qt, work_kt, meta, q_t, k_t, v_t, do_t,
@@ -1153,7 +1147,7 @@ def _ffa_bwd_dq_pallas_gqa(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((hk, g, sqp, d), jnp.float32)],
         interpret=params.interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(work_qt, work_kt, meta, q_g, k_t, v_t, do_g, lse_p, delta_p)
@@ -1437,7 +1431,7 @@ def _ffa_bwd_dkv_pallas(
             jax.ShapeDtypeStruct((hk, skp, dv), jnp.float32),
         ],
         interpret=params.interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(work_qt_t, work_kt_t, meta_t, q_t, k_t, v_t, do_t,
@@ -1662,7 +1656,7 @@ def _ffa_bwd_dkv_pallas_gqa(
             jax.ShapeDtypeStruct((hk, skp, dv), jnp.float32),
         ],
         interpret=params.interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(work_qt_t, work_kt_t, meta_t, q_g, k_t, v_t, do_g, lse_p, delta_p)
@@ -1758,7 +1752,7 @@ def _ffa_delta_pallas(out_t, do_t, block_q: int, interpret: bool):
         ],
         out_shape=[jax.ShapeDtypeStruct((hq, sqp, NUM_LANES), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
     )(out_t, do_t)
@@ -2068,7 +2062,7 @@ def _ffa_bwd_fused_pallas(
         # operand 9 (dqz, counting the 3 scalar-prefetch args) -> output 0
         input_output_aliases={9: 0},
         interpret=params.interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(work_qt_t, work_kt_t, meta_t, q_t, k_t, v_t, do_t,
@@ -2320,7 +2314,7 @@ def _ffa_bwd_fused_pallas_gqa(
         # operand 9 (dqz, counting the 3 scalar-prefetch args) -> output 0
         input_output_aliases={9: 0},
         interpret=params.interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(work_qt_t, work_kt_t, meta_t, q_g, k_t, v_t, do_g, lse_p, delta_p,
@@ -2722,10 +2716,19 @@ _ffa_core.defvjp(_ffa_core_fwd, _ffa_core_bwd)
 
 
 def _should_interpret() -> bool:
-    return (
-        env_general.is_interpret_mode_enable()
-        or jax.default_backend() == "cpu"
-    )
+    """Pallas interpret mode is the CPU route and nothing else: on when
+    the default backend is ``cpu`` (tests, host-side rehearsal), an error
+    when ``MAGI_ATTENTION_PALLAS_INTERPRET=1`` meets any other backend —
+    an accelerator run that interpreted its kernels would pass every
+    check while measuring nothing."""
+    backend = jax.default_backend()
+    if env_general.is_interpret_mode_enable() and backend != "cpu":
+        raise RuntimeError(
+            "MAGI_ATTENTION_PALLAS_INTERPRET=1 with jax.default_backend()="
+            f"{backend!r}: interpret mode is the CPU test route; unset the "
+            "variable to compile the kernels for this device"
+        )
+    return backend == "cpu"
 
 
 def ffa_attn_with_plan(

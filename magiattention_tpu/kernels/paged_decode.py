@@ -41,13 +41,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from .ffa import (
-    _CompilerParams,
     _lane_tile,
     _should_interpret,
     EMPTY_THRESH,
@@ -197,7 +196,7 @@ def _paged_decode_pallas(page_table, lengths, q_hds, k_pages, v_pages,
             jax.ShapeDtypeStruct((hk, S, g, NUM_LANES), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -329,7 +328,7 @@ def paged_decode_attn_sharded(
             spec_kv_heads,  # v_pages (num_pages, ps, hk, dv)
         ),
         out_specs=(PartitionSpec("kv"), PartitionSpec("kv")),
-        check_rep=False,
+        check_vma=False,
     )
     out_hds, lse_hds = sharded(
         cache.page_table, cache.lengths, q_hds, cache.k_pages, cache.v_pages
@@ -485,7 +484,7 @@ def _paged_decode_spec_pallas(page_table, lengths, q_hds, k_pages, v_pages,
             jax.ShapeDtypeStruct((hk, S, kg, NUM_LANES), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -704,7 +703,7 @@ def _paged_decode_int8_pallas(page_table, lengths, q_hds, k_pages, v_pages,
             jax.ShapeDtypeStruct((hk, S, g, NUM_LANES), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -38,7 +38,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..api import dispatch, get_mesh, get_position_ids
-from ..utils.compat import shard_map
+from jax import shard_map
 from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
 from .llama import LlamaConfig, _rms_norm, attn_block, masked_ce
 

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..kernels.ffa import ffa_attn

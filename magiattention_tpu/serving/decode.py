@@ -67,7 +67,6 @@ def _feasibility(
                 and not quantized
                 and shards > 1
                 and hk % shards == 0
-                and len(jax.devices()) >= shards
             )
         if rung == "paged_decode_spec":
             # quantized verify descends to gather_ffa's dequantized path
@@ -125,6 +124,13 @@ def decode_attn_step(
     hk = cache.k_pages.shape[2]
     dv = cache.v_pages.shape[-1]
     quantized = cache.quantized
+    if shards > len(jax.devices()):
+        # a kv mesh wider than the host is a configuration error: serving
+        # unsharded instead would hide that the devices are not there
+        raise ValueError(
+            f"decode_shards={shards} but only {len(jax.devices())} "
+            f"device(s) are visible ({jax.devices()[0].platform})"
+        )
     key = (S, hq, hk, d, dv, str(q_batch.dtype), quantized, shards)
     if quantized:
         default = "paged_decode_int8"

@@ -18,19 +18,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-try:
-    from magiattention_tpu.utils.compile_cache import enable_persistent_cache
-
-    enable_persistent_cache()
-except Exception:
-    pass  # cache dir not writable: run uncached
 import jax.numpy as jnp
 import numpy as np
 
 
 def main() -> int:
+    from magiattention_tpu.benchmarking.bench import measuring_device
+
     trace_dir = sys.argv[1] if len(sys.argv) > 1 else "/tmp/ffa_trace"
-    print("backend:", jax.default_backend(), flush=True)
+    print("device:", measuring_device("tpu_profile_ffa"), flush=True)
 
     from magiattention_tpu.kernels.ffa import ffa_attn
 

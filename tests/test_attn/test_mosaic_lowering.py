@@ -5,8 +5,8 @@ the CPU-only test environment via JAX cross-platform lowering
 (``.trace(...).lower(lowering_platforms=('tpu',))``). That runs the full
 Pallas->Mosaic path — BlockSpec validation, index-map evaluation, Mosaic
 MLIR generation + verification — without executing, so BlockSpec/layout
-bugs (like the r2 max-logits lse-layout bug found only in a chip window,
-docs/tpu_results.md) are caught always-on in CI.
+bugs (like the max-logits lse-layout bug once found only on the chip) are
+caught always-on in CI.
 
 Limit (documented per the verdict): the Mosaic->LLO *compile* inside XLA
 needs libtpu, so errors raised only by the Mosaic backend compiler (e.g.

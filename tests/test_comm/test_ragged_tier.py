@@ -24,7 +24,7 @@ import pytest
 from magiattention_tpu.common.enum import AttnMaskType
 from magiattention_tpu.common.ranges import AttnRanges
 from magiattention_tpu.functional.dist_attn import _ragged_arrays
-from magiattention_tpu.utils.compat import shard_map
+from jax import shard_map
 from magiattention_tpu.meta import (
     make_attn_meta_from_dispatch_meta,
     make_dispatch_meta_from_qk_ranges,

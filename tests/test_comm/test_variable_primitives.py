@@ -4,7 +4,7 @@ _all_gather_v.py, _scatter_v.py — VERDICT r1 missing item 4)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from magiattention_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from magiattention_tpu.comm.primitives import all_gather_vv, scatter_v

@@ -154,7 +154,7 @@ def test_hp_group_cast_primitive_fast():
     """Fast-tier coverage of hp_group_cast itself: fp32 output, fp32
     collective in the backward HLO, and gradients equal to the plain cast
     (the e2e runtime A/Bs above are the slow tier)."""
-    from magiattention_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from magiattention_tpu.comm.primitives import cast_rows

@@ -12,8 +12,8 @@ Limits (documented): the async start/done split + latency-hiding schedule
 happen inside the TPU compiler (needs libtpu); XLA:CPU never splits
 collectives into async pairs (verified: compiled CPU HLO of this exact
 program contains zero `-start`/`-done` ops), so the *scheduled* overlap can
-only be measured on silicon (scripts/tpu_window_queue.sh runs
-benchmarks/overlap_bench.py in chip windows).
+only be measured on the chip (benchmarks/overlap_bench.py on a four-chip
+host; ROADMAP A6).
 """
 
 from __future__ import annotations

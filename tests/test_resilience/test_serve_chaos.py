@@ -45,6 +45,14 @@ needs_mesh = pytest.mark.skipif(
 )
 
 
+@pytest.fixture(autouse=True)
+def _telemetry_into_tmp(monkeypatch, tmp_path):
+    """These tests turn telemetry on; its JSONL and persisted store (backend
+    policies, chaos quarantines) must not land in ./telemetry of the
+    checkout, where a later chip run's copy of the tree would carry them."""
+    monkeypatch.setenv("MAGI_ATTENTION_TELEMETRY_DIR", str(tmp_path / "tel"))
+
+
 def make_requests(model):
     return [
         ServeRequest(

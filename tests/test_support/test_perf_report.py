@@ -65,9 +65,20 @@ def test_report_without_history_is_empty(history_dir):
     assert pr.history_report("missing", ["x"], "y") == ""
 
 
-def test_append_never_raises(history_dir, monkeypatch):
+def test_append_failure_is_loud(history_dir, monkeypatch):
+    # a measurement whose record cannot be written did not land: the
+    # harness must stop, not report success over a lost row
     monkeypatch.setattr(pr, "HISTORY_DIR", "/proc/definitely/not/writable")
-    assert pr.append_row("k", {"a": 1}) == ""
+    with pytest.raises(OSError):
+        pr.append_row("k", {"a": 1})
+
+
+def test_commit_column_comes_from_the_caller(history_dir):
+    # a chip run's copy of the tree is not a git repository
+    pr.append_row("k", {"a": 1})
+    pr.append_row("k", {"a": 2, "commit": "abc1234"})
+    rows = list(csv.DictReader(open(history_dir / "k.csv")))
+    assert [r["commit"] for r in rows] == ["unknown", "abc1234"]
 
 
 def test_fwdbwd_floor_uses_executed_flops():

@@ -97,20 +97,12 @@ class TestCredibleFloor:
         implied_tflops = flops / (ms * 1e-3) / 1e12
         assert implied_tflops == pytest.approx(MEASURED_CEILING_TFLOPS)
 
-    def test_off_tpu_path_ignores_floor(self, monkeypatch):
-        # CPU backend: short plain scan, floor must not apply
+    def test_off_tpu_raises(self, monkeypatch):
+        # slope timing is a device measurement: on the CPU it must stop,
+        # not switch to another timing method under the same name
         monkeypatch.setattr(bench.jax, "default_backend", lambda: "cpu")
-        called = {}
-
-        def fake_scan(body, carry0, length, reps):
-            called["scan"] = True
-            return 1.0
-
-        monkeypatch.setattr(bench, "do_bench_scan", fake_scan)
-        ms = bench.do_bench_scan_slope(
-            lambda c: c, 0, min_credible_ms=50.0
-        )
-        assert called["scan"] and ms == 1.0
+        with pytest.raises(RuntimeError, match="times a TPU"):
+            bench.do_bench_scan_slope(lambda c: c, 0, min_credible_ms=50.0)
 
 
 def test_kv_bodies_preserve_aux_and_consume_grads():

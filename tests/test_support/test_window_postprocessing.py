@@ -1,13 +1,11 @@
-"""The window-day post-processing tools must work BEFORE a window lands.
+"""The post-processing tools must work BEFORE a chip run lands.
 
-A chip window is minutes long and rare; the scripts that turn its CSV
-rows into decisions (fit_tile_overhead's least-squares, bench.py's
-cached-silicon promotion) run unattended afterwards. These tests pin
-them on synthetic data so a tooling bug cannot waste the next window.
+Chip time is budgeted; the script that turns a run's CSV rows into a
+decision (fit_tile_overhead's least-squares) runs afterwards. These tests
+pin it on synthetic data so a tooling bug cannot waste the next run.
 """
 
 import csv
-import json
 import os
 
 import numpy as np
@@ -114,41 +112,3 @@ class TestFitTileOverhead:
             rc = fit.main()
         assert rc == 1
         assert "degenerate fit" in buf.getvalue()  # THE guard, not rc=1
-
-
-class TestBenchPromotion:
-    def _bench(self, tmp_path, monkeypatch, cached):
-        bench = load_script(os.path.join(ROOT, "bench.py"), "bench_module")
-        cache = tmp_path / ".bench_last_tpu.json"
-        if cached is not None:
-            cache.write_text(json.dumps(cached))
-        monkeypatch.setattr(bench, "_CACHE_PATH", str(cache))
-        return bench
-
-    CACHED = {"metric": "m", "value": 42.0, "unit": "TFLOP/s",
-              "vs_baseline": 0.4, "measured_at": "2026-07-30T00:00:00Z"}
-
-    def test_degraded_cpu_marked_stale(self, tmp_path, monkeypatch):
-        bench = self._bench(tmp_path, monkeypatch, self.CACHED)
-        out = bench._promote_cached_silicon(
-            {"metric": "m", "value": 0.0, "backend": "cpu"}
-        )
-        assert out["value"] == 42.0
-        assert out["stale"] is True
-        assert out["live_status"] == "degraded_cpu"
-        assert "error" not in out
-
-    def test_crash_keeps_error_at_top_level(self, tmp_path, monkeypatch):
-        bench = self._bench(tmp_path, monkeypatch, self.CACHED)
-        out = bench._promote_cached_silicon(
-            {"metric": "m", "value": 0.0, "error": "worker died"}
-        )
-        assert out["value"] == 42.0
-        assert out["stale"] is True
-        assert out["error"] == "worker died"
-        assert out["live_status"] == "crashed"
-
-    def test_no_cache_passthrough(self, tmp_path, monkeypatch):
-        bench = self._bench(tmp_path, monkeypatch, None)
-        live = {"metric": "m", "value": 0.0, "error": "boom"}
-        assert bench._promote_cached_silicon(dict(live)) == live
