@@ -20,8 +20,12 @@ It exits non-zero within seconds when JAX's default backend is not ``tpu``,
 and refuses to start under any variable that could hide the device
 (interpret mode, fallbacks, retries, backend pins, fault injection,
 telemetry). No phase is wrapped in try/except: a phase that fails fails the
-run. The last line of stdout is one JSON object; ``"ok": true`` only when
-every check passed.
+run. The line before the last, ``report: {...}``, carries the sizes, every
+check's error against its tolerance, the kernels, plans and compile times.
+The last line of stdout is the result, one JSON object with exactly these
+keys, ``"ok": true`` only when every check passed::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
 ``python chip_smoke.py --rehearse-cpu [N]`` is a toy-size rehearsal of the
 same code on N virtual CPU devices (default 4) with the kernels interpreted.
@@ -31,6 +35,7 @@ pass line.
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import sys
@@ -138,6 +143,11 @@ def _parse_args(argv: list[str]) -> int | None:
 def main(argv: list[str]) -> int:
     rehearse = _parse_args(argv)
     _refuse_hidden_device_env()
+    if importlib.util.find_spec("magiattention_tpu") is None:
+        sys.exit(
+            "chip_smoke: the magiattention_tpu package is not importable: "
+            "run this script from the root of a checkout, not on its own."
+        )
     tag = "[cpu rehearsal] " if rehearse else ""
 
     def say(msg: str) -> None:
@@ -221,14 +231,23 @@ def main(argv: list[str]) -> int:
         cache["entries_after"] = _cache_entries(cache["dir"])
         result["compile_cache"] = cache
     result["wall_s"] = round(time.perf_counter() - t_start, 1)
+    return _emit(result, device, bool(rehearse), say)
+
+
+def _emit(result: dict, device: dict, rehearse: bool, say) -> int:
+    """Print the report line and, on a chip run, the result line; return the
+    exit code. The result line is the LAST line of stdout and holds exactly
+    ``ok`` and ``device`` (``platform``, ``kind``, ``count``): whoever runs
+    the smoke parses that line and nothing else."""
     failed = [k for k, c in result["checks"].items() if not c["ok"]]
+    say("report: " + json.dumps(
+        {"device": device, "failed_checks": failed, **result}))
     if rehearse:
-        # never the pass line: no "ok" key, and the line is marked
-        say(json.dumps({"rehearsal": "cpu", "failed_checks": failed,
-                        "device": device, **result}))
+        # never the result line: a rehearsal proves nothing about the chip
+        say("rehearsal " + ("FAILED: " + ", ".join(failed) if failed
+                            else "finished; no result line on the cpu"))
         return 1 if failed else 0
-    print(json.dumps({"ok": not failed, "device": device, **result}),
-          flush=True)
+    print(json.dumps({"ok": not failed, "device": device}), flush=True)
     return 1 if failed else 0
 
 

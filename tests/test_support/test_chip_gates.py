@@ -88,6 +88,25 @@ def test_chip_smoke_gate_stops_cpu_before_the_model(chip_smoke, capsys):
     assert '"ok"' not in out  # no result line
 
 
+def test_chip_smoke_last_line_is_ok_and_device_only(chip_smoke, capsys):
+    import json
+
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    checks = {"a": {"ok": True}, "b": {"err": 1.0, "tol": 0.1, "ok": False}}
+    for result, code in (
+        ({"checks": {"a": checks["a"]}, "sizes": {}}, 0),
+        ({"checks": checks, "sizes": {}}, 1),
+    ):
+        assert chip_smoke._emit(result, device, False, print) == code
+        *_, report, last = capsys.readouterr().out.splitlines()
+        # exactly the keys whoever runs the smoke parses; details go before
+        assert json.loads(last) == {"ok": code == 0, "device": device}
+        assert report.startswith("report: ") and '"sizes"' in report
+    # a rehearsal never prints a result line
+    assert chip_smoke._emit(result, device, True, print) == 1
+    assert '"ok": false, "device"' not in capsys.readouterr().out
+
+
 def test_chip_smoke_counts_a_cache_dir_that_does_not_exist_yet(
     chip_smoke, tmp_path
 ):
