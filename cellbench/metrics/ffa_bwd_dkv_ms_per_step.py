@@ -1,0 +1,7 @@
+"""Self time per step of the ``bwd_dkv`` kernel bodies, by their names."""
+
+from cellbench import kernel_times
+
+
+def read(ctx):
+    return kernel_times.ms_per_step(ctx, "bwd_dkv")

@@ -152,12 +152,16 @@ def _kernels_dir() -> Path:
 
 
 def discover_pallas_sites(kernels_dir: str | Path | None = None) -> list[PallasSite]:
-    """Every ``*.pallas_call`` call site under ``kernels/``, with the kernel
-    body name resolved through local ``kernel = partial(<fn>, ...)``
-    assignments inside the enclosing wrapper."""
+    """Every ``*.pallas_call`` call site under ``kernels/`` (the sites go
+    through ``_named.pallas_call``, which forwards to ``pl.pallas_call``
+    under the kernel's name and is itself no site), with the kernel body
+    name resolved through local ``kernel = partial(<fn>, ...)`` assignments
+    inside the enclosing wrapper."""
     root = Path(kernels_dir) if kernels_dir else _kernels_dir()
     sites: list[PallasSite] = []
     for path in sorted(root.glob("*.py")):
+        if path.name == "_named.py":
+            continue
         tree = ast.parse(path.read_text(), filename=str(path))
         for fn in ast.walk(tree):
             if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):

@@ -52,6 +52,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _named
 from .ffa import (
     _lane_tile,
     _should_interpret,
@@ -182,7 +183,7 @@ def _bsp_fwd_pallas(chunk_tbl, q_r, k_c, v_c, scale: float, interpret: bool):
         ],
     )
     kernel = partial(_bsp_fwd_kernel, ds=ds)
-    out, lse = pl.pallas_call(
+    out, lse = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -379,7 +380,7 @@ def _bsp_bwd_pallas(chunk_tbl, q_r, k_c, v_c, do_r, lse_r, delta_r,
         ],
     )
     kernel = partial(_bsp_bwd_kernel, scale=scale)
-    dq, dk, dv_out = pl.pallas_call(
+    dq, dk, dv_out = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[

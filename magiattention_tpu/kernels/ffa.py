@@ -56,6 +56,7 @@ from ..env import general as env_general
 from ..env import kernel as env_kernel
 from ..resilience.inject import maybe_inject
 from ..utils.mem_budget import VMEM_ALLOWED_BYTES, ffa_kernel_residency
+from . import _named
 from .ffa_plan import (  # noqa: F401
     EK0,
     EK1,
@@ -453,7 +454,7 @@ def _ffa_fwd_pallas(params: FFAParams, work_qt, work_kt, meta, q_t, k_t, v_t):
         nc=_clamp_chunks(bk),
     )
     lse_shape = jax.ShapeDtypeStruct((hq, sqp, NUM_LANES), jnp.float32)
-    outs = pl.pallas_call(
+    outs = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -683,7 +684,7 @@ def _ffa_fwd_pallas_gqa(
         _fwd_kernel_gqa, softcap=params.softcap, bq=bq, bk=bk, g=g,
         nc=_clamp_chunks(bk),
     )
-    outs = pl.pallas_call(
+    outs = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -922,7 +923,7 @@ def _ffa_bwd_dq_pallas(
         _bwd_dq_kernel, softcap=params.softcap,
         scale=params.softmax_scale, bq=bq, bk=bk, nc=_clamp_chunks(bk),
     )
-    (dq_t,) = pl.pallas_call(
+    (dq_t,) = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((hq, sqp, d), jnp.float32)],
@@ -1142,7 +1143,7 @@ def _ffa_bwd_dq_pallas_gqa(
         scale=params.softmax_scale, bq=bq, bk=bk, g=g,
         nc=_clamp_chunks(bk),
     )
-    (dq_g,) = pl.pallas_call(
+    (dq_g,) = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((hk, g, sqp, d), jnp.float32)],
@@ -1423,7 +1424,7 @@ def _ffa_bwd_dkv_pallas(
         _bwd_dkv_kernel, softcap=params.softcap,
         bq=bq, bk=bk, group=g, nc=_clamp_chunks(bq),
     )
-    dk_t, dv_t = pl.pallas_call(
+    dk_t, dv_t = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -1648,7 +1649,7 @@ def _ffa_bwd_dkv_pallas_gqa(
         _bwd_dkv_kernel_gqa, softcap=params.softcap, bq=bq, bk=bk, g=g,
         clamp=_registry_mod().extent_clamp_enabled(),
     )
-    dk_t, dv_t = pl.pallas_call(
+    dk_t, dv_t = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -1737,7 +1738,7 @@ def _ffa_delta_pallas(out_t, do_t, block_q: int, interpret: bool):
     hq, sqp, dv = out_t.shape
     bq = min(block_q, sqp)
     nqt = sqp // bq
-    (delta_b,) = pl.pallas_call(
+    (delta_b,) = _named.pallas_call(
         partial(_delta_kernel, bq=bq),
         grid=(hq, nqt),
         in_specs=[
@@ -2051,7 +2052,7 @@ def _ffa_bwd_fused_pallas(
         scale=params.softmax_scale, bq=bq, bk=bk, group=g,
         nc=_clamp_chunks(bq),
     )
-    dq_t, dk_t, dv_t = pl.pallas_call(
+    dq_t, dk_t, dv_t = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -2303,7 +2304,7 @@ def _ffa_bwd_fused_pallas_gqa(
         scale=params.softmax_scale, bq=bq, bk=bk, g=g,
         clamp=_registry_mod().extent_clamp_enabled(),
     )
-    dq_g, dk_t, dv_t = pl.pallas_call(
+    dq_g, dk_t, dv_t = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[

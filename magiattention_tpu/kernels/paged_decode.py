@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec
 
+from . import _named
 from .ffa import (
     _lane_tile,
     _should_interpret,
@@ -188,7 +189,7 @@ def _paged_decode_pallas(page_table, lengths, q_hds, k_pages, v_pages,
         ],
     )
     kernel = partial(_paged_decode_kernel, ps=ps)
-    out, lse = pl.pallas_call(
+    out, lse = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -476,7 +477,7 @@ def _paged_decode_spec_pallas(page_table, lengths, q_hds, k_pages, v_pages,
         ],
     )
     kernel = partial(_paged_decode_spec_kernel, ps=ps, spec_k=spec_k, g=g)
-    out, lse = pl.pallas_call(
+    out, lse = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -695,7 +696,7 @@ def _paged_decode_int8_pallas(page_table, lengths, q_hds, k_pages, v_pages,
         ],
     )
     kernel = partial(_paged_decode_int8_kernel, ps=ps)
-    out, lse = pl.pallas_call(
+    out, lse = _named.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[

@@ -3,6 +3,30 @@
 The reference instruments every hot-path function with NVTX ranges and opens
 torch.profiler windows; the TPU equivalents are ``jax.named_scope`` (shows up
 in XLA HLO + xprof traces) and ``jax.profiler`` trace windows.
+
+What a scope does to a device trace. A v5e trace knows an operation by its
+HLO instruction's NAME; the scopes' full path is in the instruction's
+``op_name`` metadata only, which the trace does not carry. XLA names a
+``tpu_custom_call`` (a Pallas kernel) after the INNERMOST named scope of its
+``op_name`` and nothing else after a scope at all: fusions are named by
+what they fuse, and a collective the library issues keeps its JAX
+primitive's name where XLA has an instruction of that kind
+(``ragged_all_to_all``, ``all_to_all``; a ``ppermute`` becomes
+``collective-permute-start``). So:
+
+* the scopes here are gated on ``MAGI_ATTENTION_PROFILE_MODE`` as the
+  reference gates its NVTX ranges (which cost at run time), and they show
+  in ``op_name`` / ``compiled.as_text()``, not as names in a trace;
+* a kernel's identity is not gated: ``kernels/_named.py`` binds every
+  Pallas call under ``magi<body>`` (``magi_fwd_kernel``,
+  ``magi_bwd_dq_kernel``, ...), always, and that innermost scope is what a
+  trace shows. Inside ``_multi_ffa*`` the instruction is therefore
+  ``magi_*`` and ``ffa_fwd_stage{i}`` survives in ``op_name`` only;
+* ``ffa_fwd_stage{i}`` / ``ffa_bwd_delta`` / ``ffa_bwd`` / ``lse_merge``
+  live in ``_multi_ffa*`` and are entered on the multi-stage overlap path
+  only; the merged single-call path (``use_overlap`` off, one stage) goes
+  straight to ``ffa_attn_with_plan``. ``group_cast_stage{i}`` is entered on
+  both, and shows in ``op_name`` only.
 """
 
 from __future__ import annotations
@@ -73,18 +97,6 @@ def instrument_host(fn: Callable | None = None, *, name: str | None = None):
         return inner
 
     return wrap(fn) if fn is not None else wrap
-
-
-@contextmanager
-def add_profile_event(name: str):
-    """Annotate a host-side region in the profiler trace (ref
-    add_nvtx_event). Gated on MAGI_ATTENTION_PROFILE_MODE like every other
-    annotation helper here — off means no TraceAnnotation is constructed."""
-    if not env_general.is_profile_mode_enable():
-        yield
-    else:
-        with jax.profiler.TraceAnnotation(name):
-            yield
 
 
 class switch_profile:

@@ -7,7 +7,6 @@ import pytest
 
 from magiattention_tpu.utils import profiling
 from magiattention_tpu.utils.profiling import (
-    add_profile_event,
     instrument_host,
     instrument_scope,
     profile_scope,
@@ -52,8 +51,6 @@ def _exercise_all():
     assert hosted(1) == 2
     with profile_scope("scope"):
         pass
-    with add_profile_event("event"):
-        pass
 
 
 def test_flag_off_is_identity(monkeypatch, spies):
@@ -65,8 +62,8 @@ def test_flag_off_is_identity(monkeypatch, spies):
 def test_flag_on_annotates(monkeypatch, spies):
     monkeypatch.setenv("MAGI_ATTENTION_PROFILE_MODE", "1")
     _exercise_all()
-    # instrument_scope + profile_scope; instrument_host + add_profile_event
-    assert spies == {"named_scope": 2, "trace_annotation": 2}
+    # instrument_scope + profile_scope; instrument_host
+    assert spies == {"named_scope": 2, "trace_annotation": 1}
 
 
 @pytest.fixture
