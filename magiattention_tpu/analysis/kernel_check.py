@@ -414,15 +414,8 @@ def capture_ffa_contracts(spec: AuditSpec) -> list[KernelContract]:
     delta_t = jnp.zeros((spec.hq, sqp), jnp.float32)
 
     def pack_ok(kind: str, kbq: int, kbk: int) -> bool:
-        return (
-            g > 1
-            and sqp % kbq == 0
-            and ffa_kernel_residency(
-                kind, kbq, kbk, spec.d, head_dim_v=spec.dv,
-                dtype_bytes=itemsize, group=g, packed=True,
-            )
-            <= VMEM_ALLOWED_BYTES
-        )
+        return sqp % kbq == 0 and ffa.gqa_pack_fits(
+            kind, g, kbq, kbk, spec.d, spec.dv, itemsize)
 
     def fused_ok(packed_flag: bool) -> bool:
         # mirrors ffa.fused_bwd_feasible: the runtime never routes an

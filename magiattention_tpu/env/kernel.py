@@ -6,7 +6,17 @@ from .general import _get_int, _get_str
 
 
 def ffa_block_q() -> int:
-    """Q tile rows per grid step (multiple of 8 for fp32 / 16 for bf16)."""
+    """Q tile rows per grid step (multiple of 8 for fp32 / 16 for bf16).
+
+    One tile for every pass unless a pass has its own (FFA_BLOCK_*_DQ /
+    _DKV below). 256 stays the default since the v5e A/B of PR 25
+    (PERF.md): the GQA-packed bodies carry g x 256 rows a step at it and
+    won every cell at g = 4, d = 128 — forward 287 ms a step of the dense
+    cell at 256 plain, 233 at 512 plain, 201 at 256 packed — and 512 rows
+    under the packed dkv is refused by the chip's compiler (16.68 of 16
+    MiB of VMEM). The key still matters where nothing packs (g = 1,
+    max-logits, a pack flag at 0): 512 there was faster at g = 4 and is
+    not measured at g = 1."""
     return _get_int("MAGI_ATTENTION_FFA_BLOCK_Q", 256)
 
 
@@ -52,6 +62,13 @@ def ffa_blocks_pinned() -> bool:
     )
 
 
+def ffa_pass_blocks_pinned() -> bool:
+    """True when a backward pass's own tile is set via env
+    (FFA_BLOCK_{Q,K}_{DQ,DKV}); 0 inherits, so it pins nothing."""
+    return any((ffa_block_q_dq(), ffa_block_k_dq(),
+                ffa_block_q_dkv(), ffa_block_k_dkv()))
+
+
 def ffa_native_plan() -> str:
     """Native (C) FFA work-list builder: 'auto' (use when the native lib
     builds; silently fall back), '1' (require), '0' (pure Python). Unlike
@@ -74,9 +91,12 @@ def ffa_extent_clamp() -> bool:
 def ffa_gqa_pack_dq() -> bool:
     """GQA-pack the dq backward kernel (grid (hk, W)): k/v fetched once
     per work item instead of per q-head, s/dp matmuls g x taller,
-    lse/delta tile-packed on the host. Opt-in until silicon A/B data picks
-    a default; VMEM-guarded like the fwd pack."""
-    return _get_int("MAGI_ATTENTION_FFA_GQA_PACK_DQ", 0) == 1
+    lse/delta tile-packed on the host. ON by default since the v5e A/B of
+    PR 25 (PERF.md): at g = 4 it beat the plain body at 256 rows (198 ->
+    158 ms a step of the dense cell) and at 512 rows (168) in every cell.
+    VMEM-guarded like the fwd pack (kernels/ffa.gqa_pack_fits). 0 brings
+    the plain body back, at the same tiles."""
+    return _get_int("MAGI_ATTENTION_FFA_GQA_PACK_DQ", 1) == 1
 
 
 def ffa_gqa_pack_dkv() -> bool:
@@ -95,7 +115,10 @@ def ffa_gqa_pack() -> bool:
     """Pack the whole GQA query group of one kv head into each fwd grid
     step (grid (hk, W) instead of (hq, W)): k/v HBM traffic drops by the
     group factor and per-step bookkeeping amortizes over a taller MXU op.
-    Opt-in until silicon A/B data picks a default; ignored when
-    max-logits output is requested or the packed score tile would
-    overflow VMEM."""
-    return _get_int("MAGI_ATTENTION_FFA_GQA_PACK", 0) == 1
+    ON by default since the v5e A/B of PR 25 (PERF.md): at g = 4 it beat
+    the plain body at 256 rows (287 -> 201 ms a step of the dense cell)
+    and at 512 rows (233) in every cell. Ignored when max-logits output is
+    requested or the chip's compiler would refuse the packed step
+    (kernels/ffa.gqa_pack_fits: over 1024 packed rows, or VMEM); 0 brings
+    the plain body back, at the same tiles."""
+    return _get_int("MAGI_ATTENTION_FFA_GQA_PACK", 1) == 1

@@ -48,6 +48,7 @@ from ..kernels.ffa import (
     ffa_fwd_pallas_dispatch,
     _should_interpret,
     ffa_attn_with_plan,
+    note_tiles,
     resolved_bwd_mode,
 )
 from ..kernels.ffa_plan import build_ffa_plan, pad_plan
@@ -372,6 +373,11 @@ class DeferredTilePolicy:
             from ..kernels.tile_policy import auto_tile_enabled
 
             self._auto_tile_pending = auto_tile_enabled()
+        # who chose the tiles, for the registry's ``ffa_tiles`` note
+        self._tile_source = kernel_registry.tiles_source(
+            block_q is not None or block_k is not None,
+            self._auto_tile_pending,
+        )
         if not self._auto_tile_pending:
             self._build_plans(block_q, block_k)
 
@@ -946,6 +952,12 @@ class DistAttnRuntime(DeferredTilePolicy):
         # auto-tile runs HERE (not __post_init__) so the VMEM guard sees
         # the real head dims and dtype (r3 advisor finding)
         self._ensure_auto_plans(dh, dv, q.dtype.itemsize)
+        # the merged plan's params, or the host stage's on the overlap path
+        params = self._ffa_params(
+            self._host_dims if self.use_overlap else self._merged_dims,
+            scale, group, return_max_logits,
+        )
+        note_tiles(params, dh, dv, q.dtype.itemsize, self._tile_source)
 
         # fp32 wire reduce for partial dkv (ref decision at dist_attn.py
         # :243-248; default off there and here). The sdpa/jnp backends keep
@@ -953,10 +965,6 @@ class DistAttnRuntime(DeferredTilePolicy):
         hp_bwd = env_comm.is_bwd_high_precision_reduce_enable()
 
         if not self.use_overlap:
-            params = self._ffa_params(
-                self._merged_dims, scale, group, return_max_logits
-            )
-
             def f(q, k, v, cast_ops, arrays):
                 if hp_bwd:
                     # fused all-stage hp cast: receive buffers AND the
@@ -993,9 +1001,7 @@ class DistAttnRuntime(DeferredTilePolicy):
             return fn(q, k, v, self._cast_ops, self._merged_arrays)
 
         # multi-stage overlap path
-        host_params = self._ffa_params(
-            self._host_dims, scale, group, return_max_logits
-        )
+        host_params = params
         stage_params = [
             self._ffa_params(d, scale, group, return_max_logits)
             for d in self._stage_dims

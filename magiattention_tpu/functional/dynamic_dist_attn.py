@@ -43,6 +43,7 @@ from ..kernels.ffa import (
     _should_interpret,
     default_blocks,
     ffa_attn_with_plan,
+    note_tiles,
     resolved_bwd_mode,
 )
 from ..meta.collection.dynamic_meta import DynamicAttnPlan
@@ -416,6 +417,7 @@ class DynamicDistAttnRuntime(DeferredTilePolicy):
             interpret=_should_interpret(),
             emit_max_logits=return_max_logits,
         )
+        note_tiles(params, dh, dv, q.dtype.itemsize, self._tile_source)
         from ..env import comm as env_comm
 
         static = (

@@ -709,27 +709,64 @@ def _ffa_fwd_pallas_gqa(
     return out_t, lse_t, ml
 
 
+# What the v5e compiler takes of the packed q-major bodies (fwd, dq): a
+# sweep of g x head dims x dtype at 256 x 512, compiled for a described
+# chip (PERF.md, PR 25), passed every shape with at most 1024 packed rows
+# and a modeled residency under 9 MiB, and refused shapes the plain VMEM
+# budget admits — 2048 rows (g = 8) wanted 17.1-18.8 MiB of the core's 16
+# for a model of 9.8-13.5, dq at d = 256, g = 4, bf16 16.85 for 10.0: the
+# model counts two score-sized temporaries and Mosaic keeps more.
+Q_MAJOR_PACK_MAX_ROWS = 1024
+Q_MAJOR_PACK_MAX_BYTES = 9 * 1024 * 1024
+
+
+def gqa_pack_fits(
+    kind: str, group: int, bq: int, bk: int, d: int, dv: int | None,
+    itemsize: int = 2,
+) -> bool:
+    """Can the ``kind`` ("fwd" | "dq" | "dkv" | "fused") body run
+    GQA-packed at this tile, flags apart? Real grouping and a VMEM guard:
+    the EXACT packed-step residency (blocks + scratch + score-tile
+    intermediates, utils/mem_budget.ffa_kernel_residency — the same model
+    the static kernel checker proves K1 with) must fit the per-core budget
+    with headroom, and for the q-major bodies the tighter bounds above."""
+    if group <= 1:
+        return False
+    budget = VMEM_ALLOWED_BYTES
+    if kind in ("fwd", "dq"):
+        if group * bq > Q_MAJOR_PACK_MAX_ROWS:
+            return False
+        budget = Q_MAJOR_PACK_MAX_BYTES
+    return ffa_kernel_residency(
+        kind, bq, bk, d, head_dim_v=dv, dtype_bytes=itemsize, group=group,
+        packed=True,
+    ) <= budget
+
+
+def gqa_pack_admitted(
+    kind: str, group: int, bq: int, bk: int, d: int, dv: int | None,
+    itemsize: int = 2,
+) -> bool:
+    """:func:`gqa_pack_fits` and the pass's env flag (on by default; the
+    registry's pin). ONE predicate for the four trace-time dispatch guards
+    below: this is the rule that buys a pass its q rows a grid step — the
+    packed body wherever there is a group and the chip's compiler takes
+    it, else the plain one, at the same tiles."""
+    flag = "dkv" if kind == "fused" else kind  # the packing trade-off is dkv's
+    return (
+        gqa_pack_fits(kind, group, bq, bk, d, dv, itemsize)
+        and _registry_mod().gqa_pack_variant(flag) == "gqa_packed"
+    )
+
+
 def _use_gqa_pack(
     params: FFAParams, d: int, dv: int, itemsize: int = 2
 ) -> bool:
-    """Trace-time dispatch to the packed fwd kernel: opt-in flag, real
-    grouping, no max-logits (the packed kernel doesn't emit them), and a
-    VMEM guard — the EXACT packed-step residency (blocks + scratch +
-    score-tile intermediates, utils/mem_budget.ffa_kernel_residency — the
-    same model the static kernel checker proves K1 with) must fit the
-    per-core budget with headroom."""
-    from . import registry as _registry
-
-    return (
-        _registry.gqa_pack_variant("fwd") == "gqa_packed"
-        and params.group > 1
-        and not params.emit_max_logits
-        and ffa_kernel_residency(
-            "fwd", params.block_q, params.block_k, d, head_dim_v=dv,
-            dtype_bytes=itemsize, group=params.group, packed=True,
-        )
-        <= VMEM_ALLOWED_BYTES
-    )
+    """Trace-time dispatch to the packed fwd kernel: admitted
+    (:func:`gqa_pack_admitted`) and no max-logits, which the packed kernel
+    doesn't emit."""
+    return not params.emit_max_logits and gqa_pack_admitted(
+        "fwd", params.group, params.block_q, params.block_k, d, dv, itemsize)
 
 
 # ---------------------------------------------------------------------------
@@ -1158,23 +1195,10 @@ def _ffa_bwd_dq_pallas_gqa(
 def _use_gqa_pack_dq(
     params: FFAParams, d: int, dv: int | None = None, itemsize: int = 2
 ) -> bool:
-    """Trace-time dispatch to the packed dq kernel: opt-in flag, real
-    grouping, and a VMEM guard on the EXACT packed-step residency with the
-    REAL head dims (utils/mem_budget.ffa_kernel_residency — shared with
-    the static kernel checker's K1; an earlier score-tile-only formula
-    under-counted blocks + scratch at large head_dim)."""
-    from . import registry as _registry
-
-    bq, bk = params.dq_blocks()
-    return (
-        _registry.gqa_pack_variant("dq") == "gqa_packed"
-        and params.group > 1
-        and ffa_kernel_residency(
-            "dq", bq, bk, d, head_dim_v=dv, dtype_bytes=itemsize,
-            group=params.group, packed=True,
-        )
-        <= VMEM_ALLOWED_BYTES
-    )
+    """Trace-time dispatch to the packed dq kernel
+    (:func:`gqa_pack_admitted` at the dq pass's own tiles)."""
+    return gqa_pack_admitted(
+        "dq", params.group, *params.dq_blocks(), d, dv, itemsize)
 
 
 def ffa_bwd_dq_pallas_dispatch(
@@ -1669,26 +1693,14 @@ def _ffa_bwd_dkv_pallas_gqa(
 def _use_gqa_pack_dkv(
     params: FFAParams, sqp: int, d: int, dv: int, itemsize: int = 2
 ) -> bool:
-    """Trace-time dispatch to the packed dkv kernel. ON by default when
-    there is real grouping (env flag ``ffa_gqa_pack_dkv``) and shapes
-    divide (the dkv q tile must tile the padded seqlen for the host-side
-    lse/delta tile-pack). VMEM guard: the EXACT packed-step residency —
-    blocks + (bk, d+dv) fp32 scratch + the (bk, g*bq) fp32 s_t/dp_t tiles
-    (utils/mem_budget.ffa_kernel_residency, shared with the static kernel
-    checker's K1) — must fit the per-core budget with headroom."""
-    from . import registry as _registry
-
+    """Trace-time dispatch to the packed dkv kernel: admitted
+    (:func:`gqa_pack_admitted`: blocks + (bk, d+dv) fp32 scratch + the
+    (bk, g*bq) fp32 s_t/dp_t tiles must fit) and shapes divide (the dkv q
+    tile must tile the padded seqlen for the host-side lse/delta
+    tile-pack)."""
     bq, bk = params.dkv_blocks()
-    return (
-        _registry.gqa_pack_variant("dkv") == "gqa_packed"
-        and params.group > 1
-        and sqp % bq == 0
-        and ffa_kernel_residency(
-            "dkv", bq, bk, d, head_dim_v=dv, dtype_bytes=itemsize,
-            group=params.group, packed=True,
-        )
-        <= VMEM_ALLOWED_BYTES
-    )
+    return sqp % bq == 0 and gqa_pack_admitted(
+        "dkv", params.group, bq, bk, d, dv, itemsize)
 
 
 def ffa_bwd_dkv_pallas_dispatch(
@@ -2333,19 +2345,9 @@ def _use_gqa_pack_fused(
     identical) with the LARGER fused residency — dkv's plus the revisited
     dq window and its aliased zero background (utils/mem_budget
     ``ffa_kernel_residency("fused", ...)``, one source of truth with K1)."""
-    from . import registry as _registry
-
     bq, bk = params.dkv_blocks()
-    return (
-        _registry.gqa_pack_variant("dkv") == "gqa_packed"
-        and params.group > 1
-        and sqp % bq == 0
-        and ffa_kernel_residency(
-            "fused", bq, bk, d, head_dim_v=dv, dtype_bytes=itemsize,
-            group=params.group, packed=True,
-        )
-        <= VMEM_ALLOWED_BYTES
-    )
+    return sqp % bq == 0 and gqa_pack_admitted(
+        "fused", params.group, bq, bk, d, dv, itemsize)
 
 
 def fused_bwd_feasible(
@@ -2875,6 +2877,43 @@ def default_blocks(sq: int, sk: int, block_q=None, block_k=None) -> tuple[int, i
     return min(bq, _round_up(sq, 16)), min(bk, _round_up(sk, 128))
 
 
+# a call's tiles name by everything it is computed from: an eager call per
+# step pays one lookup, not three registry resolves and residency sums
+_TILES_NAMES: dict[tuple, str] = {}
+
+
+def note_tiles(
+    params: FFAParams, d: int, dv: int, itemsize: int, source: str
+) -> str:
+    """Record the per-pass tiles of a call under the registry's
+    ``ffa_tiles`` decision, e.g. ``fwd256x512g4 dq256x512g4 dkv256x512g4``:
+    a ``g`` suffix marks a GQA-packed body, whose rows a grid step are g
+    times the tile's. ``source`` is who chose the tiles
+    (``registry.tiles_source``)."""
+    sqp = params.num_q_tiles * params.block_q
+    passes = (
+        ("fwd", (params.block_q, params.block_k)),
+        ("dq", params.dq_blocks()),
+        ("dkv", params.dkv_blocks()),
+    )
+    key = (passes, sqp, params.group, params.emit_max_logits, d, dv,
+           itemsize, _registry_mod().gqa_pack_flags())
+    name = _TILES_NAMES.get(key)
+    if name is None:
+        packed = (
+            _use_gqa_pack(params, d, dv, itemsize),
+            _use_gqa_pack_dq(params, d, dv, itemsize),
+            _use_gqa_pack_dkv(params, sqp, d, dv, itemsize),
+        )
+        name = _TILES_NAMES[key] = " ".join(
+            f"{tag}{bq}x{bk}" + (f"g{params.group}" if on else "")
+            for (tag, (bq, bk)), on in zip(passes, packed)
+        )
+    _registry_mod().note_choice(
+        "ffa_tiles", (sqp, d, dv, itemsize, params.group), name, source)
+    return name
+
+
 # ---------------------------------------------------------------------------
 # mixed-granularity dispatch: coarse-block pass over dense slices + fine-
 # block pass over fragmented slices, merged through the LSE-merge math
@@ -3104,10 +3143,13 @@ def ffa_attn(
                 ),
             )
     policy_dq = policy_dkv = None
-    if block_q is None and block_k is None and not _registry_mod().tiles_pinned():
+    explicit = block_q is not None or block_k is not None
+    auto_tile = False
+    if not explicit and not _registry_mod().tiles_pinned():
         from .tile_policy import auto_tile_enabled, choose_blocks_per_pass
 
-        if auto_tile_enabled():
+        auto_tile = auto_tile_enabled()
+        if auto_tile:
             # plan-geometry-driven, per-PASS tile choice (ref tile tables
             # analogue): fwd/dq score the q-major plan, dkv the k-major one,
             # and thin bands get their own block_k candidates; explicit
@@ -3142,6 +3184,8 @@ def ffa_attn(
         emit_max_logits=return_max_logits,
         **overrides,
     )
+    note_tiles(params, d, dv, q.dtype.itemsize,
+               _registry_mod().tiles_source(explicit, auto_tile))
     return ffa_attn_with_plan(
         q, k, v, arrays, params, return_max_logits=return_max_logits
     )

@@ -202,6 +202,18 @@ class BackendRegistry:
         self._announce(decision, key, choice)
         return choice
 
+    def note(
+        self, decision: str, key: Any, name: str, source: str
+    ) -> BackendChoice:
+        """Record a choice the call site computed itself (a rule over
+        shapes, nothing to resolve against): ``last_choice`` and one
+        ``backend_select`` record, no memo, no store."""
+        choice = BackendChoice(name, source)
+        with self._lock:
+            self._last[decision] = (key, name)
+        self._announce(decision, key, choice)
+        return choice
+
     def last(self, decision: str) -> tuple[Any, str] | None:
         with self._lock:
             return self._last.get(decision)
@@ -242,6 +254,12 @@ def resolve(
     pin: str | None = None,
 ) -> BackendChoice:
     return get_registry().resolve(decision, key, heuristic, pin=pin)
+
+
+def note_choice(
+    decision: str, key: Any, name: str, source: str
+) -> BackendChoice:
+    return get_registry().note(decision, key, name, source)
 
 
 def stats() -> dict[str, int]:
@@ -287,10 +305,27 @@ def tiles_pinned() -> bool:
     return env_kernel.ffa_blocks_pinned()
 
 
+def tiles_source(explicit: bool, auto_tile: bool) -> str:
+    """Who chose a call's FFA tiles, for the ``ffa_tiles`` note: "pin"
+    (tile arguments, or any FFA_BLOCK_* key, one pass's included),
+    "auto_tile" (the policy), else "default" (``ffa.default_blocks``)."""
+    if explicit or tiles_pinned() or env_kernel.ffa_pass_blocks_pinned():
+        return "pin"
+    return "auto_tile" if auto_tile else "default"
+
+
+def gqa_pack_flags() -> tuple[bool, bool, bool]:
+    """The fwd / dq / dkv pack flags as they stand, read without a
+    resolve: a cache key for what :func:`gqa_pack_variant` would say."""
+    return (env_kernel.ffa_gqa_pack(), env_kernel.ffa_gqa_pack_dq(),
+            env_kernel.ffa_gqa_pack_dkv())
+
+
 def gqa_pack_variant(kind: str) -> str:
     """'gqa_packed' | 'plain' for the fwd / bwd-dq / bwd-dkv kernels. The
-    pack flags are explicit opt-ins, so these decisions are always pinned;
-    the call site's VMEM-residency guard still applies on top."""
+    pack flags (all on by default) are read as pins, so these decisions
+    are always pinned; the call site's grouping and VMEM-residency guard
+    (kernels/ffa.gqa_pack_admitted) still applies on top."""
     if kind == "fwd":
         flag = env_kernel.ffa_gqa_pack()
         decision = "ffa_fwd"
@@ -329,14 +364,14 @@ register_backend(
 register_backend(
     "calc_attn", "sdpa_online", 2,
     "streamed dense reference — resilience ladder's last rung")
-register_backend("ffa_fwd", "plain", 0, "per-head fwd kernel")
 register_backend(
-    "ffa_fwd", "gqa_packed", 1, "grouped-head packed fwd kernel")
+    "ffa_fwd", "gqa_packed", 0, "grouped-head packed fwd kernel (default on)")
+register_backend("ffa_fwd", "plain", 1, "per-head fwd kernel")
 register_backend("ffa_bwd", "fused", 0, "one-pass fused dq/dk/dv")
 register_backend(
     "ffa_bwd", "split", 1, "split dq + dkv passes — fused's fallback rung")
-register_backend("ffa_bwd_dq", "plain", 0, "per-head dq kernel")
-register_backend("ffa_bwd_dq", "gqa_packed", 1, "packed dq kernel")
+register_backend("ffa_bwd_dq", "gqa_packed", 0, "packed dq (default on)")
+register_backend("ffa_bwd_dq", "plain", 1, "per-head dq kernel")
 register_backend("ffa_bwd_dkv", "gqa_packed", 0, "packed dkv (default on)")
 register_backend("ffa_bwd_dkv", "plain", 1, "per-head dkv kernel")
 register_backend(
@@ -384,5 +419,12 @@ PIN_KEYS: dict[str, tuple[str, ...]] = {
     "ffa_bwd_dq": ("MAGI_ATTENTION_FFA_GQA_PACK_DQ",),
     "ffa_bwd_dkv": ("MAGI_ATTENTION_FFA_GQA_PACK_DKV",),
     "ffa_lowering": ("MAGI_ATTENTION_FFA_EXTENT_CLAMP",),
+    # any FFA_BLOCK_* key is a "pin"; else the auto-tile policy, or
+    # ffa.default_blocks (tiles_source)
+    "ffa_tiles": (
+        "MAGI_ATTENTION_FFA_BLOCK_Q", "MAGI_ATTENTION_FFA_BLOCK_K",
+        "MAGI_ATTENTION_FFA_BLOCK_Q_DQ", "MAGI_ATTENTION_FFA_BLOCK_K_DQ",
+        "MAGI_ATTENTION_FFA_BLOCK_Q_DKV", "MAGI_ATTENTION_FFA_BLOCK_K_DKV",
+        "MAGI_ATTENTION_FFA_AUTO_TILE"),
     "nsa_slc": ("MAGI_ATTENTION_BACKEND_NSA_SLC",),
 }

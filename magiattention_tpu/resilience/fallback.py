@@ -145,6 +145,7 @@ def _descend_ladder(runtime, q, k, v, return_max_logits, first_err,
         # pin the ladder's choice: the deferred auto-tile policy must not
         # overwrite a rung's plans on the retry
         runtime._auto_tile_pending = False
+        runtime._tile_source = "pin"  # a rung is an explicit tile
         for hop, (rung_bq, rung_bk) in enumerate(tile_ladder(bq, bk)):
             try:
                 runtime._build_plans(rung_bq, rung_bk)

@@ -226,7 +226,7 @@ def test_gqa_packed_matches_unpacked(monkeypatch, seed, g):
         grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
         return out, lse, grads
 
-    monkeypatch.delenv("MAGI_ATTENTION_FFA_GQA_PACK", raising=False)
+    monkeypatch.setenv("MAGI_ATTENTION_FFA_GQA_PACK", "0")
     out_u, lse_u, g_u = run()
     monkeypatch.setenv("MAGI_ATTENTION_FFA_GQA_PACK", "1")
     out_p, lse_p, g_p = run()
@@ -264,7 +264,7 @@ def test_gqa_packed_dq_matches_unpacked(monkeypatch, seed, g):
 
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    monkeypatch.delenv("MAGI_ATTENTION_FFA_GQA_PACK_DQ", raising=False)
+    monkeypatch.setenv("MAGI_ATTENTION_FFA_GQA_PACK_DQ", "0")
     g_u = run()
     monkeypatch.setenv("MAGI_ATTENTION_FFA_GQA_PACK_DQ", "1")
     g_p = run()

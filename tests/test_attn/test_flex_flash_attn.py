@@ -66,15 +66,51 @@ def make_inputs(dtype, seed=0, sq=S, sk=S):
     return q, k, v
 
 
-def dense_mask(case):
+def mask_case(case, s=S):
+    """The case's slices with every boundary scaled from S to ``s``."""
     qr, kr, tm = MASK_CASES[case]
+    return (np.array(qr) * (s // S)).tolist(), (
+        np.array(kr) * (s // S)).tolist(), tm
+
+
+def dense_mask(case, s=S):
+    qr, kr, tm = mask_case(case, s)
     return AttnMask.from_ranges(
         AttnRanges.from_ranges(qr),
         AttnRanges.from_ranges(kr),
         [AttnMaskType.from_int_type(t) for t in tm],
-        total_seqlen_q=S,
-        total_seqlen_k=S,
+        total_seqlen_q=s,
+        total_seqlen_k=s,
     ).mask_array
+
+
+# long enough for four q tiles and two k tiles of default_blocks
+S_LONG = 1024
+UNPACKED = {"MAGI_ATTENTION_FFA_GQA_PACK": "0",
+            "MAGI_ATTENTION_FFA_GQA_PACK_DQ": "0"}
+# (backend, length, env) -> the per-pass tiles and bodies the ffa backend
+# must run: every pass GQA-packed on default_blocks (g x its rows a grid
+# step), or, with the q-major packs off, fwd and dq plain at the same tile
+BACKENDS_AND_TILES = [
+    pytest.param("sdpa", S, {}, None, id="sdpa"),
+    pytest.param("ffa", S, {}, "fwd128x128g2 dq128x128g2 dkv128x128g2",
+                 id="ffa"),
+    pytest.param("ffa", S_LONG, {}, "fwd256x512g2 dq256x512g2 dkv256x512g2",
+                 id="ffa_long_packed"),
+    pytest.param("ffa", S_LONG, UNPACKED, "fwd256x512 dq256x512 dkv256x512g2",
+                 id="ffa_long_plain_q_major"),
+]
+
+
+def _setenv(monkeypatch, env):
+    for key, val in env.items():
+        monkeypatch.setenv(key, val)
+
+
+def _ran_tiles(tiles):
+    from magiattention_tpu.kernels import registry
+
+    assert tiles is None or registry.last_choice("ffa_tiles") == tiles
 
 
 @pytest.mark.parametrize("case", sorted(MASK_CASES))
@@ -93,39 +129,46 @@ def test_forward_matches_ref(case, backend):
 
 @pytest.mark.parametrize("case", ["causal", "varlen_causal", "sliding_window",
                                   "shared_question", "empty_rows"])
-@pytest.mark.parametrize("backend", ["sdpa", "ffa"])
-def test_backward_matches_ref(case, backend):
-    qr, kr, tm = MASK_CASES[case]
-    q, k, v = make_inputs(jnp.float32, seed=1)
-    mask = dense_mask(case)
+@pytest.mark.parametrize("backend,s,env,tiles", BACKENDS_AND_TILES)
+def test_backward_matches_ref(monkeypatch, case, backend, s, env, tiles):
+    _setenv(monkeypatch, env)
+    qr, kr, tm = mask_case(case, s)
+    q, k, v = make_inputs(jnp.float32, seed=1, sq=s, sk=s)
+    mask = dense_mask(case, s)
     rng = np.random.default_rng(2)
-    w = jnp.asarray(rng.standard_normal((S, HQ, D)), dtype=jnp.float32)
+    w = jnp.asarray(rng.standard_normal((s, HQ, D)), dtype=jnp.float32)
 
     def loss_backend(q, k, v):
-        out, _ = flex_flash_attn_func(
+        out, meta = flex_flash_attn_func(
             q, k, v, np.array(qr), np.array(kr), np.array(tm), backend=backend
         )
-        return jnp.sum(out.astype(jnp.float32) * w)
+        return jnp.sum(out.astype(jnp.float32) * w), (out, meta.lse)
 
     def loss_ref(q, k, v):
-        out, _ = ref_attn(q, k, v, mask, compute_dtype=jnp.float32)
-        return jnp.sum(out.astype(jnp.float32) * w)
+        out, lse = ref_attn(q, k, v, mask, compute_dtype=jnp.float32)
+        return jnp.sum(out.astype(jnp.float32) * w), (out, lse)
 
-    g = jax.grad(loss_backend, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g, fwd = jax.grad(loss_backend, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    g_ref, fwd_ref = jax.grad(loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    _ran_tiles(tiles)
+    for name, a, b in zip("out lse".split(), fwd, fwd_ref):
+        assert_close(a, b, atol=1e-4, rtol=1e-4, norm_rtol=2e-5,
+                     msg=f"{case} {name}")
     for name, a, b in zip("dq dk dv".split(), g, g_ref):
         assert_close(a, b, atol=1e-3, rtol=1e-3, norm_rtol=2e-4,
                      msg=f"{case} {name}")
 
 
-@pytest.mark.parametrize("backend", ["sdpa", "ffa"])
-def test_bf16_forward(backend):
-    qr, kr, tm = MASK_CASES["varlen_causal"]
-    q, k, v = make_inputs(jnp.bfloat16, seed=3)
+@pytest.mark.parametrize("backend,s,env,tiles", BACKENDS_AND_TILES)
+def test_bf16_forward(monkeypatch, backend, s, env, tiles):
+    _setenv(monkeypatch, env)
+    qr, kr, tm = mask_case("varlen_causal", s)
+    q, k, v = make_inputs(jnp.bfloat16, seed=3, sq=s, sk=s)
     out, meta = flex_flash_attn_func(
         q, k, v, np.array(qr), np.array(kr), np.array(tm), backend=backend
     )
-    out_ref, lse_ref = ref_attn(q, k, v, dense_mask("varlen_causal"))
+    _ran_tiles(tiles)
+    out_ref, lse_ref = ref_attn(q, k, v, dense_mask("varlen_causal", s))
     assert_close(out, out_ref, atol=3e-2, rtol=3e-2, norm_rtol=2e-2,
                  mismatch_thres=0.01, msg="bf16 out")
     assert_close(meta.lse, lse_ref, atol=3e-2, rtol=3e-2, norm_rtol=2e-2,

@@ -36,23 +36,25 @@ ALL_BODIES = {
     "_bwd_fused_kernel", "_bwd_fused_kernel_gqa",
 }
 
-# (id, group size, switches) -> the bodies the forward+backward must hold
+# (id, group size, switches) -> the bodies the forward+backward must hold.
+# Every pass packs by default where there is a group (PR 25).
+PLAIN_Q_MAJOR = {"MAGI_ATTENTION_FFA_GQA_PACK": "0",
+                 "MAGI_ATTENTION_FFA_GQA_PACK_DQ": "0"}
 VARIANTS = [
     ("split_default", 2, {"MAGI_ATTENTION_FFA_FUSED_BWD": "0"},
-     {"_fwd_kernel", "_delta_kernel", "_bwd_dq_kernel",
+     {"_fwd_kernel_gqa", "_delta_kernel", "_bwd_dq_kernel_gqa",
       "_bwd_dkv_kernel_gqa"}),
     ("split_mha", 1, {"MAGI_ATTENTION_FFA_FUSED_BWD": "0"},
      {"_fwd_kernel", "_delta_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"}),
-    ("split_gqa_packed", 2,
-     {"MAGI_ATTENTION_FFA_FUSED_BWD": "0", "MAGI_ATTENTION_FFA_GQA_PACK": "1",
-      "MAGI_ATTENTION_FFA_GQA_PACK_DQ": "1"},
-     {"_fwd_kernel_gqa", "_delta_kernel", "_bwd_dq_kernel_gqa",
+    ("split_plain_q_major", 2,
+     {"MAGI_ATTENTION_FFA_FUSED_BWD": "0", **PLAIN_Q_MAJOR},
+     {"_fwd_kernel", "_delta_kernel", "_bwd_dq_kernel",
       "_bwd_dkv_kernel_gqa"}),
     ("fused_gqa_packed", 2, {"MAGI_ATTENTION_FFA_FUSED_BWD": "1"},
-     {"_fwd_kernel", "_delta_kernel", "_bwd_fused_kernel_gqa"}),
+     {"_fwd_kernel_gqa", "_delta_kernel", "_bwd_fused_kernel_gqa"}),
     ("fused_plain", 2,
      {"MAGI_ATTENTION_FFA_FUSED_BWD": "1",
-      "MAGI_ATTENTION_FFA_GQA_PACK_DKV": "0"},
+      "MAGI_ATTENTION_FFA_GQA_PACK_DKV": "0", **PLAIN_Q_MAJOR},
      {"_fwd_kernel", "_delta_kernel", "_bwd_fused_kernel"}),
 ]
 
@@ -195,7 +197,8 @@ def test_no_pallas_call_site_passes_a_name():
 
 def test_the_benchmarks_kernel_report_is_unchanged(bare_env):
     """``cellbench.family_llama.pallas_kernels`` of a toy ``train_step``:
-    the set the parent commit reported (PR 22), name for name."""
+    the set the cells report, name for name — the packed bodies since
+    PR 25, where PR 22's and PR 24's runs had the plain fwd and dq."""
     from jax.sharding import Mesh
 
     from cellbench import family_llama as family
@@ -212,8 +215,8 @@ def test_the_benchmarks_kernel_report_is_unchanged(bare_env):
         lambda p, t, l: family.train_step(p, mcfg, t, l, key)
     )(params, tokens, tokens)
     assert family.pallas_kernels(jaxpr) == {
-        "_fwd_kernel": True, "_delta_kernel": True, "_bwd_dq_kernel": True,
-        "_bwd_dkv_kernel_gqa": True,
+        "_fwd_kernel_gqa": True, "_delta_kernel": True,
+        "_bwd_dq_kernel_gqa": True, "_bwd_dkv_kernel_gqa": True,
     }
     stacks = {
         eqn.params["jaxpr"].debug_info.func_name:

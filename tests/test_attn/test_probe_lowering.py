@@ -74,10 +74,14 @@ def test_headline_fwdbwd_lowers(mosaic):
     assert text.count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("value", ["1", "0"], ids=["packed", "plain"])
 @pytest.mark.parametrize("flag", ["MAGI_ATTENTION_FFA_GQA_PACK",
                                   "MAGI_ATTENTION_FFA_GQA_PACK_DQ"])
-def test_gqa_pack_variants_lower(mosaic, monkeypatch, flag):
-    monkeypatch.setenv(flag, "1")
+def test_gqa_pack_variants_lower(mosaic, monkeypatch, flag, value):
+    """Both bodies of the q-major passes lower: the packed ones (the
+    default where there is a group) and the plain ones the flags bring
+    back."""
+    monkeypatch.setenv(flag, value)
     q, k, v, qr, kr, tm = _headline_inputs()
 
     if flag.endswith("_DQ"):

@@ -1,6 +1,9 @@
 """Auto tile selection (kernels/tile_policy.py — ref tile-table analogue)."""
 
+import re
+
 import numpy as np
+import pytest
 
 from magiattention_tpu.kernels.mask_utils import types_to_bands
 from magiattention_tpu.kernels.tile_policy import (
@@ -122,8 +125,13 @@ def test_count_matches_builder_on_random_slices():
             )
 
 
-def test_cp_runtime_honors_auto_tile(monkeypatch):
-    """The static CP runtime consults the policy (not only ffa_attn)."""
+@pytest.mark.parametrize("chooser", ["auto_tile", "default_g4", "dkv_pin_g1"])
+def test_cp_runtime_honors_the_tile_choice(monkeypatch, chooser):
+    """The static CP runtime consults the chooser (not only ffa_attn): the
+    auto-tile policy when its flag is on, else ``default_blocks`` for every
+    pass — with a GQA group each pass packs at it (a 6-array stacked plan,
+    g x 256 rows a step) — and a pass's own env tile rides the stacked cp
+    path as 12 arrays (fwd6 + dq3 + dkv3) and reads as a pin."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh
@@ -135,32 +143,47 @@ def test_cp_runtime_honors_auto_tile(monkeypatch):
     from magiattention_tpu.common.enum import AttnMaskType
     from magiattention_tpu.common.mask import AttnMask
     from magiattention_tpu.common.ranges import AttnRanges
+    from magiattention_tpu.kernels import registry
     from magiattention_tpu.testing.ref_attn import ref_attn
 
-    monkeypatch.setenv("MAGI_ATTENTION_FFA_AUTO_TILE", "1")
+    auto = chooser == "auto_tile"
+    monkeypatch.setenv("MAGI_ATTENTION_FFA_AUTO_TILE", "1" if auto else "0")
     monkeypatch.delenv("MAGI_ATTENTION_FFA_BLOCK_Q", raising=False)
     monkeypatch.delenv("MAGI_ATTENTION_FFA_BLOCK_K", raising=False)
-    s, h, d = 512, 2, 32
+    if chooser == "dkv_pin_g1":
+        monkeypatch.setenv("MAGI_ATTENTION_FFA_BLOCK_Q_DKV", "128")
+    # a full default tile a rank: cp = 4 x 512
+    s, hq, hk, d, chunk = {
+        "auto_tile": (512, 2, 2, 32, 32),
+        "default_g4": (2048, 4, 1, 32, 512),
+        "dkv_pin_g1": (2048, 2, 2, 32, 512),
+    }[chooser]
     mesh = Mesh(np.array(jax.devices("cpu")[:4]), axis_names=("cp",))
     key = magi_attn_flex_key(
-        [[0, s]], [[0, s]], [1], s, s, mesh=mesh, chunk_size=32,
+        [[0, s]], [[0, s]], [1], s, s, mesh=mesh, chunk_size=chunk,
     )
-    # auto-tile DEFERS plan building to the first calc_attn, where the
-    # real head dims/dtype feed the VMEM guard (r3 advisor finding)
     rt = _mgr(key).runtime
-    assert rt._auto_tile_pending and not hasattr(rt, "_bq")
+    if auto:
+        # auto-tile DEFERS plan building to the first calc_attn, where the
+        # real head dims/dtype feed the VMEM guard (r3 advisor finding)
+        assert rt._auto_tile_pending and not hasattr(rt, "_bq")
+    else:
+        # the default tile needs no data: the plans are built at once
+        assert (rt._bq, rt._bk) == (256, 512)
     rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((s, h, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((s, h, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((s, h, d)), jnp.float32)
-    out_d, _ = calc_attn(
-        dispatch(q, key), dispatch(k, key, role="kv"),
-        dispatch(v, key, role="kv"), key,
-    )
-    # the choice ran with the REAL dims signature and is TPU-aligned
-    assert rt._plan_sig == (d, d, 4)
-    assert rt._bq % 16 == 0 and rt._bk % 128 == 0
-    out = undispatch(out_d, key)
+    q = jnp.asarray(rng.standard_normal((s, hq, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((s, hk, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((s, hk, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((s, hq, d)), jnp.float32)
+
+    def fwd(q, k, v):
+        out_d, _ = calc_attn(
+            dispatch(q, key), dispatch(k, key, role="kv"),
+            dispatch(v, key, role="kv"), key,
+        )
+        return undispatch(out_d, key)
+
+    out = fwd(q, k, v)
     mask = AttnMask.from_ranges(
         AttnRanges.from_ranges([[0, s]]), AttnRanges.from_ranges([[0, s]]),
         [AttnMaskType.CAUSAL], total_seqlen_q=s, total_seqlen_k=s,
@@ -169,6 +192,32 @@ def test_cp_runtime_honors_auto_tile(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(out_ref), atol=2e-5, rtol=2e-5
     )
+    if auto:
+        # the choice ran with the REAL dims signature and is TPU-aligned
+        assert rt._plan_sig == (d, d, 4)
+        assert rt._bq % 16 == 0 and rt._bk % 128 == 0
+        return
+    overrides = rt._merged_dims[4]
+    if chooser == "default_g4":
+        assert not overrides and len(rt._merged_arrays) == 6
+        want, source = "fwd256x512g4 dq256x512g4 dkv256x512g4", "default"
+    else:
+        # the 12 arrays are fwd6 + dq3 + dkv3 with only dkv on its own tile
+        assert len(rt._merged_arrays) == 12
+        assert overrides["block_q_dkv"] == 128
+        assert "block_q_dq" not in overrides
+        want, source = "fwd256x512 dq256x512 dkv128x512", "pin"
+    assert rt._tile_source == source
+    assert registry.last_choice("ffa_tiles") == want
+    g = jax.grad(lambda q, k, v: jnp.sum(fwd(q, k, v) * w), (0, 1, 2))(q, k, v)
+    g_ref = jax.grad(
+        lambda q, k, v: jnp.sum(
+            ref_attn(q, k, v, mask, compute_dtype=jnp.float32)[0] * w),
+        (0, 1, 2),
+    )(q, k, v)
+    for name, a, b in zip("dq dk dv".split(), g, g_ref):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4, err_msg=name)
 
 
 def test_explicit_blocks_override_auto(monkeypatch):
@@ -252,3 +301,144 @@ def test_per_pass_choice_thin_band_and_divisibility():
         for p in picks:
             if p is not None:
                 assert sqp % p[0] == 0 and skp % p[1] == 0, (f, p)
+
+
+# -- rows a grid step, per pass: which body runs at which tile ---------------
+
+PACKED = "fwd256x512g4 dq256x512g4 dkv256x512g4"
+UNPACK_Q_MAJOR = {"MAGI_ATTENTION_FFA_GQA_PACK": "0",
+                  "MAGI_ATTENTION_FFA_GQA_PACK_DQ": "0"}
+BF16_G4 = (1024, 1024, 4, 1, 128, 128, "bfloat16")
+# name: (sq, sk, hq, hk, d, dv, dtype, ffa_attn kwargs, env) -> the tiles'
+# name as registry.last_choice("ffa_tiles") has it, and who chose
+TILE_CASES = {
+    # the four cells' FFA calls, a chip's share (PERF.md section 4): every
+    # pass packed, g x 256 rows a step, one tile, a 6-array plan
+    "nemo12b.longdoc.cp1": (
+        (16384, 16384, 32, 8, 128, 128, "bfloat16", {}, {}), PACKED,
+        "default"),
+    "nemo12b.packed.cp1": (
+        (16384, 16384, 32, 8, 128, 128, "bfloat16", {}, {}), PACKED,
+        "default"),
+    "mistral7b.swa32k.cp1": (
+        (32768, 32768, 32, 8, 128, 128, "bfloat16", {}, {}), PACKED,
+        "default"),
+    "nemo12b.longdoc.cp4": (
+        (8192, 32768, 32, 8, 128, 128, "bfloat16", {}, {}), PACKED,
+        "default"),
+    # short sequences: default_blocks' clamp, one tile for every pass
+    "sq128": ((128, 128, 4, 1, 128, 128, "bfloat16", {}, {}),
+              "fwd128x128g4 dq128x128g4 dkv128x128g4", "default"),
+    "sq384": ((384, 384, 4, 1, 128, 128, "bfloat16", {}, {}),
+              "fwd256x384g4 dq256x384g4 dkv256x384g4", "default"),
+    "sq384_unpacked": ((384, 384, 4, 1, 128, 128, "bfloat16", {},
+                        UNPACK_Q_MAJOR),
+                       "fwd256x384 dq256x384 dkv256x384g4", "default"),
+    "sq512": ((512, 512, 4, 1, 128, 128, "bfloat16", {}, {}), PACKED,
+              "default"),
+    # no group to pack: the plain bodies, at the same tile
+    "g1": ((1024, 1024, 2, 2, 128, 128, "bfloat16", {}, {}),
+           "fwd256x512 dq256x512 dkv256x512", "default"),
+    "g1_sq512": ((512, 512, 2, 2, 128, 128, "bfloat16", {}, {}),
+                 "fwd256x512 dq256x512 dkv256x512", "default"),
+    "g2": ((1024, 1024, 4, 2, 128, 128, "bfloat16", {}, {}),
+           "fwd256x512g2 dq256x512g2 dkv256x512g2", "default"),
+    # what the v5e compiler refuses of the packed q-major bodies runs
+    # plain: over 1024 packed rows, or a modeled residency over 9 MiB
+    # (tests/test_attn/test_pack_guard_compiles.py compiles both sides)
+    "g8": ((1024, 1024, 8, 1, 128, 128, "bfloat16", {}, {}),
+           "fwd256x512 dq256x512 dkv256x512g8", "default"),
+    "d256_packed_dq_too_large": (
+        (1024, 1024, 4, 1, 256, 256, "bfloat16", {}, {}),
+        "fwd256x512g4 dq256x512 dkv256x512g4", "default"),
+    "d64": ((1024, 1024, 4, 1, 64, 64, "bfloat16", {}, {}), PACKED,
+            "default"),
+    "qk192_v128": ((1024, 1024, 4, 1, 192, 128, "bfloat16", {}, {}),
+                   PACKED, "default"),
+    "fp32": ((1024, 1024, 4, 1, 128, 128, "float32", {}, {}), PACKED,
+             "default"),
+    # the packed forward emits no max-logits: it runs plain
+    "emit_max_logits": (
+        (*BF16_G4, {"return_max_logits": True}, {}),
+        "fwd256x512 dq256x512g4 dkv256x512g4", "default"),
+    # no packed residency fits: every pass plain
+    "fp32_d512_g8_packed_does_not_fit": (
+        (1024, 1024, 8, 1, 512, 512, "float32", {}, {}),
+        "fwd256x512 dq256x512 dkv256x512", "default"),
+    "fp32_d640_g1": (
+        (1024, 1024, 2, 2, 640, 640, "float32", {}, {}),
+        "fwd256x512 dq256x512 dkv256x512", "default"),
+    # a flag at 0 brings that pass's plain body back, at the same tile
+    "fwd_unpacked_by_flag": (
+        (*BF16_G4, {}, {"MAGI_ATTENTION_FFA_GQA_PACK": "0"}),
+        "fwd256x512 dq256x512g4 dkv256x512g4", "default"),
+    "fwd_and_dq_unpacked_by_flag": (
+        (*BF16_G4, {}, UNPACK_Q_MAJOR),
+        "fwd256x512 dq256x512 dkv256x512g4", "default"),
+    "dkv_unpacked_by_flag": (
+        (*BF16_G4, {}, {"MAGI_ATTENTION_FFA_GQA_PACK_DKV": "0"}),
+        "fwd256x512g4 dq256x512g4 dkv256x512", "default"),
+    # explicit settings win exactly as before, and read as pins: a packed
+    # body follows the pinned tile while the guard admits it
+    "argument_pin": (
+        (*BF16_G4, {"block_q": 128, "block_k": 256}, {}),
+        "fwd128x256g4 dq128x256g4 dkv128x256g4", "pin"),
+    "argument_pin_of_512": (
+        (*BF16_G4, {"block_q": 512}, {}),
+        "fwd512x512 dq512x512 dkv512x512g4", "pin"),
+    "env_pin": (
+        (*BF16_G4, {}, {"MAGI_ATTENTION_FFA_BLOCK_Q": "128"}),
+        "fwd128x512g4 dq128x512g4 dkv128x512g4", "pin"),
+    "env_pin_of_one_pass": (
+        (*BF16_G4, {}, {"MAGI_ATTENTION_FFA_BLOCK_Q_DKV": "128"}),
+        "fwd256x512g4 dq256x512g4 dkv128x512g4", "pin"),
+    "auto_tile": (
+        (*BF16_G4, {}, {"MAGI_ATTENTION_FFA_AUTO_TILE": "1"}),
+        None, "auto_tile"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_tiles_and_bodies_per_pass(monkeypatch, case):
+    """Shape -> per-pass tiles and bodies of an ``ffa_attn`` call, as the
+    registry's ``ffa_tiles`` decision records them: every pass runs at
+    ``default_blocks`` and buys its q rows a grid step by GQA-packing (g x
+    the tile's rows) wherever there is a group and the guard admits the
+    packed step; pins and the auto-tile policy choose tiles as before."""
+    import jax
+    import jax.numpy as jnp
+
+    from magiattention_tpu import telemetry
+    from magiattention_tpu.kernels import ffa_plan, registry
+    from magiattention_tpu.kernels.ffa import ffa_attn
+
+    (sq, sk, hq, hk, d, dv, dtype, kwargs, env), want, source = TILE_CASES[case]
+    for key in ("MAGI_ATTENTION_FFA_BLOCK_Q", "MAGI_ATTENTION_FFA_BLOCK_K"):
+        monkeypatch.delenv(key, raising=False)
+    for key, val in env.items():
+        monkeypatch.setenv(key, val)
+    registry.reset_registry()
+    ffa_plan._cached_plan.cache_clear()  # a cached plan writes no record
+    announced = []
+    monkeypatch.setattr(telemetry, "enabled", lambda: True)
+    monkeypatch.setattr(
+        telemetry, "record_event",
+        lambda kind, **rec: announced.append((kind, rec)))
+    qr = np.array([[0, sq]], np.int32)
+    kr = np.array([[0, sk]], np.int32)
+    shapes = [jax.ShapeDtypeStruct((n, h, e), getattr(jnp, dtype))
+              for n, h, e in ((sq, hq, d), (sk, hk, d), (sk, hk, dv))]
+    # traced, not run: the choice is made from shapes alone
+    jax.eval_shape(
+        lambda q, k, v: ffa_attn(q, k, v, qr, kr, [1], **kwargs), *shapes)
+    got = registry.last_choice("ffa_tiles")
+    if want is not None:
+        assert got == want
+    tiles = [rec for kind, rec in announced
+             if kind == "backend_select" and rec["decision"] == "ffa_tiles"]
+    assert [(t["choice"], t["source"]) for t in tiles] == [(got, source)]
+    # each pass's own plan shows in the ffa_plan records, tiles and counts
+    plans = {(rec["block_q"], rec["block_k"]) for kind, rec in announced
+             if kind == "ffa_plan"}
+    assert {(int(bq), int(bk))
+            for bq, bk in re.findall(r"(\d+)x(\d+)", got)} == plans

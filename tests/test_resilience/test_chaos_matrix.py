@@ -88,6 +88,8 @@ class TestLadderSemantics:
         # initial call + rung0 failed; rung1 (the 2nd ladder entry) won
         assert rt.builds == tile_ladder(512, 512)[:2]
         assert rt._auto_tile_pending is False
+        # a rung is an explicit tile: the ``ffa_tiles`` note says "pin"
+        assert rt._tile_source == "pin"
         assert rt._backend_override is None
 
     def test_reference_backend_is_the_last_rung(self, monkeypatch):
