@@ -2,11 +2,15 @@
 through ``magiattention_tpu.models.llama`` exactly as a user would.
 
 This is the only file of the benchmark that knows the program's entry
-points. It maps a configuration file (published key names) to
+points, and with ``reference_llama.py`` the only one that knows this
+block's equations. It maps a configuration file (published key names) to
 ``LlamaConfig``, a :class:`~cellbench.traffic_gen.MaskSpec` to a runtime key
 through the public mask compilers, makes the parameters on the devices
 already sharded, and exposes the train step, the loss-and-gradient program
-of the reference check, and the counts read from the plan objects.
+of the reference check with the reference and the names compared, the
+block's FLOP counts, and the counts read from the plan objects. The
+harness takes the members ``cellbench/manifest.py:FAMILY_INTERFACE`` lists
+(README.md says what each is).
 """
 
 from __future__ import annotations
@@ -31,7 +35,13 @@ from magiattention_tpu.kernels.ffa import _should_interpret
 from magiattention_tpu.models import llama
 from magiattention_tpu.resilience.fallback import resilience_event_counts
 
+from cellbench import flops, reference_llama
 from cellbench.traffic_gen import MaskSpec
+
+# The plain reference of this block and the names compared with it: the
+# harness asks the family, and this family's live beside it.
+reference = reference_llama.reference
+CHECKS = reference_llama.CHECKS
 
 # Rehearsal widths (``--rehearse-cpu``): sizes the Pallas interpreter
 # finishes in seconds. The group size 4 and head_dim 128 are kept so the
@@ -106,12 +116,12 @@ def train_step(params, mcfg, tokens, labels, key):
     return llama.train_step(params, mcfg, tokens, labels, key)
 
 
-
-
 def check_program(mcfg: llama.LlamaConfig, key):
-    """jitted ``(params, tokens, labels) -> loss, logits (natural order),
-    d loss / d layers[0].wq, d loss / d layers[0].wk`` through
-    ``llama.loss_fn``'s own pieces."""
+    """``(params, tokens, labels) -> {name: value}`` for the names of
+    ``CHECKS``: loss, logits (natural order), d loss / d layers[0].wq,
+    d loss / d layers[0].wk, through ``llama.loss_fn``'s own pieces. The
+    jitted program returns them as a tuple in that order (a dict would
+    come out sorted: another program, and at cp 4 other roundings)."""
 
     def f(wq, wk, params, tokens, labels):
         lyr0 = {**params["layers"][0], "wq": wq, "wk": wk}
@@ -128,7 +138,45 @@ def check_program(mcfg: llama.LlamaConfig, key):
           tokens, labels)
         return loss, logits, gq, gk
 
-    return run
+    def named(params, tokens, labels) -> dict:
+        return dict(zip(CHECKS, run(params, tokens, labels)))
+
+    return named
+
+
+def layer_matmul_params(cfg: dict) -> int:
+    """Weights of one block's seven projections."""
+    dim, dh = cfg["hidden_size"], cfg["head_dim"]
+    hq, hk = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    return (
+        dim * hq * dh + 2 * dim * hk * dh + hq * dh * dim
+        + 3 * dim * cfg["intermediate_size"]
+    )
+
+
+def required_flops_per_step(cfg: dict, spec: MaskSpec) -> int:
+    """Required convention (``cellbench/flops.py``): forward + backward of
+    the whole step; every layer attends over the mask's band area."""
+    layers = cfg["num_hidden_layers"]
+    matmul = flops.matmul_flops(
+        layers * layer_matmul_params(cfg)
+        + cfg["hidden_size"] * cfg["vocab_size"],  # untied head; embed is a gather
+        spec.tokens)
+    attn = layers * (1 + flops.ATTN_BWD_OVER_FWD) * flops.attn_fwd_flops(
+        flops.band_area(spec), cfg["num_attention_heads"],
+        cfg["head_dim"], cfg["head_dim"])
+    return int(matmul + attn)
+
+
+def ffa_calls(cfg: dict) -> list[dict]:
+    """The step's FFA calls for ``flops.ffa_least_seconds``: every layer
+    attends, and under ``remat`` (``model_config``) makes three calls a
+    step, forward, re-forward and backward."""
+    return [{
+        "layers": cfg["num_hidden_layers"], "passes": ("fwd", "fwd", "bwd"),
+        "hq": cfg["num_attention_heads"], "hk": cfg["num_key_value_heads"],
+        "d_qk": cfg["head_dim"], "d_v": cfg["head_dim"],
+    }]
 
 
 def plan_facts(key, spec_rows_area: np.ndarray) -> dict:

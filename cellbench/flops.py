@@ -1,13 +1,19 @@
-"""Operations and bytes a cell's step needs, from shapes and the mask alone.
+"""Arithmetic on a mask: areas, and the operations and bytes of an
+attention call over it. What depends on a block's equations (which layers
+attend, how many weights a layer multiplies by) is the family file's:
+``required_flops_per_step(cfg, spec)`` and ``ffa_calls(cfg)`` of
+``family_<family>.py``.
 
 Two conventions, always named:
 
 * **required** (model FLOPs): what forward and backward need, recomputation
   not counted. A matmul with ``p`` weights costs ``2 p`` per token forward
-  and ``4 p`` backward; attention forward is ``4 * area * head_dim *
-  q_heads`` (QK^T and PV over the ``area`` unmasked pairs) and its backward
-  2.5 times that (five matmuls against two). This is the reference's
-  convention and ``BASELINE.md``'s.
+  and ``4 p`` backward (:func:`matmul_flops`); attention forward is ``2 *
+  area * q_heads * (d_qk + d_v)`` (QK^T and PV over the ``area`` unmasked
+  pairs; ``4 * area * head_dim * q_heads`` where the two are equal) and its
+  backward 2.5 times that where they are (five matmuls against two, three
+  of them over ``d_qk``). This is the reference's convention and
+  ``BASELINE.md``'s.
 * **executed by the FFA calls**: under ``remat`` the forward runs twice, so
   a layer makes three calls a step (forward, re-forward, backward) worth
   ``(1 + 1 + 2.5) = 4.5`` forwards. Only the kernel's roofline uses it.
@@ -65,62 +71,59 @@ def keys_needed(spec: MaskSpec, rows: np.ndarray) -> int:
     return int(np.count_nonzero(np.cumsum(need[:-1])))
 
 
-def layer_matmul_params(cfg: dict) -> int:
-    """Weights of one block's seven projections."""
-    dim, dh = cfg["hidden_size"], cfg["head_dim"]
-    hq, hk = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    return (
-        dim * hq * dh + 2 * dim * hk * dh + hq * dh * dim
-        + 3 * dim * cfg["intermediate_size"]
-    )
+def matmul_flops(weights: int, tokens: int) -> int:
+    """Required convention: ``tokens`` rows through matrices of ``weights``
+    entries in all, forward (2 a weight) and backward (4)."""
+    return 6 * tokens * weights
 
 
-def attn_fwd_flops(cfg: dict, area: int) -> int:
-    """One layer's attention forward over ``area`` unmasked pairs."""
-    return 4 * area * cfg["head_dim"] * cfg["num_attention_heads"]
+def attn_fwd_flops(area: int, q_heads: int, d_qk: int, d_v: int) -> int:
+    """One attention forward over ``area`` unmasked pairs a head: QK^T over
+    ``d_qk``, PV over ``d_v``."""
+    return 2 * area * q_heads * (d_qk + d_v)
 
 
-def model_flops_per_step(cfg: dict, spec: MaskSpec) -> int:
-    """Required convention: forward + backward of the whole step."""
-    layers = cfg["num_hidden_layers"]
-    matmul = 6 * spec.tokens * (
-        layers * layer_matmul_params(cfg)
-        + cfg["hidden_size"] * cfg["vocab_size"]  # untied head; embed is a gather
-    )
-    attn = layers * (1 + ATTN_BWD_OVER_FWD) * attn_fwd_flops(
-        cfg, band_area(spec))
-    return int(matmul + attn)
+def attn_bwd_flops(area: int, q_heads: int, d_qk: int, d_v: int) -> int:
+    """Its backward: the scores again, dQ and dK over ``d_qk``, dP and dV
+    over ``d_v``; ``ATTN_BWD_OVER_FWD`` forwards where the two are equal."""
+    return 2 * area * q_heads * (3 * d_qk + 2 * d_v)
 
 
 def ffa_least_seconds(
-    cfg: dict, spec: MaskSpec, rows: np.ndarray, peaks: dict
+    calls: list[dict], spec: MaskSpec, rows: np.ndarray, peaks: dict
 ) -> dict[str, float]:
     """The least time one device could spend in the step's FFA calls when
     it owns the query rows ``rows``: per call the larger of FLOPs over peak
-    FLOP/s and bytes over peak bytes/s (each tensor read or written once),
-    summed over the three calls of each layer. Returns the seconds, and
-    the seconds each bound alone would give, so that a reader can say which
-    binds."""
+    FLOP/s and bytes over peak bytes/s (each tensor read or written once).
+
+    ``calls`` is the family's ``ffa_calls(cfg)``: one entry per group of
+    like layers, ``{"layers": n, "passes": ("fwd", "fwd", "bwd"), "hq",
+    "hk", "d_qk", "d_v"}``: ``n`` layers that each make those calls a step
+    (a re-forward under remat is a ``"fwd"`` of its own). Returns the
+    seconds, and the seconds each bound alone would give, so that a reader
+    can say which binds."""
     area = int(rows_area(spec)[rows].sum())
-    dh, hq = cfg["head_dim"], cfg["num_attention_heads"]
-    hk = cfg["num_key_value_heads"]
-    q_bytes = len(rows) * hq * dh * 2          # bf16 q, o, do, dq
-    kv_bytes = keys_needed(spec, rows) * hk * dh * 2   # each of k, v, dk, dv
-    lse_bytes = len(rows) * hq * 4             # fp32 lse, delta
-    fwd = attn_fwd_flops(cfg, area)
-    calls = {
-        "fwd": (fwd, 2 * q_bytes + 2 * kv_bytes + lse_bytes),
-        "refwd": (fwd, 2 * q_bytes + 2 * kv_bytes + lse_bytes),
-        "bwd": (ATTN_BWD_OVER_FWD * fwd,
-                4 * q_bytes + 4 * kv_bytes + 2 * lse_bytes),
-    }
-    layers = cfg["num_hidden_layers"]
-    by_flops = layers * sum(
-        f / peaks["bf16_flops"] for f, _ in calls.values())
-    by_bytes = layers * sum(
-        b / peaks["hbm_bytes_per_s"] for _, b in calls.values())
-    least = layers * sum(
-        max(f / peaks["bf16_flops"], b / peaks["hbm_bytes_per_s"])
-        for f, b in calls.values()
-    )
-    return {"least_s": least, "flops_s": by_flops, "bytes_s": by_bytes}
+    keys = keys_needed(spec, rows)
+    out = {"least_s": 0.0, "flops_s": 0.0, "bytes_s": 0.0}
+    for group in calls:
+        hq, hk = group["hq"], group["hk"]
+        d_qk, d_v = group["d_qk"], group["d_v"]
+        fwd_bytes = (
+            len(rows) * hq * (d_qk + d_v) * 2   # bf16 q and o
+            + keys * hk * (d_qk + d_v) * 2      # bf16 k and v
+            + len(rows) * hq * 4)               # fp32 lse
+        one = {
+            "fwd": (attn_fwd_flops(area, hq, d_qk, d_v), fwd_bytes),
+            # and dq, do, dk, dv, delta: every tensor of the forward twice
+            "bwd": (attn_bwd_flops(area, hq, d_qk, d_v), 2 * fwd_bytes),
+        }
+        passes = [one[p] for p in group["passes"]]
+        n = group["layers"]
+        out["flops_s"] += n * sum(
+            f / peaks["bf16_flops"] for f, _ in passes)
+        out["bytes_s"] += n * sum(
+            b / peaks["hbm_bytes_per_s"] for _, b in passes)
+        out["least_s"] += n * sum(
+            max(f / peaks["bf16_flops"], b / peaks["hbm_bytes_per_s"])
+            for f, b in passes)
+    return out

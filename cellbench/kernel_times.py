@@ -14,6 +14,10 @@ A body's name is looked for anywhere in the instruction's name, the longest
 body first: where a kernel's scope is the outermost one, JAX decorates it
 with the transform (``transpose_jvp_magi_bwd_dq_kernel__.3``).
 
+Only instructions of the two FFA classes are read: a kernel that is not
+FFA carries the prefix too (``magi_<body>``), is classed apart by a file of
+``event_classes.d/`` and has readers of its own, and moves nothing here.
+
 A program that names no instruction so (a commit before the names) has
 nothing to read: every reader returns ``None`` and the metric is left out.
 """
@@ -41,6 +45,11 @@ def instruction_name(label: str) -> str:
     return label.split(":", 1)[1].split(" ", 1)[0]
 
 
+def event_class(label: str) -> str:
+    """The class of a ``DeviceTimes.ops`` key."""
+    return label.split(":", 1)[0]
+
+
 def kind_of(name: str) -> str | None:
     """Which kind of FFA body the instruction ``name`` is: a key of
     ``BODIES``, ``"other"`` for a name that carries the prefix and no body
@@ -52,16 +61,18 @@ def kind_of(name: str) -> str | None:
 
 
 def ms_per_step_by_kind(trace) -> dict[str, float] | None:
-    """``{kind: self milliseconds per step}`` of the named kernels over the
-    traced window, mean over the devices; every kind of ``BODIES`` and
-    ``"other"`` is there (0.0 where none ran). ``None`` without a trace,
-    and where no instruction carries the prefix."""
+    """``{kind: self milliseconds per step}`` of the named kernels of the
+    FFA classes over the traced window, mean over the devices; every kind
+    of ``BODIES`` and ``"other"`` is there (0.0 where none ran). ``None``
+    without a trace, and where no such instruction carries the prefix."""
     if trace is None:
         return None
     ns = dict.fromkeys((*BODIES, "other"), 0.0)
     named = False
     for device in trace.devices.values():
         for label, self_ns in device.ops.items():
+            if event_class(label) not in FFA_CLASSES:
+                continue
             kind = kind_of(instruction_name(label))
             if kind is not None:
                 named = True
@@ -79,8 +90,9 @@ def ms_per_step(ctx, kind: str) -> float | None:
 
 
 def bodies_sum_over_ffa(ctx) -> float | None:
-    """Time of every instruction that carries the prefix over the time of
-    the two FFA classes, %: under 100 when a call site lost its name."""
+    """Time of every instruction of the two FFA classes that carries the
+    prefix over the time of those classes, %: under 100 when a call site
+    lost its name."""
     times = ms_per_step_by_kind(ctx.trace)
     if times is None:
         return None

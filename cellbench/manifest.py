@@ -92,11 +92,28 @@ def load_generator(root: str, name: str):
     return mod.generate
 
 
+# Every member the harness and the metric readers take from a family file
+# (README.md, "The family interface", says what each is).
+FAMILY_INTERFACE = (
+    "TOY", "model_config", "init_params", "make_key", "timed_plan",
+    "plan_facts", "train_step", "check_program", "reference", "CHECKS",
+    "required_flops_per_step", "ffa_calls", "pallas_kernels", "what_ran",
+)
+
+
 def load_family(root: str, family: str):
-    """The step builder of a model family, ``family_<family>.py``."""
+    """The step builder of a model family, ``family_<family>.py``; a file
+    that lacks a member of ``FAMILY_INTERFACE`` fails here, naming it, and
+    not after the measured window."""
     mod = load_module(root, "family_" + family)
     if mod is None:
         raise KeyError(f"no step builder cellbench/family_{family}.py")
+    missing = [name for name in FAMILY_INTERFACE if not hasattr(mod, name)]
+    if missing:
+        raise AttributeError(
+            f"cellbench/family_{family}.py lacks {', '.join(missing)}: a "
+            f"family file has every member of manifest.FAMILY_INTERFACE "
+            f"(cellbench/README.md)")
     return mod
 
 

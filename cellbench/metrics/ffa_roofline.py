@@ -10,8 +10,9 @@ def read(ctx):
     spent = ctx.trace.self_ms_per_step(["ffa_fwd", "ffa_bwd"]) * 1e-3
     if not spent:
         return None
+    calls = ctx.family.ffa_calls(ctx.config)
     least = [
-        flops.ffa_least_seconds(ctx.config, ctx.spec, rows, ctx.peaks)
+        flops.ffa_least_seconds(calls, ctx.spec, rows, ctx.peaks)
         for rows in ctx.facts["rank_rows"]
     ]
     mean_least = sum(x["least_s"] for x in least) / len(least)

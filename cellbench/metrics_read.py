@@ -28,6 +28,7 @@ class Context:
     """What a metric reader may look at."""
 
     cell: Cell
+    family: object            # the cell's family_<family>.py (its counts)
     config: dict              # the configuration as run
     spec: MaskSpec            # the mask of the measured steps
     peaks: dict               # cellbench.peaks entry of the device

@@ -12,7 +12,10 @@ checks the program against the plain reference, and prints as the last line
 of stdout one JSON object::
 
     {"correct": ..., "attempted": ..., "failed": ..., "metrics": {...},
-     "device": {...}}            (and "breakdown" with --trace 1)
+     "device": {...}, ["breakdown": {...},] "checks": {...}}
+
+(``breakdown`` with ``--trace 1``; ``checks`` is every number the comparison
+read beside its limit, and the same are the last lines of stderr.)
 
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` the run sets ``MAGI_ATTENTION_PROFILE_MODE=1``, profiles
@@ -88,10 +91,11 @@ def refuse_hidden_device_env() -> None:
 
 def result_line(
     correct: bool, attempted: int, failed: int, metrics: dict, units: dict,
-    device: dict, breakdown: dict | None,
+    device: dict, breakdown: dict | None, checks: dict,
 ) -> str:
-    """The one line the driver reads: exactly the contract's keys, every
-    value as measured."""
+    """The one line the driver reads: the contract's keys, every value as
+    measured, and last ``checks``: each number the comparison read beside
+    its limit."""
     out = {
         "correct": bool(correct),
         "attempted": int(attempted),
@@ -104,6 +108,9 @@ def result_line(
     }
     if breakdown is not None:
         out["breakdown"] = breakdown
+    out["checks"] = {
+        name: {"err": c["err"], "limit": c["tol"]}
+        for name, c in checks.items()}
     return json.dumps(out)
 
 
@@ -121,6 +128,15 @@ def peak_hbm_bytes(devices) -> tuple[int | None, list]:
         for s in stats if s
     ]
     return (max(held) if held else None), stats
+
+
+def events_since(before: dict, now: dict) -> dict:
+    """The resilience events counted between two readings of the program's
+    per-process counters. ``correct`` judges the run, not the process: a
+    test worker's earlier tests may have left counts behind (on the chip a
+    process starts at zero, so the two are the same there)."""
+    return {k: n - before.get(k, 0) for k, n in now.items()
+            if n != before.get(k, 0)}
 
 
 def cell_sizes(cell, family, rehearse: int) -> tuple[dict, int, int | None, int]:
@@ -199,13 +215,13 @@ def traced_steps(step, params, batches, trace_dir: str) -> dict:
 def reference_check(
     family, mcfg, cfg: dict, spec, params, batch, mesh, devices, say
 ) -> dict:
-    """Compare the program's loss, logits and two gradients on ``spec``
-    with the plain reference on the same parameters and tokens."""
+    """Compare what the family's check program returns for ``spec`` with
+    the family's plain reference on the same parameters and tokens, name by
+    name as the family's ``CHECKS`` says."""
     import jax
     import jax.numpy as jnp
-    from functools import partial
 
-    from cellbench import flops, reference
+    from cellbench import reference
 
     key = family.make_key(spec, mesh)
     toks, labels = (jnp.asarray(x) for x in batch)
@@ -213,17 +229,12 @@ def reference_check(
     got = jax.device_get(family.check_program(mcfg, key)(params, toks, labels))
     t1 = time.perf_counter()
     one = devices[0]
-    ref_fn = jax.jit(partial(reference.loss_logits_grads, cfg=cfg))
-    ref = jax.device_get(ref_fn(
-        jax.device_put(params, one),
-        tokens=jax.device_put(toks, one), labels=jax.device_put(labels, one),
-        mask=jax.device_put(flops.mask_array(spec), one),
-    ))
+    ref = jax.device_get(family.reference(
+        jax.device_put(params, one), cfg, jax.device_put(toks, one),
+        jax.device_put(labels, one), spec))
     t2 = time.perf_counter()
-    names = ("loss", "logits", "grad_wq0", "grad_wk0")
     checks = reference.compare(
-        dict(zip(names, got)), dict(zip(names, ref)),
-        targets=int((batch[1] >= 0).sum()))
+        got, ref, family.CHECKS, targets=int((batch[1] >= 0).sum()))
     for name, c in checks.items():
         say(f"  check {name}: err={c['err']:.3e} tol={c['tol']:.1e} "
             f"{'ok' if c['ok'] else 'FAILED'}")
@@ -309,6 +320,7 @@ def main(argv: list[str]) -> int:
 
     # -- build the cell from the seed --------------------------------------
     family = manifest.load_family(args.root, cell.config["family"])
+    events_before = family.what_ran()["resilience_events"]
     generate = manifest.load_generator(args.root, cell.traffic["generator"])
     traffic = cell.traffic
     cfg, tokens, window, check_tokens = cell_sizes(cell, family, rehearse)
@@ -326,7 +338,7 @@ def main(argv: list[str]) -> int:
             spec, cfg["vocab_size"], args.seed, traffic["batches"])
     ]
     plan = family.plan_facts(key, flops.rows_area(spec))
-    say(f"cell: {cfg['num_hidden_layers']} layers, {spec.tokens} tokens, "
+    say(f"cell: family {cfg['family']}, {spec.tokens} tokens, "
         f"{len(spec.cu_seqlens) - 1} documents, window {spec.window}, "
         f"band area {flops.band_area(spec)}; plan {plan_ms:.1f} ms, "
         f"{plan['slices']} slices, chunk {plan['chunk_size']}, overlap "
@@ -373,7 +385,7 @@ def main(argv: list[str]) -> int:
         events = trace_reduce.load_xplane(traced["xplane"], HOST_SPANS)
         if events.devices or not rehearse:  # the CPU has no device plane
             reduction = trace_reduce.reduce_trace(
-                events, trace_reduce.load_classes(), TRACED_STEPS)
+                events, trace_reduce.load_classes(args.root), TRACED_STEPS)
         say(f"traced {TRACED_STEPS} steps: "
             f"{[round(x) for x in traced['step_ms']]} ms, {traced['xplane']}")
 
@@ -386,6 +398,8 @@ def main(argv: list[str]) -> int:
             check_spec, cfg["vocab_size"], args.seed, 1)[0],
         mesh, devices, say)
     ran = family.what_ran()
+    ran["resilience_events"] = events_since(
+        events_before, ran["resilience_events"])
     interpreted = [k for k, i in kernels.items() if i]
     flags = {
         "reference": all(c["ok"] for c in checks.values()),
@@ -404,7 +418,7 @@ def main(argv: list[str]) -> int:
     breakdown = None
     if args.trace:
         ctx = metrics_read.Context(
-            cell=cell, config=cfg, spec=spec,
+            cell=cell, family=family, config=cfg, spec=spec,
             # a rehearsal only exercises the readers; it prints no result
             peaks=peaks.peaks_for(
                 "TPU v5 lite" if rehearse else device["kind"]),
@@ -445,7 +459,12 @@ def main(argv: list[str]) -> int:
                             else f"FAILED: {flags}"))
         return 0 if correct else 1
     print(result_line(correct, steps, run["failed"], metrics, units, device,
-                      breakdown), flush=True)
+                      breakdown, checks), flush=True)
+    for name, c in checks.items():
+        print(f"check {name}: err {c['err']!r} limit {c['tol']!r}",
+              file=sys.stderr)
+    print("flags: " + " ".join(f"{k}={v}" for k, v in flags.items()),
+          file=sys.stderr, flush=True)
     return 0
 
 

@@ -21,9 +21,10 @@ instruction name, opcode, custom-call target and result type:
   minus that of events nested in it (none on a v5e today; control
   operations would be), self intervals are disjoint and their union is the
   device's busy time.
-* Every event falls in exactly one **class** (``event_classes.json``: the
-  first pattern that matches ``"<name> <text>"``), so busy = the sum of the
-  classes' self times and a step closes: classes + idle = window.
+* Every event falls in exactly one **class** (``event_classes.d/*.json``,
+  then ``event_classes.json``: the first pattern that matches ``"<name>
+  <text>"``), so busy = the sum of the classes' self times and a step
+  closes: classes + idle = window.
 * A collective's time **in flight** is the union of its events on either
   line (the asynchronous line spans start to done); only its events on the
   op line keep the core from computing, so its **exposed** time is their
@@ -32,6 +33,7 @@ instruction name, opcode, custom-call target and result type:
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import re
@@ -139,16 +141,35 @@ def load_events(path: str) -> Trace:
                  [Event(*e) for e in raw["host"]])
 
 
-def load_classes() -> list[tuple[str, re.Pattern]]:
-    """``[(class, compiled pattern)]`` of ``event_classes.json`` in file
-    order; first match wins."""
-    path = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "event_classes.json")
-    with open(path, encoding="utf-8") as f:
-        raw = json.load(f)
+def _class_files(root: str | None) -> list[str]:
+    """``event_classes.d/*.json`` in the order of their file names, then
+    ``event_classes.json``; each looked for under ``root`` and under this
+    checkout (as ``manifest.load_module`` looks), ``root``'s file standing
+    in for one of the same name."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    bases = [here] if root is None else [os.path.join(root, "cellbench"), here]
+    extra = {
+        os.path.basename(path): path for base in reversed(bases)
+        for path in glob.glob(os.path.join(base, "event_classes.d", "*.json"))}
+    last = next(
+        path for base in bases
+        if os.path.isfile(path := os.path.join(base, "event_classes.json")))
+    return [extra[name] for name in sorted(extra)] + [last]
+
+
+def load_classes(root: str | None = None) -> list[tuple[str, re.Pattern]]:
+    """``[(class, compiled pattern)]``, first match wins: the classes of
+    every ``event_classes.d/<name>.json`` (a file a later PR adds for a new
+    kernel or collective, same format, in file-name order), then those of
+    ``event_classes.json`` in file order. A file may name a class that is
+    there already: its patterns are then tried earlier."""
+    classes = []
+    for path in _class_files(root):
+        with open(path, encoding="utf-8") as f:
+            classes += json.load(f)["classes"]
     return [
         (c["class"], re.compile("|".join(f"(?:{p})" for p in c["patterns"])))
-        for c in raw["classes"]
+        for c in classes
     ]
 
 

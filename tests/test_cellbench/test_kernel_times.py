@@ -8,12 +8,14 @@ kernels), worked out by hand."""
 import json
 import os
 
+import numpy as np
 import pytest
 
 from cellbench import kernel_times as kt
-from cellbench import manifest, metrics_read
+from cellbench import manifest, metrics_read, peaks
 from cellbench import trace_reduce as tr
 from cellbench.trace_reduce import Event
+from cellbench.traffic_gen import MaskSpec
 
 CLASSES = tr.load_classes()
 # the kernels' result types at cp 4, as the recorded traces have them
@@ -50,15 +52,17 @@ NAMED = {"fwd": "magi_fwd_kernel", "delta": "magi_delta_kernel",
          "dq": "magi_bwd_dq_kernel", "dkv": "magi_bwd_dkv_kernel_gqa"}
 
 
-def _ctx(devices: dict[int, list[Event]] | None, steps: int = 1):
+def _ctx(devices: dict[int, list[Event]] | None, steps: int = 1,
+         classes=CLASSES):
     trace = None
     if devices is not None:
         host = [Event("step_dispatch", 0, 2 * MS),
                 Event("loss_readback", 2 * MS, 398 * MS)]
-        trace = tr.reduce_trace(tr.Trace(devices, {}, host), CLASSES, steps)
+        trace = tr.reduce_trace(tr.Trace(devices, {}, host), classes, steps)
     # the readers of this file look at the trace alone
     return metrics_read.Context(
-        cell=None, config={}, spec=None, peaks={}, facts={}, trace=trace)
+        cell=None, family=None, config={}, spec=None, peaks={}, facts={},
+        trace=trace)
 
 
 def _read(ctx) -> dict:
@@ -210,3 +214,100 @@ def test_the_manifest_lists_the_five_for_every_cell():
         assert (entry["layer"], entry["source"], entry["moves"]) == (
             "ffa", "device_trace", "tokens_per_s")
     assert per_layer["ffa_bodies_sum_over_ffa"]["better"] == "higher"
+
+
+# -- a kernel that is not FFA ------------------------------------------------
+
+SECOND_FAMILY = os.path.join(os.path.dirname(__file__), "data", "second_family")
+GATE = "custom-call tpu_custom_call -> (bf16[8192,30,192], f32[30,8192,96])"
+GATE_BWD = "custom-call tpu_custom_call -> f32[8192,30,192]"
+FFA_METRICS = (
+    "ffa_ms_per_step", "ffa_fwd_ms_per_step", "ffa_bwd_ms_per_step",
+    "ffa_roofline", *NEW_METRICS)
+
+
+def _with_gate_kernels(events: list[Event]) -> list[Event]:
+    """The step with a forward and a backward call of another Pallas
+    kernel after it, 11 and 17 ms: same target, result types that read as
+    FFA's forward and backward, and the library's prefix."""
+    at = max(e.end for e in events)
+    return events + [
+        Event("magi_gate_fwd_kernel.7", at, 11 * MS, GATE),
+        Event("magi_gate_bwd_kernel.8", at + 11 * MS, 17 * MS, GATE_BWD)]
+
+
+def _roofline_ctx(devices, classes):
+    """A context ``ffa_roofline`` can read too: the second family's counts,
+    a causal document and the chip's peaks."""
+    ctx = _ctx(devices, classes=classes)
+    ctx.family = manifest.load_family(SECOND_FAMILY, "mixer")
+    with open(os.path.join(
+            SECOND_FAMILY, "cellbench/configs/mixer-toy.json")) as f:
+        ctx.config = json.load(f)
+    ctx.spec = MaskSpec(8192, (0, 8192))
+    ctx.peaks = peaks.peaks_for("TPU v5 lite")
+    ctx.facts = {"rank_rows": [np.arange(8192)]}
+    return ctx
+
+
+def test_a_kernel_outside_ffa_moves_no_ffa_metric():
+    """With its class file, the other kernel's calls are in classes of
+    their own, its own metric reads them, and every ``ffa_*`` metric reads
+    as it does on the step without them."""
+    classes = tr.load_classes(SECOND_FAMILY)
+    alone = _roofline_ctx({0: _device(NAMED)}, classes)
+    beside = _roofline_ctx({0: _with_gate_kernels(_device(NAMED))}, classes)
+    want = {m: metrics_read.read_metric(manifest.ROOT, m, alone)
+            for m in FFA_METRICS}
+    assert want["ffa_ms_per_step"] == pytest.approx(130)
+    assert want["ffa_bodies_sum_over_ffa"] == pytest.approx(100.0)
+    # one attending layer of four, two calls a step: the least time of 3.5
+    # forwards over a causal 8192 at 8 heads of 128, over 130 ms
+    area = 8192 * 8193 // 2
+    assert want["ffa_roofline"] == pytest.approx(
+        100 * 3.5 * 4 * area * 128 * 8 / 197e12 / 0.130)
+    assert {m: metrics_read.read_metric(manifest.ROOT, m, beside)
+            for m in FFA_METRICS} == want
+    times = beside.trace.devices[0]
+    assert times.self_ns["gate_fwd"] == 11 * MS
+    assert times.self_ns["gate_bwd"] == 17 * MS
+    assert metrics_read.read_metric(
+        SECOND_FAMILY, "gate_ms_per_step", beside) == pytest.approx(28)
+    assert alone.trace.devices[0].self_ns["gate_fwd"] == 0.0
+    # and the step still closes: every event is in exactly one class
+    assert sum(times.self_ns.values()) == pytest.approx(times.busy_ns)
+
+
+def test_without_its_class_file_the_other_kernel_reads_as_ffa():
+    """What the file is for: by target and result type alone the calls
+    are ``ffa_fwd`` and ``ffa_bwd`` (they carry the prefix and no FFA
+    body's name, so the bodies' closure stays at 100 and hides nothing)."""
+    ctx = _ctx({0: _with_gate_kernels(_device(NAMED))})
+    assert metrics_read.read_metric(
+        manifest.ROOT, "ffa_ms_per_step", ctx) == pytest.approx(130 + 28)
+    assert kt.ms_per_step(ctx, "other") == pytest.approx(28)
+
+
+def test_the_classes_of_event_classes_json_are_the_parents():
+    """``event_classes.json``'s classes and patterns as PR 25 had them, last
+    in the list, and before them only what files of ``event_classes.d/``
+    bring (none today): the recorded traces class as they did."""
+    assert [(c, p.pattern) for c, p in CLASSES][-5:] == [
+        ("ffa_fwd", r"(?:custom-call tpu_custom_call -> \((bf16|f16)\[)"),
+        ("ffa_bwd", "(?:custom-call tpu_custom_call)"),
+        ("group_comm",
+         r"(?:^(ragged_all_to_all|all_to_all|ppermute|collective_permute|"
+         r"psum|pmax|pmin|all_gather|reduce_scatter|psum_scatter)\b)|"
+         "(?: ragged-all-to-all)"),
+        ("param_comm",
+         "(?: (all-gather|all-reduce|reduce-scatter|all-to-all|"
+         "collective-permute|collective-broadcast)(-start|-done)? )"),
+        ("other_compute", "(?:)"),
+    ]
+    folder = os.path.join(manifest.ROOT, "cellbench", "event_classes.d")
+    added = 0
+    for name in os.listdir(folder) if os.path.isdir(folder) else ():
+        if name.endswith(".json"):
+            with open(os.path.join(folder, name)) as f:
+                added += len(json.load(f)["classes"])
+    assert len(CLASSES) == added + 5

@@ -104,3 +104,66 @@ def test_unknown_names_raise():
         manifest.load_generator(ROOT, "no_such_generator")
     with pytest.raises(KeyError, match="no step builder"):
         manifest.load_family(ROOT, "no_such_family")
+
+
+def test_a_family_file_that_lacks_a_member_fails_at_load(tmp_path):
+    """Naming what is missing, before anything is built or measured: not
+    an ``AttributeError`` after the window."""
+    src = os.path.join(ROOT, "cellbench", "family_llama.py")
+    text = open(src).read()
+    assert "def ffa_calls(" in text and "CHECKS = " in text
+    text = text.replace("def ffa_calls(", "def ffa_kalls(").replace(
+        "CHECKS = ", "CHEKS = ")
+    os.makedirs(tmp_path / "cellbench")
+    (tmp_path / "cellbench" / "family_partial.py").write_text(text)
+    with pytest.raises(AttributeError, match=r"family_partial\.py lacks "
+                       r"CHECKS, ffa_calls: .*FAMILY_INTERFACE"):
+        manifest.load_family(str(tmp_path), "partial")
+    whole = manifest.load_family(ROOT, "llama")
+    assert all(hasattr(whole, name) for name in manifest.FAMILY_INTERFACE)
+
+
+def test_the_readme_lists_the_whole_family_interface():
+    text = open(os.path.join(ROOT, "cellbench", "README.md")).read()
+    table = text[text.index("## The family interface"):]
+    for name in manifest.FAMILY_INTERFACE:
+        assert f"| `{name}" in table, name
+
+
+# What belongs to one block's equations, and may be named only in its
+# family file and its reference: parameter leaves, the llama block's width
+# keys and projection count, the depth (a product of it and one layer's FFA
+# calls is how a hybrid would read four times too high), a block's
+# reference and its compared names.
+BLOCK_WORDS = re.compile(
+    r"\b(wq|wk|wv|wo|w_gate|w_up|w_down|attn_norm|mlp_norm|lm_head|"
+    r"grad_wq0|grad_wk0|num_hidden_layers|intermediate_size|"
+    r"num_attention_heads|num_key_value_heads|layer_matmul_params|"
+    r"model_flops_per_step|loss_logits_grads|reference_[a-z0-9]+|"
+    r"family_[a-z0-9]+)\b")
+HARNESS = ["run.py", "flops.py", "metrics_read.py", "trace_reduce.py",
+           "reference.py", "manifest.py", "kernel_times.py", "peaks.py"]
+
+
+def _harness_files():
+    metrics = os.path.join(ROOT, "cellbench", "metrics")
+    return [os.path.join(ROOT, "cellbench", f) for f in HARNESS] + sorted(
+        os.path.join(metrics, f) for f in os.listdir(metrics)
+        if f.endswith(".py"))
+
+
+@pytest.mark.parametrize(
+    "path", _harness_files(), ids=lambda p: os.path.relpath(p, ROOT))
+def test_the_harness_names_nothing_of_a_block(path):
+    """``family_<family>`` may appear as the pattern the loader fills in
+    and in prose that says where a block's things live, never as a module
+    that is imported."""
+    found = []
+    for n, line in enumerate(open(path), 1):
+        for m in BLOCK_WORDS.finditer(line):
+            word = m.group(1)
+            if word.startswith(("family_", "reference_")) and not re.search(
+                    r"^\s*(from|import)\b.*\b" + word, line):
+                continue
+            found.append((n, word))
+    assert not found, found

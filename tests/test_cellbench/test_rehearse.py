@@ -6,10 +6,12 @@ nothing and prints no result line."""
 import json
 import os
 import shutil
+import statistics
 
 import pytest
+from magiattention_tpu.models import llama
 
-from cellbench import manifest, run
+from cellbench import flops, manifest, run, trace_reduce, traffic_gen
 
 MANIFEST = json.load(open(os.path.join(manifest.ROOT, "BENCHMARK.json")))
 
@@ -55,6 +57,60 @@ def test_every_cell_rehearses_end_to_end(clean_env, capsys, cell, chips, trace):
         assert len(report["traced_step_ms"]) == run.TRACED_STEPS
     else:
         assert set(report["metrics"]) == {"tokens_per_s", "setup_s"}
+
+
+def test_correct_judges_the_resilience_events_of_the_run_itself(
+    clean_env, capsys, monkeypatch
+):
+    """An earlier test of the same worker may have left the program's
+    per-process counters non-zero (ROADMAP C0): the run counts from its own
+    start, and an event inside it still fails ``no_resilience_event``."""
+    from magiattention_tpu.resilience import fallback
+
+    monkeypatch.setitem(fallback._EVENT_COUNTS, "retry@an_earlier_test", 2)
+    report = _rehearse(
+        capsys, "--workload", MANIFEST["workloads"][0]["name"], "--seed",
+        "6", "--rehearse-cpu", "1")
+    assert report["flags"]["no_resilience_event"]
+    assert report["what_ran"]["resilience_events"] == {}
+    assert run.events_since({"a@b": 2}, {"a@b": 2}) == {}
+    assert run.events_since({"a@b": 2}, {"a@b": 3, "c@d": 1}) == {
+        "a@b": 1, "c@d": 1}
+
+
+_MASKED_CE, _ROPE = llama.masked_ce, llama._rope
+
+
+def _half_the_batch_left_out(logits, labels):
+    """The mean over the first half of the positions alone."""
+    return _MASKED_CE(logits, labels.at[labels.shape[0] // 2:].set(-1))
+
+
+def _positions_dropped(x, pos, theta):
+    return _ROPE(x, pos * 0, theta)
+
+
+@pytest.mark.parametrize("name,broken,fails", [
+    ("masked_ce", _half_the_batch_left_out, {"grad_wq0", "grad_wk0"}),
+    ("_rope", _positions_dropped, {"logits", "grad_wq0", "grad_wk0"}),
+])
+def test_a_broken_program_is_not_correct(
+    clean_env, capsys, monkeypatch, name, broken, fails
+):
+    """The rest of a run with the program broken underneath: half of the
+    batch left out of the loss (the mean taken over the rest), and every
+    token at position 0. ``correct`` comes out false, by the numbers
+    named, and the rehearsal's exit code says so."""
+    monkeypatch.setattr(llama, name, broken)
+    cell = next(w for w in MANIFEST["workloads"] if w["traffic"] == "packed")
+    code = run.main(["--workload", cell["name"], "--seed", "8",
+                     "--rehearse-cpu", "1"])
+    lines = capsys.readouterr().out.splitlines()
+    assert code == 1 and "rehearsal FAILED" in lines[-1]
+    report = json.loads(lines[-2].split("report: ", 1)[1])
+    assert not report["flags"]["reference"]
+    assert fails <= {k for k, c in report["checks"].items() if not c["ok"]}
+    assert all(v for k, v in report["flags"].items() if k != "reference")
 
 
 def test_a_cell_is_added_as_data_alone(clean_env, capsys, tmp_path):
@@ -112,4 +168,70 @@ def test_a_cell_is_added_as_data_alone(clean_env, capsys, tmp_path):
     # the new traffic made the mask (many short documents under a window:
     # more slices than documents), and the new metric read it
     assert report["metrics"]["mask_slices"] == report["plan"]["slices"] > 3
+    assert {p: p.read_bytes() for p in before} == before
+
+
+SECOND_FAMILY = os.path.join(os.path.dirname(__file__), "data", "second_family")
+
+
+def test_a_family_is_added_as_files_alone(clean_env, capsys, tmp_path):
+    """A second model family — attention in one layer of three, its own
+    reference, its own names compared, its own FLOP counts, an event class
+    and a metric for the kernel it would bring — as added files and one
+    manifest entry each: no code of ``cellbench/`` but what the family's
+    own files bring, and no edit of a file that was there."""
+    root = tmp_path
+    for sub in ("configs", "traffic", "metrics"):
+        shutil.copytree(os.path.join(manifest.ROOT, "cellbench", sub),
+                        root / "cellbench" / sub)
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    shutil.copytree(SECOND_FAMILY, root, dirs_exist_ok=True)
+
+    m = json.loads(json.dumps(MANIFEST))
+    m["configs"].append({
+        "name": "mixer-toy", "source": "https://example.org/mixer-toy",
+        "file": "cellbench/configs/mixer-toy.json", "reduced": [],
+        "why": "test"})
+    m["workloads"].append({
+        "name": "mixer.packed.cp1", "config": "mixer-toy",
+        "traffic": "packed", "chips": 1, "why": "test"})
+    m["per_layer"].append({
+        "name": "gate_ms_per_step", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "gate", "moves": "tokens_per_s",
+        "workloads": ["mixer.packed.cp1"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(m))
+
+    report = _rehearse(
+        capsys, "--root", str(root), "--workload", "mixer.packed.cp1",
+        "--seed", "3", "--trace", "1", "--rehearse-cpu", "1")
+    assert all(report["flags"].values()), report["flags"]
+    # its own names were compared, against its own reference
+    assert list(report["checks"]) == [
+        "loss", "logits", "grad_gate0", "grad_value"]
+    assert all(c["ok"] for c in report["checks"].values())
+    assert 0 < report["checks"]["grad_value"]["err"] < 1e-4  # float32
+    assert any(k.startswith("_fwd_kernel") for k in report["kernels"])
+    # the whole step's share of the peak stands on its own count: FFA's
+    # area in one layer of the toy's three, none of llama's projections
+    family = manifest.load_family(str(root), "mixer")
+    cell = manifest.load_cell(str(root), "mixer.packed.cp1")
+    cfg, tokens, window, _ = run.cell_sizes(cell, family, 1)
+    spec = traffic_gen.make_mask(
+        cell.traffic, tokens, window, 3,
+        manifest.load_generator(str(root), cell.traffic["generator"]))
+    need = family.required_flops_per_step(cfg, spec)
+    assert need == 6 * tokens * (
+        4 * 128 * 256 + 2 * 3 * 128 * 256 + 128 * 512) + int(
+        3.5 * 4 * flops.band_area(spec) * 128 * 2)
+    step_s = statistics.median(report["step_ms"]) * 1e-3
+    assert report["metrics"]["model_flops_utilization"] == pytest.approx(
+        100.0 * need / (step_s * 197e12))
+    assert [g["layers"] for g in family.ffa_calls(cfg)] == [1]
+    # the kernel it would bring has a class of its own, tried first; its
+    # metric found no device trace on the CPU and was left out
+    classes = [c for c, _ in trace_reduce.load_classes(str(root))]
+    assert classes[:3] == ["gate_fwd", "gate_bwd", "ffa_fwd"]
+    assert "gate_fwd" not in [c for c, _ in trace_reduce.load_classes()]
+    assert "gate_ms_per_step" not in report["metrics"]
+    assert [x["name"] for x in cell.per_layer][-1] == "gate_ms_per_step"
     assert {p: p.read_bytes() for p in before} == before

@@ -55,21 +55,27 @@ DEVICE = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
 
 def test_result_line_has_exactly_the_contract_keys():
     units = {"tokens_per_s": "tokens/s", "setup_s": "s"}
+    checks = {"loss": {"err": 1.25e-4, "tol": 2.3e-3, "ok": True}}
     line = run.result_line(
         True, 12, 0, {"tokens_per_s": 10321.123456789, "setup_s": 31.25},
-        units, DEVICE, None)
+        units, DEVICE, None, checks)
     got = json.loads(line)
     assert "\n" not in line
-    assert set(got) == {"correct", "attempted", "failed", "metrics", "device"}
+    # the driver's keys, then each number compared beside its limit, last
+    assert list(got) == [
+        "correct", "attempted", "failed", "metrics", "device", "checks"]
+    assert got["checks"] == {"loss": {"err": 1.25e-4, "limit": 2.3e-3}}
     assert got["correct"] is True and got["attempted"] == 12
     assert got["metrics"]["tokens_per_s"] == {
         "value": 10321.123456789, "unit": "tokens/s"}  # every digit
     assert got["device"] == DEVICE
     traced = json.loads(run.result_line(
         False, 3, 1, {}, {}, {**DEVICE, "busy_s": 2.9, "window_s": 3.0},
-        {"device_ops": [["ffa_fwd:_fwd_kernel", 1.0]], "idle_gaps": []}))
-    assert set(traced) == {
-        "correct", "attempted", "failed", "metrics", "device", "breakdown"}
+        {"device_ops": [["ffa_fwd:_fwd_kernel", 1.0]], "idle_gaps": []},
+        checks))
+    assert list(traced) == [
+        "correct", "attempted", "failed", "metrics", "device", "breakdown",
+        "checks"]
     assert set(traced["breakdown"]) == {"device_ops", "idle_gaps"}
     assert traced["correct"] is False and traced["failed"] == 1
 
