@@ -11,6 +11,7 @@ import statistics
 import pytest
 from magiattention_tpu.models import llama
 
+import foreign_cells
 from cellbench import flops, manifest, run, trace_reduce, traffic_gen
 
 MANIFEST = json.load(open(os.path.join(manifest.ROOT, "BENCHMARK.json")))
@@ -171,9 +172,6 @@ def test_a_cell_is_added_as_data_alone(clean_env, capsys, tmp_path):
     assert {p: p.read_bytes() for p in before} == before
 
 
-SECOND_FAMILY = os.path.join(os.path.dirname(__file__), "data", "second_family")
-
-
 def test_a_family_is_added_as_files_alone(clean_env, capsys, tmp_path):
     """A second model family — attention in one layer of three, its own
     reference, its own names compared, its own FLOP counts, an event class
@@ -185,20 +183,8 @@ def test_a_family_is_added_as_files_alone(clean_env, capsys, tmp_path):
         shutil.copytree(os.path.join(manifest.ROOT, "cellbench", sub),
                         root / "cellbench" / sub)
     before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
-    shutil.copytree(SECOND_FAMILY, root, dirs_exist_ok=True)
-
     m = json.loads(json.dumps(MANIFEST))
-    m["configs"].append({
-        "name": "mixer-toy", "source": "https://example.org/mixer-toy",
-        "file": "cellbench/configs/mixer-toy.json", "reduced": [],
-        "why": "test"})
-    m["workloads"].append({
-        "name": "mixer.packed.cp1", "config": "mixer-toy",
-        "traffic": "packed", "chips": 1, "why": "test"})
-    m["per_layer"].append({
-        "name": "gate_ms_per_step", "unit": "ms", "better": "lower",
-        "source": "device_trace", "layer": "gate", "moves": "tokens_per_s",
-        "workloads": ["mixer.packed.cp1"]})
+    foreign_cells.add_second_family(root, m)
     (root / "BENCHMARK.json").write_text(json.dumps(m))
 
     report = _rehearse(
