@@ -80,8 +80,9 @@ def main() -> int:
     class TinyModel(nn.Module):
         @nn.compact
         def __call__(self, tokens):  # (S,) natural order
-            x = nn.Embed(VOCAB, DIM, name="embed")(tokens)
-            x = dispatch(x, attn_key)
+            # dispatch the ids, then embed: rows are born on their chip (the
+            # other order builds all S rows everywhere, then all-reduces them)
+            x = nn.Embed(VOCAB, DIM, name="embed")(dispatch(tokens, attn_key))
             x = x + MagiAttentionLayer(name="attn")(nn.LayerNorm()(x))
             h = nn.Dense(4 * DIM, name="up")(nn.LayerNorm()(x))
             x = x + nn.Dense(DIM, name="down")(nn.gelu(h))

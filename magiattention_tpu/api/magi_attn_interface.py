@@ -327,7 +327,14 @@ def _mgr(key: DistAttnRuntimeKey) -> DistAttnRuntimeMgr:
 def dispatch(
     x: jax.Array, key: DistAttnRuntimeKey, role: str = "qo"
 ) -> jax.Array:
-    """Global natural-order tensor -> dispatched cp-sharded layout (ref :892)."""
+    """Global natural-order tensor -> dispatched cp-sharded layout (ref :892).
+
+    In a model, dispatch what is per token and narrow (token ids, labels,
+    other per-token integers) and let everything ``dim`` wide be born
+    dispatched: embed ``dispatch(tokens, key)``, as
+    ``models.llama.embed_dispatched`` does. Embedding first and dispatching
+    the activations builds all ``total_seqlen`` rows on every chip and, with
+    a vocabulary-sharded table at cp 4, all-reduces ``[S, dim]`` every step."""
     mgr = _mgr(key)
     return mgr.dispatch_qo(x) if role == "qo" else mgr.dispatch_kv(x)
 

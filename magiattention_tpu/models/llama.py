@@ -157,6 +157,18 @@ def masked_ce(logits, labels):
     )
 
 
+def embed_dispatched(embed, tokens, attn_key, dtype):
+    """Rows of ``embed`` for ``tokens`` (natural order) in DISPATCHED order
+    (shared by the Llama and MoE families — ONE source of truth for the way
+    in): the ids are dispatched, then looked up, so nothing ``dim`` wide
+    exists before the sequence is cut to a chip's share. Bit-identical to
+    ``dispatch(jnp.take(embed, tokens, axis=0).astype(dtype), attn_key)``,
+    which at cp > 1 builds all ``total_seqlen`` rows on every chip and
+    all-reduces them each step. The lookup stays float32, then the cast:
+    the backward's scatter-add into the table is float32."""
+    return jnp.take(embed, dispatch(tokens, attn_key), axis=0).astype(dtype)
+
+
 def forward(
     params: dict,
     cfg: LlamaConfig,
@@ -174,8 +186,7 @@ def forward(
         instead, which is cheaper).
     """
     dt = cfg.jdtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(dt)  # (S, dim)
-    x = dispatch(x, attn_key)
+    x = embed_dispatched(params["embed"], tokens, attn_key, dt)  # (S, dim)
     pos = get_position_ids(attn_key)
 
     def layer(x, lyr):

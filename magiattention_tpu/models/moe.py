@@ -40,7 +40,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..api import dispatch, get_mesh, get_position_ids
 from jax import shard_map
 from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
-from .llama import LlamaConfig, _rms_norm, attn_block, masked_ce
+from .llama import (
+    LlamaConfig,
+    _rms_norm,
+    attn_block,
+    embed_dispatched,
+    masked_ce,
+)
 
 
 @dataclass(frozen=True)
@@ -284,8 +290,7 @@ def moe_forward(
     ``(logits_dispatched, aux_loss)``.
     """
     dt = cfg.jdtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
-    x = dispatch(x, attn_key)
+    x = embed_dispatched(params["embed"], tokens, attn_key, dt)
     pos = get_position_ids(attn_key)
     mesh = get_mesh(attn_key) if ep_axis is not None else None
 
