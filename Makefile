@@ -48,7 +48,7 @@ smoke:  ## on the chip: kernel census, one compile-and-compare case per pallas_c
 fit-overhead:  ## fit tile_policy.OVERHEAD_ELEMS from recorded sweeps
 	$(PY) scripts/fit_tile_overhead.py
 
-telemetry-smoke:  ## CPU telemetry round trip: JSONL + store + registry -> report, then the perf gate
+telemetry-smoke:  ## CPU telemetry round trip: JSONL + run-history store -> report, registry pins, then the perf gate
 	$(PY) -m pytest tests/test_support/test_telemetry.py \
 		tests/test_support/test_store.py \
 		tests/test_support/test_registry.py -x -q

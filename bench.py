@@ -305,7 +305,7 @@ def run_sparse_suite() -> int:
 
 
 # ---------------------------------------------------------------------------
-# --bwd-suite: split-vs-fused backward A/B (MAGI_ATTENTION_FFA_FUSED_BWD)
+# --bwd-suite: split-vs-fused backward A/B (MAGI_ATTENTION_BACKEND_FFA_BWD)
 # ---------------------------------------------------------------------------
 
 
@@ -329,8 +329,8 @@ def run_bwd_suite() -> int:
     """Slope-timed split-vs-fused backward A/B per mask family and seqlen.
 
     Each (family, seq) runs the SAME fwd+bwd grad body under
-    MAGI_ATTENTION_FFA_FUSED_BWD=0 (split dq + dkv passes) and =1 (fused
-    one-pass), with the credibility floor computed from each mode's OWN
+    MAGI_ATTENTION_BACKEND_FFA_BWD=split (dq + dkv passes) and =fused (one
+    pass), with the credibility floor computed from each mode's OWN
     executed matmul work (fwd 2 tile matmuls + bwd 7 split / 5 fused —
     a fused slope beating the 5-matmul physics is an under-cancelled
     pair, not a win). Rows append to benchmarks/history/bench_bwd.csv.
@@ -406,9 +406,9 @@ def run_bwd_suite() -> int:
                 )
 
             pair = {}
-            for mode, flag in (("split", "0"), ("fused", "1")):
-                saved = os.environ.get("MAGI_ATTENTION_FFA_FUSED_BWD")
-                os.environ["MAGI_ATTENTION_FFA_FUSED_BWD"] = flag
+            for mode in ("split", "fused"):
+                saved = os.environ.get("MAGI_ATTENTION_BACKEND_FFA_BWD")
+                os.environ["MAGI_ATTENTION_BACKEND_FFA_BWD"] = mode
                 _cached_plan.cache_clear()
                 row = {
                     "family": name, "seq": seq, "mode": mode,
@@ -438,10 +438,10 @@ def run_bwd_suite() -> int:
                 finally:
                     if saved is None:
                         os.environ.pop(
-                            "MAGI_ATTENTION_FFA_FUSED_BWD", None
+                            "MAGI_ATTENTION_BACKEND_FFA_BWD", None
                         )
                     else:
-                        os.environ["MAGI_ATTENTION_FFA_FUSED_BWD"] = saved
+                        os.environ["MAGI_ATTENTION_BACKEND_FFA_BWD"] = saved
                     _cached_plan.cache_clear()
                 rows.append(row)
             rows[-1]["fused_speedup"] = round(

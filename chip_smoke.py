@@ -42,8 +42,9 @@ import sys
 import time
 
 # variables that select interpret mode, a fallback, a retry, another
-# backend, injected faults, or the telemetry store that persisted backend
-# policies are read from: none may be set for a run that claims the device
+# backend or injected faults, and telemetry, whose stage timers and records
+# add host work to every step: none may be set for a run that claims the
+# device
 FORBIDDEN_ENV = (
     "MAGI_ATTENTION_PALLAS_INTERPRET",
     "MAGI_ATTENTION_FALLBACK",
@@ -117,8 +118,8 @@ def _refuse_hidden_device_env() -> None:
         sys.exit(
             "chip_smoke: refusing to start with "
             + ", ".join(f"{k}={os.environ[k]!r}" for k in bad)
-            + " set: each can hide the device behind an interpreter, a "
-            "fallback or a persisted policy. Unset and re-run."
+            + " set: each can hide the device behind an interpreter or a "
+            "fallback, or adds host work to the steps. Unset and re-run."
         )
 
 

@@ -267,7 +267,9 @@ def check_env_doc_coverage(
     ``ENV_KEYS_AFFECTING_RUNTIME`` registry, and scoped_env defaults all
     feed the same check. Non-``MAGI_`` keys (e.g. the upstream
     ``JAX_COMPILATION_CACHE_DIR`` passthrough) are deliberately exempt —
-    they are not ours to catalogue.
+    they are not ours to catalogue. So are the keys of a
+    ``REMOVED_ENV_KEYS`` table: a key the package refuses is no knob to
+    document.
     """
     global _ENV_KEY_RE
     if _ENV_KEY_RE is None:
@@ -293,7 +295,16 @@ def check_env_doc_coverage(
                 tree = ast.parse(f.read(), filename=path)
             except SyntaxError:
                 continue
-        seen: set[str] = set()
+        # the keys of the refusal table count as seen
+        seen: set[str] = {
+            k.value
+            for node in ast.walk(tree)
+            if isinstance(node, ast.AnnAssign)
+            and getattr(node.target, "id", None) == "REMOVED_ENV_KEYS"
+            and isinstance(node.value, ast.Dict)
+            for k in node.value.keys
+            if isinstance(k, ast.Constant)
+        }
         for node in ast.walk(tree):
             if (
                 isinstance(node, ast.Constant)

@@ -1,118 +1,64 @@
-"""Backend-registry pins and performance-observatory knobs.
+"""Backend-registry pins and the telemetry store's directory.
 
 The unified backend registry (kernels/registry.py) resolves every
-attention-backend decision as pin > cached/measured policy > heuristic.
-This module owns the *pin* layer: typed getters that map the new
-``MAGI_ATTENTION_BACKEND_*`` keys — and, for compatibility, the legacy
-direct-choice flags (``MAGI_ATTENTION_FFA_FUSED_BWD``,
-``MAGI_ATTENTION_FFA_MIXED_BLOCKS``, ``MAGI_ATTENTION_SERVE_DECODE_KERNEL``)
-— onto explicit backend names. A pin bypasses the policy cache entirely;
-``None`` means "no pin, let the registry decide".
+attention-backend decision as pin > the call site's rule over shapes. This
+module owns the *pin* layer: typed getters that read one
+``MAGI_ATTENTION_BACKEND_*`` key per decision (``MAGI_ATTENTION_KERNEL_BACKEND``
+for ``calc_attn``) and return an explicit backend name, or ``None`` for
+"no pin, let the rule decide". The three getters whose pin once had an
+alias first refuse a removed key (env/general.py:REMOVED_ENV_KEYS).
 
-Legacy flags keep working but log a one-time deprecation notice pointing
-at the replacement key. New code should set the BACKEND_* keys.
-
-Store/drift knobs (MAGI_ATTENTION_BACKEND_STORE, MAGI_ATTENTION_STORE_DIR,
-MAGI_ATTENTION_DRIFT_THRESHOLD, MAGI_ATTENTION_CALIBRATION) configure the
-persistent telemetry store (telemetry/store.py) and the measured-vs-modeled
-drift layer (telemetry/drift.py).
+``MAGI_ATTENTION_STORE_DIR`` places the persistent telemetry store
+(telemetry/store.py), which is active exactly when telemetry is on.
 """
 
 from __future__ import annotations
 
-import logging
 import os
 
-from .general import _get_str
-
-logger = logging.getLogger("magiattention_tpu.env.backend")
-
-# legacy keys already warned about this process (one notice per key)
-_warned_legacy: set[str] = set()
-
-
-def _warn_legacy_once(legacy_key: str, new_key: str, mapped: str) -> None:
-    if legacy_key in _warned_legacy:
-        return
-    _warned_legacy.add(legacy_key)
-    logger.warning(
-        "%s is deprecated as a direct kernel-choice flag; it now maps to "
-        "the registry pin %s=%s (see docs/env_variables.md).",
-        legacy_key,
-        new_key,
-        mapped,
-    )
+from .general import _get_str, refuse_removed_keys
 
 
 def kernel_backend_pin() -> str | None:
     """The MAGI_ATTENTION_KERNEL_BACKEND value as a registry pin: the
     explicit value when set, None when unset (general.kernel_backend()
-    folds the default 'ffa' in — here the registry's heuristic supplies
-    it, so an unpinned runtime can be steered by measured history)."""
+    folds the default 'ffa' in — here the registry's rule supplies it)."""
     val = os.environ.get("MAGI_ATTENTION_KERNEL_BACKEND")
     return val.lower() if val else None
 
 
 def ffa_bwd_pin() -> str | None:
-    """Pin for the split-vs-fused FFA backward: 'fused' | 'split' | None.
+    """Pin for the split-vs-fused FFA backward
+    (MAGI_ATTENTION_BACKEND_FFA_BWD): 'fused' | 'split' | None.
 
-    MAGI_ATTENTION_BACKEND_FFA_BWD wins; legacy MAGI_ATTENTION_FFA_FUSED_BWD
-    maps 1->fused, 0->split, auto->None. A 'fused' pin is still subject to
-    the call site's feasibility guards (VMEM residency, meta layout) —
-    exactly the legacy flag's semantics."""
+    A 'fused' pin is still subject to the call site's feasibility guards
+    (VMEM residency, meta layout)."""
+    refuse_removed_keys()
     val = _get_str("MAGI_ATTENTION_BACKEND_FFA_BWD", "").lower()
-    if val in ("fused", "split"):
-        return val
-    legacy = os.environ.get("MAGI_ATTENTION_FFA_FUSED_BWD")
-    if legacy == "1":
-        _warn_legacy_once(
-            "MAGI_ATTENTION_FFA_FUSED_BWD", "MAGI_ATTENTION_BACKEND_FFA_BWD",
-            "fused")
-        return "fused"
-    if legacy == "0":
-        _warn_legacy_once(
-            "MAGI_ATTENTION_FFA_FUSED_BWD", "MAGI_ATTENTION_BACKEND_FFA_BWD",
-            "split")
-        return "split"
-    return None
+    return val if val in ("fused", "split") else None
 
 
 def mixed_blocks_pin() -> str | None:
-    """Pin for mixed-granularity dispatch: 'mixed' | 'single' | None.
+    """Pin for mixed-granularity dispatch
+    (MAGI_ATTENTION_BACKEND_MIXED_BLOCKS): 'mixed' | 'single' | None.
 
-    MAGI_ATTENTION_BACKEND_MIXED_BLOCKS wins; legacy
-    MAGI_ATTENTION_FFA_MIXED_BLOCKS maps 1->mixed (skip the profitability
-    gate), 0->single, auto->None. A 'mixed' pin still degrades to single
-    when the mask yields a trivial partition — legacy mode-"1" semantics."""
+    'mixed' skips the profitability gate but still degrades to single
+    when the mask yields a trivial partition."""
+    refuse_removed_keys()
     val = _get_str("MAGI_ATTENTION_BACKEND_MIXED_BLOCKS", "").lower()
-    if val in ("mixed", "single"):
-        return val
-    legacy = os.environ.get("MAGI_ATTENTION_FFA_MIXED_BLOCKS")
-    if legacy == "1":
-        _warn_legacy_once(
-            "MAGI_ATTENTION_FFA_MIXED_BLOCKS",
-            "MAGI_ATTENTION_BACKEND_MIXED_BLOCKS", "mixed")
-        return "mixed"
-    if legacy == "0":
-        _warn_legacy_once(
-            "MAGI_ATTENTION_FFA_MIXED_BLOCKS",
-            "MAGI_ATTENTION_BACKEND_MIXED_BLOCKS", "single")
-        return "single"
-    return None
+    return val if val in ("mixed", "single") else None
 
 
 def serve_decode_pin() -> str | None:
-    """Pin for the serve decode rung: 'paged_decode_sharded' |
-    'paged_decode_spec' | 'paged_decode_int8' | 'paged_decode' |
-    'gather_ffa' | 'dense' | None.
+    """Pin for the serve decode rung (MAGI_ATTENTION_BACKEND_SERVE_DECODE):
+    'paged_decode_sharded' | 'paged_decode_spec' | 'paged_decode_int8' |
+    'paged_decode' | 'gather_ffa' | 'dense' | None.
 
-    MAGI_ATTENTION_BACKEND_SERVE_DECODE wins; legacy
-    MAGI_ATTENTION_SERVE_DECODE_KERNEL maps 1->paged_decode, 0->gather_ffa,
-    auto->None. The resilience ladder still descends from the pinned rung
-    on kernel failure, and a pin remains subject to the call site's
-    feasibility guards (shard divisibility, cache dtype, 1-row vs
-    multi-row step) — an infeasible pin starts at the first feasible rung
-    below it."""
+    The resilience ladder still descends from the pinned rung on kernel
+    failure, and a pin remains subject to the call site's feasibility
+    guards (shard divisibility, cache dtype, 1-row vs multi-row step) — an
+    infeasible pin starts at the first feasible rung below it."""
+    refuse_removed_keys()
     val = _get_str("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "").lower()
     if val in (
         "paged_decode_sharded",
@@ -123,61 +69,20 @@ def serve_decode_pin() -> str | None:
         "dense",
     ):
         return val
-    legacy = os.environ.get("MAGI_ATTENTION_SERVE_DECODE_KERNEL")
-    if legacy == "1":
-        _warn_legacy_once(
-            "MAGI_ATTENTION_SERVE_DECODE_KERNEL",
-            "MAGI_ATTENTION_BACKEND_SERVE_DECODE", "paged_decode")
-        return "paged_decode"
-    if legacy == "0":
-        _warn_legacy_once(
-            "MAGI_ATTENTION_SERVE_DECODE_KERNEL",
-            "MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
-        return "gather_ffa"
     return None
 
 
 def nsa_slc_pin() -> str | None:
-    """Pin for the NSA selected-block branch: 'block_sparse_pallas' |
-    'gathered_dense' | None. New decision, so no legacy flag exists —
-    MAGI_ATTENTION_BACKEND_NSA_SLC is the only key."""
+    """Pin for the NSA selected-block branch
+    (MAGI_ATTENTION_BACKEND_NSA_SLC): 'block_sparse_pallas' |
+    'gathered_dense' | None."""
     val = _get_str("MAGI_ATTENTION_BACKEND_NSA_SLC", "").lower()
     if val in ("block_sparse_pallas", "gathered_dense"):
         return val
     return None
 
 
-def backend_store_mode() -> str:
-    """Persistent policy/measurement store mode: auto | 1 | 0.
-
-    auto (default): active whenever MAGI_ATTENTION_TELEMETRY is on.
-    0: telemetry records still flow to JSONL but nothing is persisted to —
-    or read back from — the store (registry falls back to heuristics).
-    1: reserved for forcing the store on independently of future gates;
-    today it behaves like auto (the store still requires telemetry)."""
-    val = _get_str("MAGI_ATTENTION_BACKEND_STORE", "auto").lower()
-    return val if val in ("auto", "1", "0") else "auto"
-
-
 def store_dir() -> str:
     """Directory of the persistent telemetry store (history JSONL files +
     compacted store.json). Empty default = '<telemetry_dir>/store'."""
     return _get_str("MAGI_ATTENTION_STORE_DIR", "")
-
-
-def drift_threshold() -> float:
-    """Relative prediction error above which telemetry/drift.py emits a
-    ``model_drift`` record for a cost-model observation (default 0.5 =
-    50% off after global scale fitting)."""
-    try:
-        return float(_get_str("MAGI_ATTENTION_DRIFT_THRESHOLD", "0.5"))
-    except ValueError:
-        return 0.5
-
-
-def calibration_enabled() -> bool:
-    """Let solvers consume store-fitted constants (OVERHEAD_ELEMS,
-    dcn_per_row) instead of their built-in defaults. Requires an active
-    store; with telemetry off this flag is inert and every model uses its
-    hard-coded constant."""
-    return _get_str("MAGI_ATTENTION_CALIBRATION", "1") == "1"

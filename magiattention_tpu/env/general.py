@@ -210,21 +210,14 @@ ENV_KEYS_AFFECTING_RUNTIME: tuple[str, ...] = (
     "MAGI_ATTENTION_FFA_GQA_PACK_DQ",
     "MAGI_ATTENTION_FFA_GQA_PACK_DKV",
     "MAGI_ATTENTION_FFA_AUTO_TILE",
-    # extent clamping changes the lowered kernel bodies; mixed blocks
-    # changes which plans/kernels a mask dispatches to
+    # extent clamping changes the lowered kernel bodies
     "MAGI_ATTENTION_FFA_EXTENT_CLAMP",
-    "MAGI_ATTENTION_FFA_MIXED_BLOCKS",
-    # fused vs split backward changes which kernels the vjp traces
-    "MAGI_ATTENTION_FFA_FUSED_BWD",
-    # registry pins (env/backend.py) select traced kernels directly, and the
-    # persistent store / calibration gates let measured history steer both
-    # kernel choice and solver constants — cached runtimes must not be
-    # shared across flips of any of them
+    # registry pins (env/backend.py) select traced kernels directly: fused
+    # vs split backward, which plans/kernels a mask dispatches to, the NSA
+    # branch — cached runtimes must not be shared across flips of them
     "MAGI_ATTENTION_BACKEND_FFA_BWD",
     "MAGI_ATTENTION_BACKEND_MIXED_BLOCKS",
     "MAGI_ATTENTION_BACKEND_NSA_SLC",
-    "MAGI_ATTENTION_BACKEND_STORE",
-    "MAGI_ATTENTION_CALIBRATION",
     # wire-tier selection changes the traced collective program
     "MAGI_ATTENTION_RAGGED_GRPCOLL",
     "MAGI_ATTENTION_SPLIT_ALIGNMENT",
@@ -236,6 +229,39 @@ ENV_KEYS_AFFECTING_RUNTIME: tuple[str, ...] = (
 )
 
 
+# keys that left the package -> what to set instead. The docs once told
+# users to set them, and a pin that is silently ignored changes which
+# kernel a caller traces, so a removed key found in the environment is
+# refused by name.
+REMOVED_ENV_KEYS: dict[str, str] = {
+    "MAGI_ATTENTION_FFA_FUSED_BWD":
+        "MAGI_ATTENTION_BACKEND_FFA_BWD=fused|split (was 1|0)",
+    "MAGI_ATTENTION_FFA_MIXED_BLOCKS":
+        "MAGI_ATTENTION_BACKEND_MIXED_BLOCKS=mixed|single (was 1|0)",
+    "MAGI_ATTENTION_SERVE_DECODE_KERNEL":
+        "MAGI_ATTENTION_BACKEND_SERVE_DECODE=paged_decode|gather_ffa "
+        "(was 1|0)",
+    "MAGI_ATTENTION_BACKEND_STORE":
+        "nothing: the store is active exactly when MAGI_ATTENTION_TELEMETRY "
+        "is, and no choice reads it",
+    "MAGI_ATTENTION_DRIFT_THRESHOLD":
+        "nothing: the drift layer is gone",
+    "MAGI_ATTENTION_CALIBRATION":
+        "nothing: OVERHEAD_ELEMS and DCN_PER_ROW are constants",
+}
+
+
+def refuse_removed_keys() -> None:
+    """Raise ValueError for a REMOVED_ENV_KEYS key set in the environment."""
+    for key, instead in REMOVED_ENV_KEYS.items():
+        if key in os.environ:
+            raise ValueError(
+                f"{key} was removed; set {instead} "
+                "(docs/env_variables.md)"
+            )
+
+
 def snapshot_env() -> tuple[tuple[str, str | None], ...]:
     """Hashable snapshot of every behavior-affecting flag."""
+    refuse_removed_keys()
     return tuple((k, os.environ.get(k)) for k in ENV_KEYS_AFFECTING_RUNTIME)

@@ -41,8 +41,6 @@ from ..env import resilience as env_resilience
 from ..kernels.ffa import (
     FFAParams,
     _bwd_plan_slices,
-    bwd_mode_key,
-    bwd_modeled_cost,
     ffa_bwd_pallas_dispatch,
     ffa_delta_pallas_dispatch,
     ffa_fwd_pallas_dispatch,
@@ -411,13 +409,12 @@ class DeferredTilePolicy:
         self._build_plans(blk_q, blk_k)
         self._plan_sig = sig
 
-    # -- observatory signatures (telemetry/store.py join keys) ----------
+    # -- signatures (registry, quarantine and run-history keys) ---------
 
     def _policy_key(self) -> dict:
-        """The calc_attn registry/measurement key: mask-class signature x
-        mesh x env snapshot. Keyed exactly like store.ingest_event's
-        calc_attn measurement rows, so the registry's measured-history
-        lookup joins against this runtime's own recorded steps."""
+        """The calc_attn decision key: mask-class signature x mesh x env
+        snapshot — what the registry memoizes the backend under and the
+        step watchdog quarantines a backend for."""
         return {
             "mask_sig": self._mask_signature(),
             "mesh_sig": self._mesh_signature(),
@@ -447,7 +444,7 @@ class DeferredTilePolicy:
 
     def _env_signature(self) -> str:
         """Digest of the behavior-affecting env snapshot (memoized per
-        snapshot value — flips mid-life re-key the policy lookups)."""
+        snapshot value — flips mid-life re-key the decision)."""
         snap = env_general.snapshot_env()
         cached = self._tel_env_sig
         if cached is not None and cached[0] == snap:
@@ -459,8 +456,7 @@ class DeferredTilePolicy:
     @property
     def backend(self) -> str:
         """Kernel backend via the registry's ``calc_attn`` decision: an
-        explicit MAGI_ATTENTION_KERNEL_BACKEND pins it, otherwise the
-        policy cache / measured history / the 'ffa' default decide. A
+        explicit MAGI_ATTENTION_KERNEL_BACKEND pins it, otherwise 'ffa'. A
         resilience-ladder override (sticky degradation to the reference
         path) wins over everything."""
         if self._backend_override is not None:
@@ -682,7 +678,7 @@ class DistAttnRuntime(DeferredTilePolicy):
             stages.append(d)
         payload = {
             "backend": self.backend,
-            # observatory join keys (telemetry/store.py _ATTN_KEY_FIELDS)
+            # run-history join keys (telemetry's _ATTN_KEY_FIELDS)
             "mask_sig": self._mask_signature(),
             "mesh_sig": self._mesh_signature(),
             "env_sig": self._env_signature(),
@@ -725,15 +721,6 @@ class DistAttnRuntime(DeferredTilePolicy):
                 est_flops_fwd=4 * band * dh * hq,
                 padded_flops_fwd=4 * padded * dh * hq,
                 bwd_mode=bwd_mode,
-                # the mode decision's registry/store key + modeled cost, so
-                # the drift layer can compare choose_bwd_mode's prediction
-                # against this step's measured wall time
-                bwd_key=list(
-                    bwd_mode_key(prm0, dh, dv, q.dtype.itemsize)
-                ),
-                bwd_cost=bwd_modeled_cost(
-                    prm0, dh, dv, q.dtype.itemsize, bwd_mode
-                ),
             )
         return payload
 

@@ -36,8 +36,6 @@ from ..env import resilience as env_resilience
 from ..kernels.ffa import (
     FFAParams,
     _bwd_plan_slices,
-    bwd_mode_key,
-    bwd_modeled_cost,
     ffa_bwd_pallas_dispatch,
     ffa_delta_pallas_dispatch,
     _should_interpret,
@@ -286,7 +284,7 @@ class DynamicDistAttnRuntime(DeferredTilePolicy):
         payload = {
             "planner": "dynamic",
             "backend": self.backend,
-            # observatory join keys (telemetry/store.py _ATTN_KEY_FIELDS)
+            # run-history join keys (telemetry's _ATTN_KEY_FIELDS)
             "mask_sig": self._mask_signature(),
             "mesh_sig": self._mesh_signature(),
             "env_sig": self._env_signature(),
@@ -329,12 +327,6 @@ class DynamicDistAttnRuntime(DeferredTilePolicy):
                 est_flops_fwd=4 * band * dh * hq,
                 padded_flops_fwd=4 * padded * dh * hq,
                 bwd_mode=bwd_mode,
-                bwd_key=list(
-                    bwd_mode_key(prm0, dh, dv, q.dtype.itemsize)
-                ),
-                bwd_cost=bwd_modeled_cost(
-                    prm0, dh, dv, q.dtype.itemsize, bwd_mode
-                ),
             )
         return payload
 

@@ -2355,7 +2355,7 @@ def fused_bwd_feasible(
 ) -> bool:
     """True when at least one fused-kernel variant's per-step VMEM
     residency fits the budget — the guard that forces split mode even
-    under MAGI_ATTENTION_FFA_FUSED_BWD=1."""
+    under a 'fused' pin."""
     if _use_gqa_pack_fused(params, sqp, d, dv, itemsize):
         return True
     bq, bk = params.dkv_blocks()
@@ -2392,12 +2392,11 @@ def ffa_bwd_mode(
     at trace time (static work counts / blocks / dims only; no plan
     contents, which may be traced arrays under shard_map).
 
-    Selection flows through the backend registry (kernels/registry.py):
-    a 'split'/'fused' pin (MAGI_ATTENTION_BACKEND_FFA_BWD, or the legacy
-    MAGI_ATTENTION_FFA_FUSED_BWD mapped 0/1) wins outright — 'fused' still
-    subject to the feasibility guards below — and unpinned geometries
-    resolve against the policy cache / measured history, falling back to
-    the tile_policy cost model.
+    In order: a 'split' pin (MAGI_ATTENTION_BACKEND_FFA_BWD); the
+    feasibility guards (plan meta layout, fused VMEM residency), either of
+    which forces 'split'; a 'fused' pin; else tile_policy.choose_bwd_mode
+    over the static shapes, memoized per key by the backend registry
+    (kernels/registry.py).
     """
     from ..env import backend as env_backend
     from . import registry as _registry
@@ -2428,10 +2427,9 @@ def ffa_bwd_mode(
 def bwd_mode_key(
     params: FFAParams, d: int, dv: int, itemsize: int
 ) -> tuple[int, ...]:
-    """The registry/store key of one backward-mode decision: the exact
-    static quantities choose_bwd_mode consumes — (w_dq, bq_dq, bk_dq, wt,
-    bq_dkv, bk_dkv, d, dv, itemsize, group). Shared by ffa_bwd_mode and
-    the telemetry layer so measured history joins against resolutions."""
+    """The registry key of one backward-mode decision: the exact static
+    quantities choose_bwd_mode consumes — (w_dq, bq_dq, bk_dq, wt, bq_dkv,
+    bk_dkv, d, dv, itemsize, group)."""
     bq_dq, bk_dq = params.dq_blocks()
     bq_dkv, bk_dkv = params.dkv_blocks()
     w_dq = (
@@ -2447,27 +2445,6 @@ def bwd_mode_key(
     return (
         w_dq, bq_dq, bk_dq, wt, bq_dkv, bk_dkv, d, dv, itemsize,
         params.group,
-    )
-
-
-def bwd_modeled_cost(
-    params: FFAParams, d: int, dv: int, itemsize: int, mode: str
-) -> int:
-    """choose_bwd_mode's modeled cost (MXU elems + balanced HBM term) of
-    running the backward under ``mode`` — what the drift layer compares
-    against measured wall time."""
-    from .tile_policy import (
-        BWD_MXU_ELEMS_PER_HBM_BYTE,
-        bwd_hbm_bytes,
-        bwd_mxu_elems,
-    )
-
-    key = bwd_mode_key(params, d, dv, itemsize)
-    args = key[:7]
-    return bwd_mxu_elems(mode, *args) + BWD_MXU_ELEMS_PER_HBM_BYTE * (
-        bwd_hbm_bytes(
-            mode, *args, dv, itemsize=itemsize, group=params.group
-        )
     )
 
 
@@ -3111,8 +3088,8 @@ def ffa_attn(
         and block_k is None
         and not _registry_mod().tiles_pinned()
     ):
-        # mixed-granularity dispatch: when the cost model (or an explicit
-        # MAGI_ATTENTION_FFA_MIXED_BLOCKS=1) says a coarse/fine split wins,
+        # mixed-granularity dispatch: when the cost model (or a 'mixed' pin,
+        # MAGI_ATTENTION_BACKEND_MIXED_BLOCKS) says a coarse/fine split wins,
         # run two plans and merge — only reachable when blocks are not
         # pinned (explicit settings always win) and max-logits is off (the
         # merge does not combine per-head maxima)

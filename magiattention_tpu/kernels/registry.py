@@ -1,37 +1,29 @@
 """Unified attention-backend registry: ONE selection point for every
 kernel-choice decision in the package.
 
-Call sites that used to read env flags directly (``ffa_bwd_mode``'s
-``MAGI_ATTENTION_FFA_FUSED_BWD``, ``choose_mixed_dispatch``'s
-``MAGI_ATTENTION_FFA_MIXED_BLOCKS``, ``decode_attn_step``'s
-``MAGI_ATTENTION_SERVE_DECODE_KERNEL``, ``DistAttnRuntime.backend``'s
-``MAGI_ATTENTION_KERNEL_BACKEND``) now resolve through
-:func:`resolve`, with precedence:
+A choice is **pin > the call site's rule over shapes**:
 
-1. **pin** — an explicit env-derived choice (env/backend.py getters map
-   both the new ``MAGI_ATTENTION_BACKEND_*`` keys and the legacy flags to
-   pins). A pin bypasses every cache, is re-read per call (tests flip env
-   vars mid-process), and is subject only to the call site's *feasibility*
-   guards (VMEM, plan meta layout) — exactly the legacy flag semantics.
-2. **cached decision** — the in-process memo, then the persistent policy
-   store (telemetry/store.py): a prior resolution persisted across
-   restarts, or the fastest backend with enough ``ok`` measurements in
-   history (``measured``). Both are gated on ``store_active()`` at *use*
-   time, so flipping telemetry off mid-process also stops store-sourced
-   decisions from applying — with the observatory off, resolution is
-   bit-identical to the legacy heuristics.
-3. **heuristic** — the call site's legacy default (cost model or constant),
-   run at most once per key (memoized + persisted when the store is on).
-   Each heuristic run counts as one *tuning decision*
-   (``stats()["heuristic_calls"]``); a warm policy cache makes zero.
+1. **pin** — an explicit env-derived choice (the env/backend.py getters
+   read one ``MAGI_ATTENTION_BACKEND_*`` key per decision;
+   ``MAGI_ATTENTION_KERNEL_BACKEND`` pins ``calc_attn``). A pin bypasses
+   the memo, is re-read per call (tests flip env vars mid-process), and is
+   subject only to the call site's *feasibility* guards (VMEM, plan meta
+   layout).
+2. **rule** — the call site's heuristic (a cost model over static shapes,
+   or a constant), run at most once per key and memoized in-process. Each
+   run counts in ``stats()["heuristic_calls"]``.
+
+Telemetry is told of every choice (one ``backend_select`` record per
+decision, key and choice) and is asked nothing: no file, no measured
+history and no earlier run decides a kernel.
 
 Rank-ordered backend registrations double as the resilience ladders:
 ``ladder("serve_decode")`` is the decode fallback order and
 ``ladder("calc_attn")[-1]`` is the reference rung the resilience module
 descends to (resilience/fallback.py).
 
-MAGI-L002: no clocks here — measurements enter via the telemetry store,
-never from this module. MAGI-L001: env access only through typed getters.
+MAGI-L002: no clocks here. MAGI-L001: env access only through typed
+getters.
 """
 
 from __future__ import annotations
@@ -43,30 +35,22 @@ from typing import Any, Callable
 from .. import telemetry
 from ..env import backend as env_backend
 from ..env import kernel as env_kernel
-
-# sources a resolution can come from; STORE_SOURCES only apply while the
-# store is active (checked on every memo hit, so a stale store-sourced memo
-# can never leak into a telemetry-off run)
-STORE_SOURCES = ("policy", "measured")
+from ..utils.canonical import canonical_key
 
 
 @dataclass(frozen=True)
 class BackendChoice:
     name: str
-    source: str  # "pin" | "policy" | "measured" | "heuristic"
+    source: str  # "pin" | "heuristic", or what note_choice was given
 
 
 def _memo_key(key: Any) -> Any:
-    """Hashable form of a decision key. Dict keys (the calc_attn policy
-    key) canonicalize to their sorted-JSON string; the ORIGINAL key is
-    still what store lookups join on, so the on-disk form matches what
-    ingest_event writes."""
+    """Hashable form of a decision key. Dict keys (the calc_attn key)
+    canonicalize to their sorted-JSON string."""
     try:
         hash(key)
         return key
     except TypeError:
-        from ..telemetry.store import canonical_key
-
         return canonical_key(key)
 
 
@@ -114,7 +98,6 @@ class BackendRegistry:
             "resolves": 0,
             "pins": 0,
             "memo_hits": 0,
-            "store_hits": 0,
             "heuristic_calls": 0,
         }
 
@@ -156,47 +139,14 @@ class BackendRegistry:
         ck = (decision, _memo_key(key))
         with self._lock:
             hit = self._memo.get(ck)
-        if hit is not None:
-            usable = hit.source not in STORE_SOURCES or _store_gate()
-            if usable:
-                with self._lock:
-                    self.stats["memo_hits"] += 1
-                    self._last[decision] = (key, hit.name)
+            if hit is not None:
+                self.stats["memo_hits"] += 1
+                self._last[decision] = (key, hit.name)
                 return hit
 
-        choice: BackendChoice | None = None
-        if _store_gate():
-            from ..telemetry import store as _tstore
-
-            persisted = _tstore.policy_lookup(decision, key)
-            if persisted is not None and (
-                not backends_for(decision)
-                or persisted["choice"] in backends_for(decision)
-            ):
-                choice = BackendChoice(persisted["choice"], "policy")
-            else:
-                best = _tstore.measured_best(decision, key)
-                if best is not None and (
-                    not backends_for(decision)
-                    or best in backends_for(decision)
-                ):
-                    choice = BackendChoice(best, "measured")
-                    _tstore.policy_record(decision, key, best, "measured")
-            if choice is not None:
-                with self._lock:
-                    self.stats["store_hits"] += 1
-
-        if choice is None:
-            name = heuristic()
-            choice = BackendChoice(name, "heuristic")
-            with self._lock:
-                self.stats["heuristic_calls"] += 1
-            if _store_gate():
-                from ..telemetry import store as _tstore
-
-                _tstore.policy_record(decision, key, name, "heuristic")
-
+        choice = BackendChoice(heuristic(), "heuristic")
         with self._lock:
+            self.stats["heuristic_calls"] += 1
             self._memo[ck] = choice
             self._last[decision] = (key, choice.name)
         self._announce(decision, key, choice)
@@ -207,7 +157,7 @@ class BackendRegistry:
     ) -> BackendChoice:
         """Record a choice the call site computed itself (a rule over
         shapes, nothing to resolve against): ``last_choice`` and one
-        ``backend_select`` record, no memo, no store."""
+        ``backend_select`` record, no memo."""
         choice = BackendChoice(name, source)
         with self._lock:
             self._last[decision] = (key, name)
@@ -217,15 +167,6 @@ class BackendRegistry:
     def last(self, decision: str) -> tuple[Any, str] | None:
         with self._lock:
             return self._last.get(decision)
-
-
-def _store_gate() -> bool:
-    """Is the persistent policy store allowed to influence resolution
-    *right now*? Lazy import keeps telemetry fully out of the picture for
-    processes that never enable it."""
-    from ..telemetry import store as _tstore
-
-    return _tstore.store_active()
 
 
 _registry: BackendRegistry | None = None
@@ -271,18 +212,12 @@ def last_choice(decision: str) -> str | None:
     return None if last is None else last[1]
 
 
-def last_key(decision: str) -> Any | None:
-    last = get_registry().last(decision)
-    return None if last is None else last[0]
-
-
 # -- call-site conveniences (the env reads kernel code used to do) ----------
 
 
 def calc_attn_backend(key: Any = ()) -> str:
     """The attention backend for a runtime/step: explicit
-    MAGI_ATTENTION_KERNEL_BACKEND pins it; otherwise the policy cache /
-    measured history / the 'ffa' default decide."""
+    MAGI_ATTENTION_KERNEL_BACKEND pins it; otherwise 'ffa'."""
     return resolve(
         "calc_attn", key, lambda: "ffa",
         pin=env_backend.kernel_backend_pin(),
@@ -291,8 +226,8 @@ def calc_attn_backend(key: Any = ()) -> str:
 
 def nsa_slc_backend(key: Any = ()) -> str:
     """The NSA selected-block branch for a shape: explicit
-    MAGI_ATTENTION_BACKEND_NSA_SLC pins it; otherwise the policy cache /
-    measured history / the gather-free kernel default decide."""
+    MAGI_ATTENTION_BACKEND_NSA_SLC pins it; otherwise the gather-free
+    kernel."""
     return resolve(
         "nsa_slc", key, lambda: "block_sparse_pallas",
         pin=env_backend.nsa_slc_pin(),
@@ -403,18 +338,13 @@ register_backend(
     "nsa_slc", "gathered_dense", 1,
     "take_along_axis + dense softmax reference")
 
-# which env keys pin each decision (new BACKEND_* key first, legacy key
-# second) — provenance for reports and docs/env_variables.md
+# which env keys pin each decision — provenance for reports and
+# docs/env_variables.md
 PIN_KEYS: dict[str, tuple[str, ...]] = {
     "calc_attn": ("MAGI_ATTENTION_KERNEL_BACKEND",),
-    "ffa_bwd": (
-        "MAGI_ATTENTION_BACKEND_FFA_BWD", "MAGI_ATTENTION_FFA_FUSED_BWD"),
-    "ffa_dispatch": (
-        "MAGI_ATTENTION_BACKEND_MIXED_BLOCKS",
-        "MAGI_ATTENTION_FFA_MIXED_BLOCKS"),
-    "serve_decode": (
-        "MAGI_ATTENTION_BACKEND_SERVE_DECODE",
-        "MAGI_ATTENTION_SERVE_DECODE_KERNEL"),
+    "ffa_bwd": ("MAGI_ATTENTION_BACKEND_FFA_BWD",),
+    "ffa_dispatch": ("MAGI_ATTENTION_BACKEND_MIXED_BLOCKS",),
+    "serve_decode": ("MAGI_ATTENTION_BACKEND_SERVE_DECODE",),
     "ffa_fwd": ("MAGI_ATTENTION_FFA_GQA_PACK",),
     "ffa_bwd_dq": ("MAGI_ATTENTION_FFA_GQA_PACK_DQ",),
     "ffa_bwd_dkv": ("MAGI_ATTENTION_FFA_GQA_PACK_DKV",),

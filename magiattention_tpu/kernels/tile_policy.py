@@ -68,22 +68,6 @@ def auto_tile_enabled() -> bool:
     return _get_int("MAGI_ATTENTION_FFA_AUTO_TILE", 0) == 1
 
 
-def _overhead_elems() -> float:
-    """The per-grid-step fixed cost the scorers charge: the built-in
-    :data:`OVERHEAD_ELEMS` constant, or the store-fitted value when the
-    performance observatory's calibration loop is on
-    (telemetry/drift.fit_constants writes it; store.calibrated gates on
-    telemetry + MAGI_ATTENTION_CALIBRATION, so with the observatory off
-    this is exactly the constant and scores are bit-identical)."""
-    from ..env import backend as env_backend
-
-    if not env_backend.calibration_enabled():
-        return OVERHEAD_ELEMS
-    from ..telemetry import store as _tstore
-
-    return _tstore.calibrated("overhead_elems", OVERHEAD_ELEMS)
-
-
 def count_ffa_work(
     qr: np.ndarray,
     kr: np.ndarray,
@@ -199,7 +183,6 @@ def choose_blocks_multi(
     seen: set[tuple[int, int]] = set()
     best = None
     best_score = None
-    ov = _overhead_elems()
     for bq, bk in CANDIDATES:
         # clamp to the problem (same rule as default_blocks), then dedupe
         bq = min(bq, _round_up(sq, 16))
@@ -213,7 +196,7 @@ def choose_blocks_multi(
             count_ffa_work(qr, kr, lo, hi, sq, sk, bq, bk)
             for qr, kr, lo, hi in rank_geoms
         )
-        score = w * (bq * bk + ov)
+        score = w * (bq * bk + OVERHEAD_ELEMS)
         if best_score is None or score < best_score:
             best, best_score = (bq, bk), score
     chosen = best or (
@@ -307,7 +290,6 @@ def choose_blocks_per_pass_multi(
     """
     maybe_inject("vmem_check")
     cands = _band_candidates(rank_geoms, sq, sk)
-    ov = _overhead_elems()
 
     def score_pass(kind: str, allowed=None):
         seen: set[tuple[int, int]] = set()
@@ -332,7 +314,7 @@ def choose_blocks_per_pass_multi(
                 counter(qr, kr, lo, hi, sq, sk, bq, bk)
                 for qr, kr, lo, hi in rank_geoms
             )
-            score = w * (bq * bk + ov)
+            score = w * (bq * bk + OVERHEAD_ELEMS)
             if best_score is None or score < best_score:
                 best, best_score = (bq, bk), score
         return best
@@ -561,11 +543,9 @@ def choose_mixed_dispatch(
 
     Selection flows through the backend registry's ``ffa_dispatch``
     decision (kernels/registry.py): a 'single'/'mixed' pin
-    (MAGI_ATTENTION_BACKEND_MIXED_BLOCKS, or the legacy
-    MAGI_ATTENTION_FFA_MIXED_BLOCKS mapped 0/1) wins — 'mixed' still
-    degrades to None when the mask yields no non-trivial partition with
-    distinct tilings; unpinned geometries resolve against the policy cache
-    / measured history, falling back to the cost model: split wins when
+    (MAGI_ATTENTION_BACKEND_MIXED_BLOCKS) wins — 'mixed' still degrades to
+    None when the mask yields no non-trivial partition with distinct
+    tilings; unpinned geometries take the cost model: split wins when
     score(coarse on dense) + score(fine on fragmented) + merge overhead <
     score(coarse on everything), with score the same padded-work +
     per-step-overhead model the tile scorer minimizes.
@@ -592,8 +572,6 @@ def choose_mixed_dispatch(
     if fine == coarse:
         return None
 
-    ov = _overhead_elems()
-
     def score(idx: np.ndarray, blocks: tuple[int, int]) -> int:
         # grid steps (incl. one dummy per empty q tile) pay fixed overhead;
         # only band-touching tiles pay compute — with extent clamping on,
@@ -608,7 +586,7 @@ def choose_mixed_dispatch(
                 qr[idx], kr[idx], d_lo[idx], d_hi[idx], blocks[0], blocks[1]
             ).sum()
         )
-        return tiles * blocks[0] * blocks[1] + w * ov
+        return tiles * blocks[0] * blocks[1] + w * OVERHEAD_ELEMS
 
     all_idx = np.arange(len(qr))
     single = score(all_idx, coarse)

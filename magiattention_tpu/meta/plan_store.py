@@ -8,7 +8,7 @@ same directory (``MAGI_ATTENTION_PLAN_STORE_DIR``). Its two contracts:
 
 - **Writes never corrupt readers.** Every write goes to a process-unique
   ``.tmp-<pid>-<n>`` sibling and lands via ``os.replace`` — the same atomic
-  snapshot idiom as ``telemetry/store.py`` — so a concurrent reader sees
+  snapshot idiom as the run-history store — so a concurrent reader sees
   either the old complete blob or the new complete blob, never a torn one.
   A crash mid-write leaves only an orphan ``.tmp`` file, which the next
   store open garbage-collects once it is older than

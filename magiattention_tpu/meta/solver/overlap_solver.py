@@ -34,18 +34,6 @@ from ...config import OverlapConfig
 DCN_PER_ROW = 8.0
 
 
-def _calibrated_dcn_per_row() -> float:
-    """DCN_PER_ROW, overridden by the telemetry store's fitted constant
-    when calibration is on and a two_level_makespan fit has converged."""
-    from ...env import backend as env_backend
-
-    if not env_backend.calibration_enabled():
-        return DCN_PER_ROW
-    from ...telemetry import store as _store
-
-    return float(_store.calibrated("dcn_per_row", DCN_PER_ROW))
-
-
 @dataclass
 class OverlapStageCost:
     comm_cost: float = 0.0
@@ -108,17 +96,9 @@ class OverlapSolver:
         host_calc: float = 0.0,
         comm_per_row: float = 1.0,
         calc_per_area: float = 1.0,
-        dcn_per_row: float | None = None,
+        dcn_per_row: float = DCN_PER_ROW,
     ) -> tuple[list[int], list[OverlapStageCost]]:
-        """Returns (stage id per item, per-stage costs).
-
-        ``dcn_per_row=None`` resolves through the telemetry store's
-        calibrated constant (fit from two_level_makespan drift
-        observations) and falls back to the built-in 8.0 when no store is
-        active or no fit has converged.
-        """
-        if dcn_per_row is None:
-            dcn_per_row = _calibrated_dcn_per_row()
+        """Returns (stage id per item, per-stage costs)."""
         if not items:
             return [], []
         cfg = self.config

@@ -27,6 +27,7 @@ from typing import Any
 
 from .. import telemetry
 from ..env import resilience as env_resilience
+from ..utils.canonical import canonical_key
 from .errors import FallbackExhaustedError, InjectedFault, NumericGuardError
 from .guards import check_outputs
 from .inject import maybe_inject, should_fire
@@ -56,15 +57,9 @@ def _decision_key(runtime) -> Any:
     return type(runtime).__name__
 
 
-def _canonical(key: Any) -> str:
-    from ..telemetry.store import canonical_key
-
-    return canonical_key(key)
-
-
 def is_quarantined(key: Any, backend: str) -> bool:
     """In-process quarantine plus the store's restart-persistent rows."""
-    ck = _canonical(key)
+    ck = canonical_key(key)
     with _lock:
         if (ck, backend) in _quarantined:
             return True
@@ -76,7 +71,7 @@ def is_quarantined(key: Any, backend: str) -> bool:
 def note_trip(key: Any, backend: str, allow_quarantine: bool) -> bool:
     """Count one trip; returns True when this trip quarantines the
     backend (threshold crossed, persisted via the store when active)."""
-    ck = _canonical(key)
+    ck = canonical_key(key)
     with _lock:
         trips = _trips[(ck, backend)] = _trips.get((ck, backend), 0) + 1
         if (

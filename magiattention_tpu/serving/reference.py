@@ -7,7 +7,7 @@ makes. Per-row FFA results depend only on the unmasked rows (masked scores
 are the MASK_VALUE constant regardless of what garbage the gathered pages
 hold, and their exp2 contributions underflow to exactly 0.0), so with an
 identical chunk schedule, ``max_pages`` and env snapshot, the engine under
-``MAGI_ATTENTION_SERVE_DECODE_KERNEL=0`` must reproduce this replay
+``MAGI_ATTENTION_BACKEND_SERVE_DECODE=gather_ffa`` must reproduce this replay
 BITWISE — the serve-smoke acceptance gate.
 
 This one-token-per-tick replay is ALSO the oracle for the speculative

@@ -38,4 +38,3 @@ from .store import (  # noqa: F401
     StoreState,
     store_active,
 )
-from .drift import fit_constants, fit_scale, scan  # noqa: F401

@@ -22,22 +22,7 @@ import socket
 import uuid
 from typing import Any
 
-
-def _jsonable(x: Any) -> Any:
-    """Best-effort conversion to JSON-serializable builtins."""
-    import numpy as np
-
-    if isinstance(x, dict):
-        return {str(k): _jsonable(v) for k, v in x.items()}
-    if isinstance(x, (list, tuple)):
-        return [_jsonable(v) for v in x]
-    if isinstance(x, np.ndarray):
-        return x.tolist()
-    if isinstance(x, np.generic):
-        return x.item()
-    if isinstance(x, (str, int, float, bool)) or x is None:
-        return x
-    return str(x)
+from ..utils.canonical import jsonable
 
 
 def process_unique_path(
@@ -63,7 +48,7 @@ class JsonlSink:
             self._fd = os.open(
                 self.path, os.O_CREAT | os.O_WRONLY | os.O_APPEND, 0o644
             )
-        data = (json.dumps(_jsonable(record)) + "\n").encode("utf-8")
+        data = (json.dumps(jsonable(record)) + "\n").encode("utf-8")
         # single write syscall per line: O_APPEND makes it atomic with
         # respect to other appenders, and there is no userspace buffer to
         # lose on crash (the old sink buffered then flushed)

@@ -1,4 +1,5 @@
-"""Persistent cross-run telemetry store — the observatory's memory.
+"""Persistent cross-run telemetry store: run history and the resilience
+layer's memory.
 
 Where telemetry/registry.py streams write-only per-process JSONL, this
 module keeps a small *readable* history that survives restarts and is
@@ -15,24 +16,23 @@ shared by every process pointing at the same directory:
 
 Row kinds (``rk`` field):
 
-- ``measure`` — one timed execution of a backend for a registry decision
-  key; aggregated into per-(decision, key, backend) count/sum/min so the
-  policy layer can pick the fastest *measured* backend.
-- ``policy``  — a resolved registry decision, persisted so a warm restart
-  re-uses it with zero re-tuning (kernels/registry.py reads these back).
-- ``hist``    — aggregated ``attn_step`` / ``serve_step`` / ``plan_solve``
-  run history keyed by (mask-class signature, shape, dtype, mesh, env
-  snapshot signature), fed by :func:`ingest_event` from the collector.
-- ``obs``     — a (predicted cost, measured ms) pair for one of the
-  open-loop cost models; consumed by telemetry/drift.py.
-- ``calib``   — a fitted model constant (e.g. ``overhead_elems``,
-  ``dcn_per_row``) solvers may consume via :func:`calibration_value`.
-- ``drift``   — a measured-vs-modeled drift finding past threshold.
+- ``hist``        — aggregated ``attn_step`` / ``serve_step`` /
+  ``plan_solve`` / ``step_retry`` run history keyed by (mask-class
+  signature, shape, dtype, mesh, env snapshot signature), fed by
+  :func:`ingest_event` from the collector.
+- ``rank_health`` — the straggler monitor's per-rank observations
+  (telemetry/health.py).
+- ``quarantine``  — a backend the step watchdog quarantined for a decision
+  key (resilience/watchdog.py), so restarts remember.
+
+The store records and reports; nothing that chooses a kernel, a tile or a
+solver constant reads it. A row of any other kind — a directory written
+by a build that still kept ``measure`` / ``policy`` / ``obs`` / ``calib``
+/ ``drift`` rows — is skipped on load.
 
 Everything here is gated on :func:`store_active` — with
-``MAGI_ATTENTION_TELEMETRY`` off (or ``MAGI_ATTENTION_BACKEND_STORE=0``)
-every entry point is a cheap early return: no file I/O, no state, and the
-backend registry falls back to its legacy heuristics bit-identically.
+``MAGI_ATTENTION_TELEMETRY`` off every entry point is a cheap early
+return: no file I/O, no state.
 """
 
 from __future__ import annotations
@@ -47,23 +47,15 @@ from typing import Any
 
 from ..env import backend as env_backend
 from ..env import general as env_general
-from .export import JsonlSink, _jsonable, process_unique_path
+from ..utils.canonical import canonical_key, jsonable
+from .export import JsonlSink, process_unique_path
 
 STORE_SCHEMA_VERSION = 1
 SNAPSHOT_NAME = "store.json"
 HISTORY_PREFIX = "history"
 
-# measurements needed before a backend is considered "verified fastest"
-MIN_MEASUREMENTS = 2
-# bounded in-memory/snapshot tails (aggregates are unbounded-safe; raw
-# observation/drift rows are not)
-OBS_CAP = 512
-DRIFT_CAP = 256
-
 # collector kinds ingest_event aggregates into run history
 _HISTORY_KINDS = ("attn_step", "serve_step", "plan_solve", "step_retry")
-# collector kinds with dedicated fold logic besides run history
-_SPECIAL_KINDS = ("model_drift", "rank_health")
 # attn_step fields forming the run-history key (ISSUE: mask-class
 # signature, shape, dtype, mesh, env snapshot)
 _ATTN_KEY_FIELDS = (
@@ -74,28 +66,14 @@ _ATTN_KEY_FIELDS = (
 
 def store_active() -> bool:
     """The ONE gate every store entry point checks first."""
-    return (
-        env_general.is_telemetry_enable()
-        and env_backend.backend_store_mode() != "0"
-    )
-
-
-def canonical_key(key: Any) -> str:
-    """Stable string form of a decision/history key (dict keys sorted,
-    tuples as lists) — the join key across processes and restarts."""
-    return json.dumps(_jsonable(key), sort_keys=True, separators=(",", ":"))
+    return env_general.is_telemetry_enable()
 
 
 @dataclass
 class StoreState:
     """In-memory aggregate view of the store (snapshot + replayed rows)."""
 
-    entries: dict[str, dict[str, Any]] = field(default_factory=dict)
     history: dict[str, dict[str, Any]] = field(default_factory=dict)
-    policy: dict[str, dict[str, Any]] = field(default_factory=dict)
-    calibration: dict[str, dict[str, Any]] = field(default_factory=dict)
-    observations: dict[str, list[dict[str, Any]]] = field(default_factory=dict)
-    drift: list[dict[str, Any]] = field(default_factory=list)
     rank_health: dict[str, dict[str, Any]] = field(default_factory=dict)
     quarantine: dict[str, dict[str, Any]] = field(default_factory=dict)
 
@@ -103,28 +81,7 @@ class StoreState:
 def _apply(state: StoreState, row: dict[str, Any]) -> None:
     """Fold one history row into the aggregate state."""
     rk = row.get("rk")
-    if rk == "measure":
-        ekey = f"{row['decision']}|{row['key']}"
-        entry = state.entries.setdefault(ekey, {"count": 0, "by_backend": {}})
-        entry["count"] += 1
-        b = entry["by_backend"].setdefault(
-            row["backend"],
-            {"count": 0, "ok": 0, "wall_ms_sum": 0.0, "wall_ms_min": None},
-        )
-        b["count"] += 1
-        if row.get("ok", True):
-            b["ok"] += 1
-            ms = float(row["wall_ms"])
-            b["wall_ms_sum"] += ms
-            if b["wall_ms_min"] is None or ms < b["wall_ms_min"]:
-                b["wall_ms_min"] = ms
-    elif rk == "policy":
-        state.policy[f"{row['decision']}|{row['key']}"] = {
-            "choice": row["choice"],
-            "source": row.get("source", "heuristic"),
-            "ts": row.get("ts"),
-        }
-    elif rk == "hist":
+    if rk == "hist":
         hkey = f"{row['kind']}|{row['key']}"
         h = state.history.setdefault(
             hkey,
@@ -146,29 +103,6 @@ def _apply(state: StoreState, row: dict[str, Any]) -> None:
             if h["wall_ms_max"] is None or ms > h["wall_ms_max"]:
                 h["wall_ms_max"] = ms
         h["last_ts"] = row.get("ts")
-    elif rk == "obs":
-        obs = state.observations.setdefault(row["model"], [])
-        obs.append(
-            {
-                "predicted": float(row["predicted"]),
-                "measured_ms": float(row["measured_ms"]),
-                "extras": row.get("extras") or {},
-            }
-        )
-        if len(obs) > OBS_CAP:
-            del obs[: len(obs) - OBS_CAP]
-    elif rk == "calib":
-        state.calibration[row["name"]] = {
-            "value": float(row["value"]),
-            "n": int(row.get("n", 0)),
-            "ts": row.get("ts"),
-        }
-    elif rk == "drift":
-        state.drift.append(
-            {k: v for k, v in row.items() if k not in ("rk", "v")}
-        )
-        if len(state.drift) > DRIFT_CAP:
-            del state.drift[: len(state.drift) - DRIFT_CAP]
     elif rk == "rank_health":
         r = str(row.get("rank"))
         h = state.rank_health.setdefault(
@@ -207,7 +141,8 @@ def _apply(state: StoreState, row: dict[str, Any]) -> None:
             )
             q["trips"] = max(q["trips"], int(row.get("trips", 1)))
             q["last_ts"] = row.get("ts")
-    # unknown rk: forward-compat skip
+    # any other rk (a newer build's, or the measure / policy / obs / calib /
+    # drift rows an older build wrote): skipped
 
 
 def _load_from_disk(directory: str) -> StoreState:
@@ -217,12 +152,7 @@ def _load_from_disk(directory: str) -> StoreState:
         with open(snap_path) as f:
             snap = json.load(f)
         if isinstance(snap, dict) and snap.get("v", 0) <= STORE_SCHEMA_VERSION:
-            state.entries = snap.get("entries", {})
             state.history = snap.get("history", {})
-            state.policy = snap.get("policy", {})
-            state.calibration = snap.get("calibration", {})
-            state.observations = snap.get("observations", {})
-            state.drift = snap.get("drift", [])
             state.rank_health = snap.get("rank_health", {})
             state.quarantine = snap.get("quarantine", {})
     except (OSError, ValueError):
@@ -297,12 +227,7 @@ class TelemetryStore:
                 json.dump(
                     {
                         "v": STORE_SCHEMA_VERSION,
-                        "entries": state.entries,
                         "history": state.history,
-                        "policy": state.policy,
-                        "calibration": state.calibration,
-                        "observations": state.observations,
-                        "drift": state.drift,
                         "rank_health": state.rank_health,
                         "quarantine": state.quarantine,
                     },
@@ -326,42 +251,6 @@ class TelemetryStore:
 
     # -- writers ----------------------------------------------------------
 
-    def record_measurement(
-        self,
-        decision: str,
-        key: Any,
-        backend: str,
-        wall_ms: float,
-        ok: bool = True,
-        **extra: Any,
-    ) -> None:
-        with self._lock:
-            self._append(
-                {
-                    "rk": "measure",
-                    "decision": decision,
-                    "key": canonical_key(key),
-                    "backend": backend,
-                    "wall_ms": float(wall_ms),
-                    "ok": bool(ok),
-                    **({"ctx": _jsonable(extra)} if extra else {}),
-                }
-            )
-
-    def record_policy(
-        self, decision: str, key: Any, choice: str, source: str
-    ) -> None:
-        with self._lock:
-            self._append(
-                {
-                    "rk": "policy",
-                    "decision": decision,
-                    "key": canonical_key(key),
-                    "choice": choice,
-                    "source": source,
-                }
-            )
-
     def record_history(
         self, kind: str, key: Any, wall_ms: float | None, **extra: Any
     ) -> None:
@@ -374,36 +263,8 @@ class TelemetryStore:
             if wall_ms is not None:
                 row["wall_ms"] = float(wall_ms)
             if extra:
-                row["ctx"] = _jsonable(extra)
+                row["ctx"] = jsonable(extra)
             self._append(row)
-
-    def record_observation(
-        self,
-        model: str,
-        predicted: float,
-        measured_ms: float,
-        **extras: Any,
-    ) -> None:
-        with self._lock:
-            self._append(
-                {
-                    "rk": "obs",
-                    "model": model,
-                    "predicted": float(predicted),
-                    "measured_ms": float(measured_ms),
-                    **({"extras": _jsonable(extras)} if extras else {}),
-                }
-            )
-
-    def record_calibration(self, name: str, value: float, n: int) -> None:
-        with self._lock:
-            self._append(
-                {"rk": "calib", "name": name, "value": float(value), "n": n}
-            )
-
-    def record_drift(self, row: dict[str, Any]) -> None:
-        with self._lock:
-            self._append({"rk": "drift", **_jsonable(row)})
 
     def record_rank_health(
         self,
@@ -426,7 +287,7 @@ class TelemetryStore:
             if ewma_ms is not None:
                 row["ewma_ms"] = float(ewma_ms)
             if extra:
-                row["ctx"] = _jsonable(extra)
+                row["ctx"] = jsonable(extra)
             self._append(row)
 
     def record_quarantine(
@@ -451,37 +312,6 @@ class TelemetryStore:
 
     # -- readers ----------------------------------------------------------
 
-    def policy_for(self, decision: str, key: Any) -> dict[str, Any] | None:
-        with self._lock:
-            return self._ensure_loaded().policy.get(
-                f"{decision}|{canonical_key(key)}"
-            )
-
-    def best_backend(
-        self, decision: str, key: Any, min_count: int = MIN_MEASUREMENTS
-    ) -> tuple[str, float] | None:
-        """Fastest *verified* backend for a decision key: lowest mean
-        wall_ms among backends with >= min_count ok measurements."""
-        with self._lock:
-            entry = self._ensure_loaded().entries.get(
-                f"{decision}|{canonical_key(key)}"
-            )
-        if not entry:
-            return None
-        best: tuple[str, float] | None = None
-        for name, b in entry["by_backend"].items():
-            if b["ok"] < min_count:
-                continue
-            mean = b["wall_ms_sum"] / b["ok"]
-            if best is None or mean < best[1]:
-                best = (name, mean)
-        return best
-
-    def calibration_for(self, name: str) -> float | None:
-        with self._lock:
-            c = self._ensure_loaded().calibration.get(name)
-        return None if c is None else float(c["value"])
-
     def rank_health_view(self) -> dict[str, dict[str, Any]]:
         with self._lock:
             return {
@@ -500,7 +330,7 @@ class TelemetryStore:
             }
 
 
-# -- module-level gated access (what the registry / solvers use) ------------
+# -- module-level gated access (what the watchdog and the collector use) ----
 
 _store: TelemetryStore | None = None
 _store_lock = threading.Lock()
@@ -535,59 +365,6 @@ def reset() -> None:
         _store = None
 
 
-def policy_lookup(decision: str, key: Any) -> dict[str, Any] | None:
-    st = get_store()
-    return None if st is None else st.policy_for(decision, key)
-
-
-def policy_record(decision: str, key: Any, choice: str, source: str) -> None:
-    st = get_store()
-    if st is not None:
-        st.record_policy(decision, key, choice, source)
-
-
-def measured_best(decision: str, key: Any) -> str | None:
-    st = get_store()
-    if st is None:
-        return None
-    best = st.best_backend(decision, key)
-    return None if best is None else best[0]
-
-
-def calibration_value(name: str) -> float | None:
-    st = get_store()
-    return None if st is None else st.calibration_for(name)
-
-
-def calibrated(name: str, default: float) -> float:
-    """A store-fitted model constant, or ``default`` when the store or
-    MAGI_ATTENTION_CALIBRATION is off (or no sane fit exists). This is the
-    one entry point solvers/cost models use — off-path it is two env dict
-    reads and the built-in constant, bit-identical to pre-store behavior."""
-    if not store_active() or not env_backend.calibration_enabled():
-        return default
-    v = calibration_value(name)
-    if v is None or not (v > 0):
-        return default
-    return v
-
-
-def record_measurement(
-    decision: str, key: Any, backend: str, wall_ms: float, ok: bool = True
-) -> None:
-    st = get_store()
-    if st is not None:
-        st.record_measurement(decision, key, backend, wall_ms, ok=ok)
-
-
-def record_observation(
-    model: str, predicted: float, measured_ms: float, **extras: Any
-) -> None:
-    st = get_store()
-    if st is not None:
-        st.record_observation(model, predicted, measured_ms, **extras)
-
-
 def quarantined_backends(decision: str, key: Any) -> set[str]:
     """Restart-persistent quarantine set for a decision key; empty when
     the store is inactive (quarantine still works in-process then)."""
@@ -606,109 +383,26 @@ def record_quarantine(
 # -- collector ingest -------------------------------------------------------
 
 
-def _tile_score_prediction(
-    record: dict[str, Any],
-) -> tuple[float, float, float] | None:
-    """Re-evaluate the tile-policy cost model on a recorded plan: the same
-    ``w * (bq*bk + OVERHEAD_ELEMS)`` score choose_blocks minimized, summed
-    over the plan's groups. Uses the built-in constant (not a calibrated
-    one) — drift is measured against the open-loop model. Returns
-    (score, tile_area_term, work_count_term) so drift.fit_constants can
-    refit OVERHEAD_ELEMS from the two components."""
-    groups = record.get("plan_groups")
-    if not groups:
-        return None
-    from ..kernels.tile_policy import OVERHEAD_ELEMS
-
-    area = 0.0
-    works = 0.0
-    for g in groups:
-        try:
-            area += g["num_work"] * g["block_q"] * g["block_k"]
-            works += g["num_work"]
-        except (KeyError, TypeError):
-            return None
-    if works <= 0:
-        return None
-    return (area + works * OVERHEAD_ELEMS, area, works)
-
-
 def ingest_event(record: dict[str, Any]) -> None:
     """Collector hook: fold a telemetry record into the persistent store.
     Called for every record the collector writes; cheap kind/gate check
     first so non-store kinds cost one tuple membership test."""
     kind = record.get("kind")
-    if kind not in _HISTORY_KINDS and kind not in _SPECIAL_KINDS:
-        return
-    if not store_active():
+    if kind not in _HISTORY_KINDS and kind != "rank_health":
         return
     st = get_store()
     if st is None:
-        return
-    if kind == "model_drift":
-        st.record_drift(
-            {
-                k: record[k]
-                for k in ("model", "alpha", "rel_err", "predicted",
-                          "measured_ms", "extras")
-                if k in record
-            }
-        )
         return
     wall_ms = record.get("wall_ms")
     if kind == "attn_step":
         key = {f: record.get(f) for f in _ATTN_KEY_FIELDS}
         st.record_history("attn_step", key, wall_ms)
-        if wall_ms is not None and record.get("backend"):
-            # the step wall time is a calc_attn measurement; finer
-            # decisions (ffa_bwd, serve_decode) are measured by their own
-            # harnesses/tests and land as explicit measure rows
-            bwd_key = record.get("bwd_key")
-            # keyed exactly like DistAttnRuntime._policy_key so the
-            # registry's measured lookup joins against these rows
-            mkey = {
-                "mask_sig": record.get("mask_sig"),
-                "mesh_sig": record.get("mesh_sig"),
-                "env_sig": record.get("env_sig"),
-            }
-            st.record_measurement(
-                "calc_attn",
-                mkey,
-                str(record["backend"]),
-                float(wall_ms),
-                bwd_mode=record.get("bwd_mode"),
-            )
-            pred = _tile_score_prediction(record)
-            if pred is not None:
-                area, works = pred[1], pred[2]
-                st.record_observation(
-                    "tile_score", pred[0], float(wall_ms),
-                    mask_sig=record.get("mask_sig"),
-                    area=area, works=works,
-                )
-            if bwd_key is not None and record.get("bwd_cost") is not None:
-                st.record_observation(
-                    "bwd_cost", float(record["bwd_cost"]), float(wall_ms),
-                    bwd_mode=record.get("bwd_mode"), bwd_key=bwd_key,
-                )
     elif kind == "serve_step":
         key = {
             "occupancy": record.get("occupancy"),
             "pages_in_use": record.get("pages_in_use"),
         }
         st.record_history("serve_step", key, wall_ms)
-        backend = record.get("decode_backend")
-        if backend is None:
-            from ..kernels import registry as _kreg
-
-            backend = _kreg.last_choice("serve_decode")
-        if wall_ms is not None and backend:
-            st.record_measurement(
-                "serve_decode",
-                _kreg_last_key_or(key),
-                str(backend),
-                float(wall_ms),
-            )
     elif kind == "plan_solve":
         key = {
             k: record.get(k)
@@ -731,10 +425,3 @@ def ingest_event(record: dict[str, Any]) -> None:
             if k in record
         }
         st.record_history("step_retry", key, wall_ms)
-
-
-def _kreg_last_key_or(default: Any) -> Any:
-    from ..kernels import registry as _kreg
-
-    last = _kreg.last_key("serve_decode")
-    return default if last is None else last

@@ -5,7 +5,7 @@ mixed workload of 9 requests (ragged prompts incl. single-token and
 page-boundary lengths) through 4 batch slots:
 
 1. **Bitwise pass** — engine pinned to the gather+FFA decode rung
-   (``MAGI_ATTENTION_SERVE_DECODE_KERNEL=0``); every request must
+   (``MAGI_ATTENTION_BACKEND_SERVE_DECODE=gather_ffa``); every request must
    complete and every generated hidden row must equal the sequential
    per-request replay (serving/reference.py) BITWISE. This is the
    determinism contract of the scheduler + paged cache: admission order,
@@ -83,7 +83,7 @@ def bitwise_pass(model: ToyModel) -> None:
         prefill_chunk=16,
     )
     requests = make_requests(model)
-    with scoped_env({"MAGI_ATTENTION_SERVE_DECODE_KERNEL": "0"}):
+    with scoped_env({"MAGI_ATTENTION_BACKEND_SERVE_DECODE": "gather_ffa"}):
         engine = ServeEngine(model, config)
         finished = engine.run(requests)
 
@@ -121,7 +121,7 @@ def kernel_pass(model: ToyModel) -> None:
         )
         for i, (length, new_tokens) in enumerate([(5, 2), (16, 3), (9, 2)])
     ]
-    with scoped_env({"MAGI_ATTENTION_SERVE_DECODE_KERNEL": "1"}):
+    with scoped_env({"MAGI_ATTENTION_BACKEND_SERVE_DECODE": "paged_decode"}):
         engine = ServeEngine(model, config)
         finished = engine.run(requests)
     assert len(finished) == len(requests)
@@ -220,7 +220,7 @@ def spec_pass(model: ToyModel) -> None:
 
     # greedy self-draft on the reference rung: real rollbacks, commits
     # bitwise vs the one-token-per-tick replay oracle
-    with scoped_env({"MAGI_ATTENTION_SERVE_DECODE_KERNEL": "0"}):
+    with scoped_env({"MAGI_ATTENTION_BACKEND_SERVE_DECODE": "gather_ffa"}):
         stats = _run_stats(ServeEngine(model, config), requests)
     _assert_bitwise(requests, reference, "spec greedy")
     attempted = sum(s["draft_attempted"] for s in stats)
@@ -232,7 +232,7 @@ def spec_pass(model: ToyModel) -> None:
 
     # oracle draft: every row must commit (the full-accept end)
     oracle_reqs = make_requests(model)
-    with scoped_env({"MAGI_ATTENTION_SERVE_DECODE_KERNEL": "0"}):
+    with scoped_env({"MAGI_ATTENTION_BACKEND_SERVE_DECODE": "gather_ffa"}):
         o_stats = _run_stats(
             ServeEngine(model, config, draft_fn=oracle_draft_fn(reference)),
             oracle_reqs,
@@ -265,7 +265,7 @@ def int8_pass(model: ToyModel) -> None:
     )
     # bitwise vs the int8 replay oracle on the reference rung
     requests = make_requests(model)
-    with scoped_env({"MAGI_ATTENTION_SERVE_DECODE_KERNEL": "0"}):
+    with scoped_env({"MAGI_ATTENTION_BACKEND_SERVE_DECODE": "gather_ffa"}):
         ServeEngine(model, config).run(requests)
     _assert_bitwise(requests, run_reference(model, requests, config), "int8")
 

@@ -209,13 +209,7 @@ SECTION_SCHEMAS: dict[str, dict[str, str]] = {
         "selections": "backend_select records (one per decision+key+choice)",
         "by_decision": "per decision: choice counts, source counts, last",
         "sources": "total counts per resolution source "
-                   "(pin/policy/measured/heuristic)",
-    },
-    "model_drift": {
-        "findings": "model_drift records (rel_err past threshold)",
-        "by_model": "per cost model: count, worst rel_err, last alpha",
-        "worst": "the single worst finding (model, rel_err, predicted_ms, "
-                 "measured_ms)",
+                   "(pin/heuristic, or a call site's own rule)",
     },
     "rank_health": {
         "observations": "rank_health records (one per observed step wall)",
@@ -234,13 +228,7 @@ SECTION_SCHEMAS: dict[str, dict[str, str]] = {
     },
     "store": {
         "dir": "store directory read (--store)",
-        "policy_entries": "persisted registry decisions",
-        "policy_by_decision": "persisted decision counts per decision name",
-        "measure_entries": "aggregated (decision, key) measurement entries",
         "history": "run-history aggregate counts per kind",
-        "observations": "cost-model observation counts per model",
-        "calibration": "fitted constants {name: {value, n}}",
-        "drift_rows": "persisted drift findings",
         "rank_health_rows": "persisted per-rank health aggregates",
         "quarantine_rows": "persisted quarantined (decision, key, backend)",
     },
@@ -652,35 +640,6 @@ def aggregate(records: list[dict]) -> dict:
             "sources": dict(sorted(sources.items())),
         }
 
-    drifts = kinds.get("model_drift", [])
-    if drifts:
-        by_model: dict[str, dict] = {}
-        worst = None
-        for r in drifts:
-            m = r.get("model", "?")
-            rel = r.get("rel_err")
-            d = by_model.setdefault(
-                m, {"count": 0, "max_rel_err": None, "alpha_last": None}
-            )
-            d["count"] += 1
-            if rel is not None:
-                if d["max_rel_err"] is None or rel > d["max_rel_err"]:
-                    d["max_rel_err"] = rel
-                if worst is None or rel > worst["rel_err"]:
-                    worst = {
-                        "model": m,
-                        "rel_err": rel,
-                        "predicted_ms": r.get("predicted_ms"),
-                        "measured_ms": r.get("measured_ms"),
-                    }
-            if r.get("alpha") is not None:
-                d["alpha_last"] = r["alpha"]
-        agg["model_drift"] = {
-            "findings": len(drifts),
-            "by_model": {k: by_model[k] for k in sorted(by_model)},
-            "worst": worst,
-        }
-
     health = kinds.get("rank_health", [])
     if health:
         per_rank: dict[int, dict] = {}
@@ -737,36 +696,20 @@ def aggregate(records: list[dict]) -> dict:
 
 def aggregate_store(store_dir: str) -> dict:
     """The persistent store's aggregate view (--store): reads
-    ``store.json`` + ``history-*.jsonl`` via the package's own loader so
-    the report agrees byte-for-byte with what the registry reads back."""
+    ``store.json`` + ``history-*.jsonl`` via the package's own loader."""
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     from magiattention_tpu.telemetry.store import _load_from_disk
 
     state = _load_from_disk(store_dir)
-    policy_by_decision: dict[str, int] = {}
-    for k in state.policy:
-        dec = k.split("|", 1)[0]
-        policy_by_decision[dec] = policy_by_decision.get(dec, 0) + 1
     history: dict[str, int] = {}
     for h in state.history.values():
         kind = h.get("kind", "?")
         history[kind] = history.get(kind, 0) + 1
     return {
         "dir": store_dir,
-        "policy_entries": len(state.policy),
-        "policy_by_decision": dict(sorted(policy_by_decision.items())),
-        "measure_entries": len(state.entries),
         "history": dict(sorted(history.items())),
-        "observations": {
-            m: len(v) for m, v in sorted(state.observations.items())
-        },
-        "calibration": {
-            k: {"value": v.get("value"), "n": v.get("n")}
-            for k, v in sorted(state.calibration.items())
-        },
-        "drift_rows": len(state.drift),
-        "rank_health_rows": len(getattr(state, "rank_health", {})),
-        "quarantine_rows": len(getattr(state, "quarantine", {})),
+        "rank_health_rows": len(state.rank_health),
+        "quarantine_rows": len(state.quarantine),
     }
 
 
@@ -846,7 +789,7 @@ def format_summary(agg: dict) -> str:
                 f"  backward: mode={st['bwd_mode']} "
                 f"(fused={fused_count} split={split_count} steps) — fused "
                 "one-pass shares the S/P recompute across dq/dk/dv "
-                "(5 vs 7 tile matmuls; MAGI_ATTENTION_FFA_FUSED_BWD)"
+                "(5 vs 7 tile matmuls; MAGI_ATTENTION_BACKEND_FFA_BWD)"
             )
         if st.get("wall_ms_last") is not None:
             lines.append(
@@ -1095,26 +1038,6 @@ def format_summary(agg: dict) -> str:
             )
             lines.append(f"  {dec}: {choices} (last={d['last_choice']})")
 
-    dr = agg.get("model_drift")
-    if dr:
-        lines.append("")
-        lines.append(f"model drift findings={dr['findings']}")
-        for m, d in dr["by_model"].items():
-            rel = d["max_rel_err"]
-            rel_s = f"{rel:.2f}" if rel is not None else "?"
-            alpha = d["alpha_last"]
-            alpha_s = f"{alpha:.3g}" if alpha is not None else "?"
-            lines.append(
-                f"  {m}: {d['count']} finding(s), worst rel_err={rel_s}, "
-                f"fitted scale alpha={alpha_s}"
-            )
-        w = dr.get("worst")
-        if w and w.get("predicted_ms") is not None:
-            lines.append(
-                f"  worst: {w['model']} predicted {w['predicted_ms']:.2f} ms"
-                f" vs measured {w['measured_ms']:.2f} ms"
-            )
-
     rh = agg.get("rank_health")
     if rh:
         lines.append("")
@@ -1159,26 +1082,12 @@ def format_summary(agg: dict) -> str:
     if so:
         lines.append("")
         hist = " ".join(f"{k}={v}" for k, v in so["history"].items()) or "none"
-        obs = (
-            " ".join(f"{k}={v}" for k, v in so["observations"].items())
-            or "none"
-        )
-        lines.append(
-            f"store [{so['dir']}]: policy={so['policy_entries']} "
-            f"measure_entries={so['measure_entries']} "
-            f"drift_rows={so['drift_rows']}"
-        )
-        lines.append(f"  history: {hist}")
-        lines.append(f"  observations: {obs}")
+        lines.append(f"store [{so['dir']}]: history: {hist}")
         if so.get("rank_health_rows") or so.get("quarantine_rows"):
             lines.append(
                 f"  degraded ranks: rank_health_rows="
                 f"{so['rank_health_rows']} "
                 f"quarantine_rows={so['quarantine_rows']}"
-            )
-        for name, c in so["calibration"].items():
-            lines.append(
-                f"  calibrated {name}={c['value']:.4g} (n={c['n']})"
             )
     return "\n".join(lines)
 

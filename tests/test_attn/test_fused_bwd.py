@@ -1,4 +1,4 @@
-"""Fused one-pass FFA backward tests (MAGI_ATTENTION_FFA_FUSED_BWD).
+"""Fused one-pass FFA backward tests (MAGI_ATTENTION_BACKEND_FFA_BWD).
 
 Parity: the fused kernel (shared score recompute for dq/dk/dv, dq
 revisit-accumulated across the k-major traversal on the plan's QVF/QVL
@@ -8,7 +8,7 @@ including the extent-clamped fragmented plans.
 
 Units: the Pallas delta kernel (rowsum(dO ⊙ O)), the tile_policy
 arithmetic-intensity cost model (the analytic 7 → 5 tile-matmul drop),
-mode resolution (`ffa_bwd_mode` flag/meta/VMEM gating), and the
+mode resolution (`ffa_bwd_mode` pin/meta/VMEM gating), and the
 resilience rung: a fused-kernel failure degrades to split under
 MAGI_ATTENTION_FALLBACK=1 and raises typed without it.
 """
@@ -78,7 +78,7 @@ def test_fused_grad_parity_vs_sdpa_online(family, g):
         np.random.default_rng(12).standard_normal(q.shape), jnp.float32
     )
     grads = _grads(q, k, v, qr, kr, lo, hi, w,
-                   env={"MAGI_ATTENTION_FFA_FUSED_BWD": "1"})
+                   env={"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused"})
     grads_ref = _grads(q, k, v, qr, kr, lo, hi, w, ref=True)
     for name, got, want in zip("dq dk dv".split(), grads, grads_ref):
         assert_close(got, want, msg=f"{family} g={g} {name}",
@@ -106,9 +106,9 @@ def test_fused_vs_split_parity(family, dtype, pack):
     )
     base_env = {"MAGI_ATTENTION_FFA_GQA_PACK_DKV": pack}
     fused = _grads(q, k, v, qr, kr, lo, hi, w,
-                   env={**base_env, "MAGI_ATTENTION_FFA_FUSED_BWD": "1"})
+                   env={**base_env, "MAGI_ATTENTION_BACKEND_FFA_BWD": "fused"})
     split = _grads(q, k, v, qr, kr, lo, hi, w,
-                   env={**base_env, "MAGI_ATTENTION_FFA_FUSED_BWD": "0"})
+                   env={**base_env, "MAGI_ATTENTION_BACKEND_FFA_BWD": "split"})
     for name, got, want in zip("dq dk dv".split(), fused, split):
         assert_close(got, want, msg=f"{family} pack={pack} {name}",
                      **TOL[dtype])
@@ -126,30 +126,30 @@ def _params(bq=256, bk=512, group=1, **over):
 
 
 class TestBwdModeResolution:
-    def test_flag_zero_always_split(self):
-        with scoped_env({"MAGI_ATTENTION_FFA_FUSED_BWD": "0"}):
+    def test_split_pin_always_split(self):
+        with scoped_env({"MAGI_ATTENTION_BACKEND_FFA_BWD": "split"}):
             assert ffa_bwd_mode(_params(), 1024, D, D, 4, META_DIM) == "split"
 
     def test_legacy_meta_without_visit_cols_is_split(self):
         # 13-col metas (pre-QVF/QVL) cannot drive the fused kernel
-        with scoped_env({"MAGI_ATTENTION_FFA_FUSED_BWD": "1"}):
+        with scoped_env({"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused"}):
             assert ffa_bwd_mode(_params(), 1024, D, D, 4, QVL) == "split"
 
-    def test_flag_one_fused_when_feasible(self):
-        with scoped_env({"MAGI_ATTENTION_FFA_FUSED_BWD": "1"}):
+    def test_fused_pin_fused_when_feasible(self):
+        with scoped_env({"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused"}):
             assert ffa_bwd_mode(_params(), 1024, D, D, 4, META_DIM) == "fused"
             assert resolved_bwd_mode(_params(), 1024, D, D, 4) == "fused"
 
     def test_vmem_infeasible_forces_split_even_under_flag_one(self):
         # (1024, 1024) fp32 tiles at head_dim 256: the fused residency
         # (dkv blocks + double-buffered dq out + aliased zeros input)
-        # busts the 14 MiB budget, so flag=1 still resolves to split
+        # busts the 14 MiB budget, so a fused pin still resolves to split
         big = _params(bq=1024, bk=1024)
-        with scoped_env({"MAGI_ATTENTION_FFA_FUSED_BWD": "1"}):
+        with scoped_env({"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused"}):
             assert ffa_bwd_mode(big, 2048, 256, 256, 4, META_DIM) == "split"
 
     def test_forced_fallback_parity(self, monkeypatch):
-        """flag=1 with the feasibility gate forced shut: the dispatch
+        """a fused pin with the feasibility gate forced shut: the dispatch
         silently runs split and still matches the reference."""
         qr, kr, lo, hi = FAMILIES["causal"]
         q, k, v = _inputs(jnp.float32, hq=HK, seed=15)
@@ -159,7 +159,7 @@ class TestBwdModeResolution:
         monkeypatch.setattr(ffa, "fused_bwd_feasible",
                             lambda *a, **kw: False)
         grads = _grads(q, k, v, qr, kr, lo, hi, w,
-                       env={"MAGI_ATTENTION_FFA_FUSED_BWD": "1"})
+                       env={"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused"})
         monkeypatch.undo()
         grads_ref = _grads(q, k, v, qr, kr, lo, hi, w, ref=True)
         for name, got, want in zip("dq dk dv".split(), grads, grads_ref):
@@ -184,7 +184,7 @@ class TestFusedFallbackRung:
         monkeypatch.setattr(ffa, "_ffa_bwd_fused_pallas_gqa", self._boom)
         grads = _grads(
             q, k, v, qr, kr, lo, hi, w,
-            env={"MAGI_ATTENTION_FFA_FUSED_BWD": "1",
+            env={"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused",
                  "MAGI_ATTENTION_FALLBACK": "1"},
         )
         monkeypatch.undo()
@@ -201,7 +201,7 @@ class TestFusedFallbackRung:
         monkeypatch.setattr(ffa, "_ffa_bwd_fused_pallas_gqa", self._boom)
         with pytest.raises(InjectedFault, match="kernel_lowering"):
             _grads(q, k, v, qr, kr, lo, hi, w,
-                   env={"MAGI_ATTENTION_FFA_FUSED_BWD": "1",
+                   env={"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused",
                         "MAGI_ATTENTION_FALLBACK": "0"})
 
 

@@ -41,19 +41,19 @@ ALL_BODIES = {
 PLAIN_Q_MAJOR = {"MAGI_ATTENTION_FFA_GQA_PACK": "0",
                  "MAGI_ATTENTION_FFA_GQA_PACK_DQ": "0"}
 VARIANTS = [
-    ("split_default", 2, {"MAGI_ATTENTION_FFA_FUSED_BWD": "0"},
+    ("split_default", 2, {"MAGI_ATTENTION_BACKEND_FFA_BWD": "split"},
      {"_fwd_kernel_gqa", "_delta_kernel", "_bwd_dq_kernel_gqa",
       "_bwd_dkv_kernel_gqa"}),
-    ("split_mha", 1, {"MAGI_ATTENTION_FFA_FUSED_BWD": "0"},
+    ("split_mha", 1, {"MAGI_ATTENTION_BACKEND_FFA_BWD": "split"},
      {"_fwd_kernel", "_delta_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"}),
     ("split_plain_q_major", 2,
-     {"MAGI_ATTENTION_FFA_FUSED_BWD": "0", **PLAIN_Q_MAJOR},
+     {"MAGI_ATTENTION_BACKEND_FFA_BWD": "split", **PLAIN_Q_MAJOR},
      {"_fwd_kernel", "_delta_kernel", "_bwd_dq_kernel",
       "_bwd_dkv_kernel_gqa"}),
-    ("fused_gqa_packed", 2, {"MAGI_ATTENTION_FFA_FUSED_BWD": "1"},
+    ("fused_gqa_packed", 2, {"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused"},
      {"_fwd_kernel_gqa", "_delta_kernel", "_bwd_fused_kernel_gqa"}),
     ("fused_plain", 2,
-     {"MAGI_ATTENTION_FFA_FUSED_BWD": "1",
+     {"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused",
       "MAGI_ATTENTION_FFA_GQA_PACK_DKV": "0", **PLAIN_Q_MAJOR},
      {"_fwd_kernel", "_delta_kernel", "_bwd_fused_kernel"}),
 ]

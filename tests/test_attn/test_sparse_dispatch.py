@@ -155,15 +155,16 @@ def _mixed_mask(seq=S):
     return qr, kr, lo, hi
 
 
-@pytest.mark.parametrize("mode", ["0", "1", "auto"])
+@pytest.mark.parametrize("mode", ["single", "mixed", None])
 def test_mixed_dispatch_parity(mode):
     """The two-pass LSE-merged dispatch matches the single-plan path and
-    the reference in every MAGI_ATTENTION_FFA_MIXED_BLOCKS mode."""
+    the reference under every MAGI_ATTENTION_BACKEND_MIXED_BLOCKS pin and
+    none."""
     qr, kr, lo, hi = _mixed_mask()
     q, k, v = _inputs(jnp.float32, hq=4, seed=3)
     rng = np.random.default_rng(4)
     w = jnp.asarray(rng.standard_normal(q.shape), dtype=jnp.float32)
-    with scoped_env({"MAGI_ATTENTION_FFA_MIXED_BLOCKS": mode}):
+    with scoped_env({"MAGI_ATTENTION_BACKEND_MIXED_BLOCKS": mode}):
         _cached_plan.cache_clear()
 
         def loss(q, k, v):
@@ -198,7 +199,7 @@ def test_clamp_off_matches_clamp_on():
     for flag in ("1", "0"):
         with scoped_env({
             "MAGI_ATTENTION_FFA_EXTENT_CLAMP": flag,
-            "MAGI_ATTENTION_FFA_MIXED_BLOCKS": "0",
+            "MAGI_ATTENTION_BACKEND_MIXED_BLOCKS": "single",
         }):
             _cached_plan.cache_clear()
             outs[flag] = ffa_attn(q, k, v, qr, kr, d_lo=lo, d_hi=hi)
@@ -304,9 +305,9 @@ def test_choose_mixed_dispatch_modes():
     qr, kr, lo, hi = _mixed_mask(seq)
     one = np.asarray([[0, seq]], np.int32)
     flo, fhi = types_to_bands(one, one, np.asarray([FULL], np.int32))
-    with scoped_env({"MAGI_ATTENTION_FFA_MIXED_BLOCKS": "0"}):
+    with scoped_env({"MAGI_ATTENTION_BACKEND_MIXED_BLOCKS": "single"}):
         assert choose_mixed_dispatch(qr, kr, lo, hi, seq, seq) is None
-    with scoped_env({"MAGI_ATTENTION_FFA_MIXED_BLOCKS": "1"}):
+    with scoped_env({"MAGI_ATTENTION_BACKEND_MIXED_BLOCKS": "mixed"}):
         mix = choose_mixed_dispatch(qr, kr, lo, hi, seq, seq)
         assert mix is not None
         # the split partitions the slice set, dense/fine tilings distinct
@@ -315,7 +316,7 @@ def test_choose_mixed_dispatch_modes():
         assert mix.coarse_blocks != mix.fine_blocks
         # a single dense slice has nothing to split
         assert choose_mixed_dispatch(one, one, flo, fhi, seq, seq) is None
-    with scoped_env({"MAGI_ATTENTION_FFA_MIXED_BLOCKS": "auto"}):
+    with scoped_env({"MAGI_ATTENTION_BACKEND_MIXED_BLOCKS": None}):
         mix = choose_mixed_dispatch(qr, kr, lo, hi, seq, seq)
         # the dense-1024 + 8x128-diag split is profitable under the model
         assert mix is not None

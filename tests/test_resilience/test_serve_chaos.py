@@ -47,8 +47,8 @@ needs_mesh = pytest.mark.skipif(
 
 @pytest.fixture(autouse=True)
 def _telemetry_into_tmp(monkeypatch, tmp_path):
-    """These tests turn telemetry on; its JSONL and persisted store (backend
-    policies, chaos quarantines) must not land in ./telemetry of the
+    """These tests turn telemetry on; its JSONL and persisted store (run
+    history, chaos quarantines) must not land in ./telemetry of the
     checkout, where a later chip run's copy of the tree would carry them."""
     monkeypatch.setenv("MAGI_ATTENTION_TELEMETRY_DIR", str(tmp_path / "tel"))
 
@@ -72,11 +72,11 @@ def assert_recovers_bitwise(monkeypatch, config, hops_per_inject_step=1):
     sharded -> paged_decode -> gather, so its faulted steps inject and
     hop twice; every other backend lands on gather in one hop)."""
     model = ToyModel.create()
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     base = make_requests(model)
     ServeEngine(model, config).run(base)
 
-    monkeypatch.delenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", raising=False)
+    monkeypatch.delenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", raising=False)
     monkeypatch.setenv("MAGI_ATTENTION_FAULT_INJECT", "serve_decode")
     monkeypatch.setenv("MAGI_ATTENTION_FALLBACK", "1")
     monkeypatch.setenv("MAGI_ATTENTION_TELEMETRY", "1")
@@ -100,7 +100,7 @@ def assert_recovers_bitwise(monkeypatch, config, hops_per_inject_step=1):
 
 def assert_raises_typed(monkeypatch, config):
     model = ToyModel.create()
-    monkeypatch.delenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", raising=False)
+    monkeypatch.delenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", raising=False)
     monkeypatch.setenv("MAGI_ATTENTION_FAULT_INJECT", "serve_decode")
     monkeypatch.delenv("MAGI_ATTENTION_FALLBACK", raising=False)
     engine = ServeEngine(model, config)
@@ -114,11 +114,11 @@ class TestServeDecode:
         gather+FFA, which is exactly the rung the pinned configuration
         runs — so recovery is not just finite but bitwise-identical."""
         model = ToyModel.create()
-        monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+        monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
         base = make_requests(model)
         ServeEngine(model, CONFIG).run(base)
 
-        monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "1")
+        monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "paged_decode")
         monkeypatch.setenv("MAGI_ATTENTION_FAULT_INJECT", "serve_decode")
         monkeypatch.setenv("MAGI_ATTENTION_FALLBACK", "1")
         monkeypatch.setenv("MAGI_ATTENTION_TELEMETRY", "1")
@@ -144,7 +144,7 @@ class TestServeDecode:
 
     def test_raises_typed_without_fallback(self, monkeypatch):
         model = ToyModel.create()
-        monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "1")
+        monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "paged_decode")
         monkeypatch.setenv("MAGI_ATTENTION_FAULT_INJECT", "serve_decode")
         monkeypatch.delenv("MAGI_ATTENTION_FALLBACK", raising=False)
         engine = ServeEngine(model, CONFIG)

@@ -49,7 +49,7 @@ def assert_bitwise(requests, reference):
 
 
 def test_engine_matches_reference_bitwise(model, monkeypatch):
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     config = ServeConfig(
         page_size=8, num_pages=12, max_slots=3, max_pages_per_seq=4,
         prefill_chunk=8,
@@ -67,7 +67,7 @@ def test_engine_matches_reference_bitwise(model, monkeypatch):
 def test_eviction_is_output_transparent(model, monkeypatch):
     """A pool tight enough to force eviction/restart must still produce
     bitwise-identical outputs — restarts recompute exactly."""
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     config = ServeConfig(
         page_size=4, num_pages=6, max_slots=3, max_pages_per_seq=6,
         prefill_chunk=8,
@@ -85,7 +85,7 @@ def test_eviction_is_output_transparent(model, monkeypatch):
 def test_unservable_request_raises_typed(model, monkeypatch):
     """One request alone outgrowing the whole pool surfaces the typed
     PageExhaustedError (nothing else is evictable)."""
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     config = ServeConfig(
         page_size=4, num_pages=2, max_slots=2, max_pages_per_seq=4,
         prefill_chunk=8,
@@ -96,7 +96,7 @@ def test_unservable_request_raises_typed(model, monkeypatch):
 
 
 def test_serve_step_telemetry_round_trip(model, monkeypatch, tmp_path):
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     monkeypatch.setenv("MAGI_ATTENTION_TELEMETRY", "1")
     monkeypatch.setenv("MAGI_ATTENTION_TELEMETRY_DIR", str(tmp_path))
     telemetry.reset()
@@ -148,7 +148,7 @@ def test_serve_step_telemetry_round_trip(model, monkeypatch, tmp_path):
 
 
 def test_telemetry_off_is_zero_overhead(model, monkeypatch):
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     monkeypatch.delenv("MAGI_ATTENTION_TELEMETRY", raising=False)
     telemetry.reset()
     config = ServeConfig(

@@ -61,7 +61,7 @@ def test_spec_greedy_draft_commits_bitwise_with_rollback(model, monkeypatch):
     """The greedy self-draft misses often (it ignores the cache), so this
     run exercises REAL rollbacks — and the committed tokens must still be
     a bitwise replay of the sequential oracle."""
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     requests = make_requests(model, WORKLOAD)
     engine = ServeEngine(model, SPEC_CONFIG)
     stats = run_collect(engine, requests)
@@ -80,7 +80,7 @@ def test_spec_oracle_draft_accepts_every_row(model, monkeypatch):
     """With the oracle draft (true next inputs) every verify row commits:
     accept_rate == 1 on every tick that decoded, and the engine finishes
     in fewer decode ticks than one-token-per-tick."""
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     requests = make_requests(model, WORKLOAD)
     reference = run_reference(model, requests, SPEC_CONFIG)
     engine = ServeEngine(
@@ -103,7 +103,7 @@ def test_spec_kernel_rung_within_tolerance(model, monkeypatch):
     oracle: same token COUNT, outputs within kernel tolerance (the rung is
     not bitwise vs gather, so accept decisions may differ — commits still
     track the oracle trajectory to fp32 accumulation error)."""
-    monkeypatch.delenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", raising=False)
+    monkeypatch.delenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", raising=False)
     requests = make_requests(model, WORKLOAD)
     engine = ServeEngine(model, SPEC_CONFIG)
     run_collect(engine, requests)
@@ -123,7 +123,7 @@ def test_spec_kernel_rung_within_tolerance(model, monkeypatch):
 def test_int8_engine_bitwise_vs_int8_oracle(model, monkeypatch):
     """Quantized append is a pure function of a page's append history, so
     the int8 engine on the gather rung replays the int8 oracle bitwise."""
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     requests = make_requests(model, WORKLOAD)
     ServeEngine(model, INT8_CONFIG).run(requests)
     assert_bitwise(requests, run_reference(model, requests, INT8_CONFIG))
@@ -132,7 +132,7 @@ def test_int8_engine_bitwise_vs_int8_oracle(model, monkeypatch):
 def test_int8_within_tolerance_of_f32(model, monkeypatch):
     """int8-vs-f32 is the quantization error itself — bounded, not
     bitwise. Covers both the kernel rung (unpinned) and the f32 oracle."""
-    monkeypatch.delenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", raising=False)
+    monkeypatch.delenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", raising=False)
     requests = make_requests(model, WORKLOAD)
     ServeEngine(model, INT8_CONFIG).run(requests)
     f32_ref = run_reference(model, requests, F32_CONFIG)
@@ -174,7 +174,7 @@ def test_int8_at_least_doubles_slot_residency():
     reason="sharded rung needs >=2 devices (serve-smoke forces a CPU mesh)",
 )
 def test_sharded_engine_bitwise_vs_single_device(model, monkeypatch):
-    monkeypatch.delenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", raising=False)
+    monkeypatch.delenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", raising=False)
     single = make_requests(model, WORKLOAD)
     ServeEngine(model, F32_CONFIG).run(single)
 
@@ -196,7 +196,7 @@ def test_sharded_engine_bitwise_vs_single_device(model, monkeypatch):
 def test_serve_step_stats_carry_scale_stamps(model, monkeypatch):
     """Every tick's stats (== the serve_step telemetry record) must stamp
     the scale knobs so the telemetry report can segment by them."""
-    monkeypatch.setenv("MAGI_ATTENTION_SERVE_DECODE_KERNEL", "0")
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_SERVE_DECODE", "gather_ffa")
     engine = ServeEngine(model, SPEC_CONFIG)
     stats = run_collect(engine, make_requests(model, [(5, 2)], seed=110))
     for s in stats:
