@@ -388,6 +388,7 @@ def run_bwd_suite() -> int:
                 num_k_tiles=plan.num_k_tiles, block_q=bq, block_k=bk,
                 softmax_scale=float(D) ** -0.5, softcap=0.0,
                 group=HQ // HK, interpret=_should_interpret(),
+                min_revisit_distance=plan.min_revisit_distance,
             )
             auto_mode = resolved_bwd_mode(
                 prm, plan.num_q_tiles * bq, D, D,

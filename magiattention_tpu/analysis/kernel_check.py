@@ -388,7 +388,9 @@ def capture_ffa_contracts(spec: AuditSpec) -> list[KernelContract]:
         overrides.update(
             block_q_dkv=spec.dkv_blocks[0], block_k_dkv=spec.dkv_blocks[1],
             num_work_dkv=plan_dkv.num_work_t,
+            min_revisit_distance=plan_dkv.min_revisit_distance,
         )
+    overrides.setdefault("min_revisit_distance", plan.min_revisit_distance)
 
     params = ffa.FFAParams(
         num_work=plan.num_work,
@@ -1196,6 +1198,8 @@ def _guard_conds(expr: ast.expr) -> list[tuple[str, str]] | None:
         and isinstance(expr.left, ast.Name)
     ):
         return [(expr.left.id, ast.unparse(expr.comparators[0]))]
+    if isinstance(expr, ast.Name):  # a boolean local, e.g. ``moved``
+        return [(expr.id, "True")]
     return None
 
 
@@ -1452,11 +1456,37 @@ def _check_kernel_sources_one(
         # content is undefined; interpret mode hides this — (b) when a
         # last-visit correction is declared (flush_guard not None), flush
         # exactly once on the LAST visit, and (c) only ever accumulate
-        # (+=) in between, never overwrite
+        # (+=) in between, never overwrite. Where the visits are not
+        # adjacent the chip writes the window back and does NOT read it
+        # back: (d) a declared ``readback`` operand (the output's own
+        # array, aliased and indexed like it) must be copied in on a later
+        # visit — one plain store, under the first-visit guard's complement
         for rv in revisits:
             rout = rv["out"]
             rvf = rv["init_guard"]
             rvl = rv.get("flush_guard")
+            rback = rv.get("readback")
+            r_back_ids: set[int] = set()
+            if rback is not None:
+                for conds, node in blocks:
+                    if (rvf, "0") not in conds:
+                        continue
+                    for a in _subscript_stores(node, (rout,))[rout]:
+                        if isinstance(a, ast.Assign) and any(
+                            isinstance(n, ast.Name) and n.id == rback
+                            for n in ast.walk(a.value)
+                        ):
+                            r_back_ids.add(id(a))
+                if len(r_back_ids) != 1:
+                    report.add(
+                        "K2", ERROR, site,
+                        f"revisit-accumulated output '{rout}' is read back "
+                        f"from '{rback}' {len(r_back_ids)} times under the "
+                        f"{rvf} == 0 (later-visit) guard — the contract "
+                        f"requires exactly one: the chip does not fetch an "
+                        f"output window, so a later visit would accumulate "
+                        f"on whatever tile the buffer held last",
+                    )
             r_init_ids: set[int] = set()
             has_init = False
             for conds, node in blocks:
@@ -1503,7 +1533,7 @@ def _check_kernel_sources_one(
                         f"one last-visit flush",
                     )
             for a in _subscript_stores(fn, (rout,))[rout]:
-                if id(a) in r_init_ids or id(a) in r_flush_ids:
+                if id(a) in r_init_ids | r_flush_ids | r_back_ids:
                     continue
                 if not isinstance(a, ast.AugAssign):
                     report.add(
@@ -1911,11 +1941,12 @@ _TOY_CONTRACTS = {
 }
 
 # minimal fused-style kernel: a scratch accumulator (is_first/is_last)
-# PLUS a revisit-accumulated output (qvf/qvl) — the shape the
-# deleted_revisit_init mutation operates on
+# PLUS a revisit-accumulated output (qvf/qvl) read back from its aliased
+# operand — the shape the deleted_revisit_init and
+# deleted_revisit_readback mutations operate on
 _TOY_FUSED_KERNEL_SRC = '''
-def _toy_fused_kernel(qt_ref, kt_ref, meta_ref, x_ref, dq_ref, o_ref,
-                      acc_scr):
+def _toy_fused_kernel(qt_ref, kt_ref, meta_ref, x_ref, dqin_ref, dq_ref,
+                      o_ref, acc_scr):
     w = pl.program_id(1)
     is_first = meta_ref[w, IS_FIRST]
     is_last = meta_ref[w, IS_LAST]
@@ -1929,6 +1960,12 @@ def _toy_fused_kernel(qt_ref, kt_ref, meta_ref, x_ref, dq_ref, o_ref,
     @pl.when(qvf == 1)
     def _():
         dq_ref[0] = jnp.zeros((8, 8), jnp.float32)
+
+    moved = (w == 0) | (qt_ref[w] != qt_ref[w - 1])
+
+    @pl.when(moved & (qvf == 0))
+    def _():
+        dq_ref[0] = dqin_ref[0]
 
     contrib = jax.lax.dot_general(
         x_ref[:], x_ref[:], (((1,), (0,)), ((), ())),
@@ -1955,7 +1992,8 @@ _TOY_FUSED_CONTRACTS = {
         init_guard="is_first",
         flush_guard="is_last",
         group_inner=None,
-        revisit=dict(out="dq_ref", init_guard="qvf", flush_guard="qvl"),
+        revisit=dict(out="dq_ref", init_guard="qvf", flush_guard="qvl",
+                     readback="dqin_ref"),
     ),
 }
 
@@ -2061,6 +2099,19 @@ def run_seeded_mutations() -> list[dict]:
         # first visit, so only K2's revisit rule can catch it
         src = _TOY_FUSED_KERNEL_SRC
         start = src.index("    @pl.when(qvf == 1)")
+        end = src.index("    moved = ")
+        check_kernel_sources(
+            report, src[:start] + src[end:], _TOY_FUSED_CONTRACTS,
+            "mutation.py",
+        )
+
+    def no_revisit_readback(report: VerifyReport) -> None:
+        # delete the later-visit copy-in of the aliased dq operand: the
+        # interpreter tier-1 runs keeps an output's contents between
+        # visits and still passes, while the chip accumulates on whatever
+        # tile the window held last (PR 30) — K2's read-back rule catches it
+        src = _TOY_FUSED_KERNEL_SRC
+        start = src.index("    @pl.when(moved & (qvf == 0))")
         end = src.index("    contrib = ")
         check_kernel_sources(
             report, src[:start] + src[end:], _TOY_FUSED_CONTRACTS,
@@ -2131,6 +2182,7 @@ def run_seeded_mutations() -> list[dict]:
     run("swapped_index_map_axes", "K3", swapped)
     run("missing_accumulator_init", "K2", no_init)
     run("deleted_revisit_init", "K2", no_revisit_init)
+    run("deleted_revisit_readback", "K2", no_revisit_readback)
     run("bf16_accumulator", "K4", bf16_scratch)
     run("unlisted_env_key", "K5", unlisted_key)
     run("corrupted_extent_row", "K3", bad_extent)

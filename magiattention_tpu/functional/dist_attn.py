@@ -321,21 +321,24 @@ def _stack_plans(args: list[AttnArg], sq: int, sk: int, bq: int, bk: int,
             jnp.asarray(np.stack([getattr(p, f) for p in padded]))
             for f in fields
         )
-        return stacked, plans[0].num_q_tiles, plans[0].num_k_tiles, w, wt
+        # the ranks run one program: the least distance binds them all
+        dist = min(p.min_revisit_distance for p in plans)
+        return (stacked, plans[0].num_q_tiles, plans[0].num_k_tiles, w, wt,
+                dist)
 
     fwd_fields = ("work_qt", "work_kt", "meta", "work_qt_t", "work_kt_t",
                   "meta_t")
-    stacked, nqt, nkt, w, wt = build_stack(bq, bk, fwd_fields)
+    stacked, nqt, nkt, w, wt, dist = build_stack(bq, bk, fwd_fields)
 
     def build_triple(blocks, kind):
         if kind == "dq":
-            triple, _, _, w2, _ = build_stack(*blocks, fwd_fields[0:3])
+            triple, _, _, w2, _, _ = build_stack(*blocks, fwd_fields[0:3])
             return triple, w2
-        triple, _, _, _, wt2 = build_stack(*blocks, fwd_fields[3:6])
-        return triple, wt2
+        triple, _, _, _, wt2, dist2 = build_stack(*blocks, fwd_fields[3:6])
+        return triple, wt2, dist2
 
     stacked, overrides = assemble_bwd_overrides(
-        stacked, bq, bk, nqt, nkt, build_triple,
+        stacked, bq, bk, nqt, nkt, build_triple, dist,
         policy_dq=policy_dq, policy_dkv=policy_dkv,
     )
     return stacked, (nqt, nkt, w, wt, overrides)

@@ -136,6 +136,12 @@ class FFAParams:
     block_k_dkv: int | None = None
     num_work_dq: int | None = None
     num_work_dkv: int | None = None
+    # ffa_plan.min_revisit_distance of the k-major list the one-pass
+    # backward would walk (the dkv override's own list when there is one;
+    # the least over the ranks' lists of a stacked plan). A host integer
+    # beside num_work_t: the plan's arrays may be traced, the mode may read
+    # only statics. 0 = not known, which keeps the backward split.
+    min_revisit_distance: int = 0
 
     def dq_blocks(self) -> tuple[int, int]:
         return (self.block_q_dq or self.block_q,
@@ -1784,6 +1790,29 @@ def ffa_delta_pallas_dispatch(params: FFAParams, out_t, do_t):
 # ---------------------------------------------------------------------------
 
 
+# Grid steps the one-pass bodies need between leaving a q tile's dq window
+# and fetching it again. The pipeline starts step i's write-back when step
+# i ends and waits for it when step i + 1 ends; it issues step j's fetch
+# when step j - 1 starts. At j - i = 3 the fetch is issued after the
+# write-back it has to see was waited for, so the order holds by the
+# pipeline's own wait. At 2 the fetch is queued behind a write-back still
+# in flight: the chip read such blocks whole (62 revisits a kv head at 2,
+# 61 at 3, g = 4 and g = 1, three runs each, against the split pair; TPU
+# v5e, my chip run, PR 30, PERF.md §6), but in a kernel alone on the chip,
+# and nothing but the DMA queue's order stands behind it — not relied on.
+# ffa_plan._late_revisit_order walks the k-major list so that the cells'
+# lists read 4 on one chip and 3 on a chunked rank at cp 4; a list under
+# 3, or one whose distance is not known (0), runs the split pair.
+FUSED_DQ_REVISIT_DISTANCE = 3
+
+
+def _dq_window_moved(work_qt_ref, w):
+    """True when step ``w``'s dq window is another block than step
+    ``w - 1``'s (always at a head's first step): the window was written
+    back and re-fetched, so the output buffer holds none of this tile."""
+    return (w == 0) | (work_qt_ref[w] != work_qt_ref[jnp.maximum(w - 1, 0)])
+
+
 def _bwd_fused_kernel(
     work_qt_ref,
     work_kt_ref,
@@ -1794,7 +1823,7 @@ def _bwd_fused_kernel(
     do_ref,
     lse_ref,
     delta_ref,
-    dqz_ref,
+    dqin_ref,
     dq_ref,
     dk_ref,
     dv_ref,
@@ -1807,23 +1836,35 @@ def _bwd_fused_kernel(
     bk: int,
     group: int,
     nc: int,
+    readback: bool,
 ):
     """Fused one-pass backward: dk, dv AND dq from ONE score recompute.
 
     Same grid and dk/dv discipline as :func:`_bwd_dkv_kernel` (k-major
     plan, grid (hk, WT, g), group innermost, VMEM scratch flushed on the
     k tile's last visit). The fused extra: each work item's dq
-    contribution ``ds @ k`` is accumulated directly into the REVISITED dq
-    output window — the k-major traversal visits one q tile many times,
-    non-consecutively, so there is no scratch run to accumulate in;
-    instead the output block itself is read-modify-written across visits:
-    zero-initialized when the plan's first-q-visit flag (QVF) is set,
-    accumulated every visit, and flushed (folding softmax_scale) on the
-    last-q-visit flag (QVL). Never-visited q tiles (fully masked rows)
-    keep the aliased zero background the wrapper passes as ``dqz_ref``.
-    This shares the s_t/p_t recompute between dq and dk/dv — 5 tile
-    matmuls per work item where the split passes spend 7 — and halves the
-    backward HBM reads of q/k/v/do.
+    contribution ``ds @ k`` is accumulated into the dq output window. The
+    k-major traversal visits one q tile many times, non-consecutively, and
+    the chip writes an output window back when its block index changes and
+    does NOT read it back on a later visit. So the partial sum comes in as
+    an operand: ``dqin_ref`` is the dq array itself (aliased to the
+    output, same index map), fetched whenever the block changes. A run of
+    adjacent visits starts from it (or from zero when the plan's
+    first-q-visit flag QVF is set), accumulates in the resident output
+    window, and the last visit (QVL) folds softmax_scale in before the
+    window is written back. The fetch of a revisit is issued a grid step
+    ahead, so the plan keeps non-adjacent visits FUSED_DQ_REVISIT_DISTANCE
+    steps apart (:func:`fused_bwd_feasible`). Never-visited q tiles (fully
+    masked rows) keep the zero background the wrapper donates. This shares
+    the s_t/p_t recompute between dq and dk/dv — 5 tile matmuls per work
+    item where the split passes spend 7 — and halves the backward HBM
+    reads of q/k/v/do.
+
+    ``readback`` is False only under ``interpret=True``: that interpreter
+    carries the aliased operand and the output as two arrays (the operand
+    stays zero) but keeps the output's contents between visits, so there
+    the window itself is the partial sum. The TPU interpreter
+    (``pltpu.InterpretParams``) and the chip take the operand.
     """
     w = pl.program_id(1)
     gi = pl.program_id(2)
@@ -1834,7 +1875,6 @@ def _bwd_fused_kernel(
     qvl = meta_ref[w, QVL]
     use_exp2 = softcap == 0.0
     exp_fn = jnp.exp2 if use_exp2 else jnp.exp
-    del dqz_ref  # aliased zero background only; never read in-kernel
 
     @pl.when((is_first == 1) & (gi == 0))
     def _():
@@ -1848,6 +1888,16 @@ def _bwd_fused_kernel(
     @pl.when(qvf == 1)
     def _():
         dq_ref[0] = jnp.zeros((bq, d), jnp.float32)
+
+    if readback:
+        # a later visit: the window's block changed on the way here (with
+        # the group innermost every step changes it), so it holds nothing
+        # of this tile — take the partial sum the last visit wrote back
+        moved = _dq_window_moved(work_qt_ref, w) if group == 1 else True
+
+        @pl.when(moved & (qvf == 0))
+        def _():
+            dq_ref[0] = dqin_ref[0]
 
     q = q_ref[0]  # pre-scaled by softmax_scale (* log2e when softcap-free)
     k = k_ref[0]
@@ -1980,10 +2030,11 @@ def _ffa_bwd_fused_pallas(
     """Fused one-pass backward pallas call (see :func:`_bwd_fused_kernel`).
 
     Returns (dq_t, dk_t, dv_t), all fp32. The dq output is aliased to a
-    zero input (``input_output_aliases``) whose CONSTANT index map fetches
-    one window exactly once: q tiles the k-major work list never visits
-    (fully masked rows) keep that zero background, so no dummy work items
-    are needed and the plan's work counts are untouched.
+    zero input (``input_output_aliases``) with the output's own index map:
+    a visit fetches what the tile's last visit wrote back, and q tiles the
+    k-major work list never visits (fully masked rows) keep the donated
+    zero background, so no dummy work items are needed and the plan's work
+    counts are untouched.
     """
     bq, bk = params.dkv_blocks()
     hq, sqp, d = q_t.shape
@@ -2032,10 +2083,11 @@ def _ffa_bwd_fused_pallas(
                 lambda h, w, gi, qt, kt, mt: (h * g + gi, 0, qt[w]),
                 memory_space=pltpu.VMEM,
             ),
-            # aliased zero background for dq: constant index map — the
-            # window is fetched once, never streamed per step, never read
+            # the dq array itself, aliased to the output and indexed like
+            # it: the partial sum of the window's earlier visits
             pl.BlockSpec(
-                (1, bq, d), lambda h, w, gi, qt, kt, mt: (0, 0, 0),
+                (1, bq, d),
+                lambda h, w, gi, qt, kt, mt: (h * g + gi, qt[w], 0),
                 memory_space=pltpu.VMEM,
             ),
         ],
@@ -2062,7 +2114,7 @@ def _ffa_bwd_fused_pallas(
     kernel = partial(
         _bwd_fused_kernel, softcap=params.softcap,
         scale=params.softmax_scale, bq=bq, bk=bk, group=g,
-        nc=_clamp_chunks(bq),
+        nc=_clamp_chunks(bq), readback=params.interpret is not True,
     )
     dq_t, dk_t, dv_t = _named.pallas_call(
         kernel,
@@ -2096,7 +2148,7 @@ def _bwd_fused_kernel_gqa(
     do_ref,
     lse_ref,
     delta_ref,
-    dqz_ref,
+    dqin_ref,
     dq_ref,
     dk_ref,
     dv_ref,
@@ -2109,16 +2161,18 @@ def _bwd_fused_kernel_gqa(
     bk: int,
     g: int,
     clamp: bool,
+    readback: bool,
 ):
     """GQA-packed fused one-pass backward: grid (hk, WT), the whole query
     group of one kv head per step (see :func:`_bwd_dkv_kernel_gqa` for the
     packing scheme). The dq window is the full (g, bq, d) group block of
-    the work item's q tile, revisit-accumulated under the same QVF/QVL
-    discipline as :func:`_bwd_fused_kernel` — one init and one flush per
-    tile visit run covers all g heads at once. Clamping is the whole-item
+    the work item's q tile, read-modify-written under the same QVF/QVL
+    discipline and the same aliased ``dqin_ref`` operand as
+    :func:`_bwd_fused_kernel` — one init, one read-back and one flush per
+    run of visits covers all g heads at once. Clamping is the whole-item
     live guard (the packed lane dim interleaves the g heads' q rows, so
-    it cannot be chunked by a single q extent); init/flush stay OUTSIDE
-    the guard so dead items still honor their visit flags.
+    it cannot be chunked by a single q extent); init, read-back and flush
+    stay OUTSIDE the guard so dead items still honor their visit flags.
     """
     w = pl.program_id(1)
     is_first = meta_ref[w, IS_FIRST]
@@ -2128,7 +2182,6 @@ def _bwd_fused_kernel_gqa(
     qvl = meta_ref[w, QVL]
     use_exp2 = softcap == 0.0
     exp_fn = jnp.exp2 if use_exp2 else jnp.exp
-    del dqz_ref  # aliased zero background only; never read in-kernel
 
     @pl.when(is_first == 1)
     def _():
@@ -2141,6 +2194,13 @@ def _bwd_fused_kernel_gqa(
     @pl.when(qvf == 1)
     def _():
         dq_ref[0] = jnp.zeros((g, bq, d), jnp.float32)
+
+    if readback:
+        moved = _dq_window_moved(work_qt_ref, w)
+
+        @pl.when(moved & (qvf == 0))
+        def _():
+            dq_ref[0] = dqin_ref[0]
 
     q = q_ref[0].reshape(g * bq, d)  # pre-scaled on host
     k = k_ref[0]
@@ -2292,9 +2352,9 @@ def _ffa_bwd_fused_pallas_gqa(
             pl.BlockSpec((None, None, 1, g * bq),
                          lambda h, w, qt, kt, mt: (h, qt[w], 0, 0),
                          memory_space=pltpu.VMEM),
-            # aliased zero background for dq (constant index map)
+            # the dq array itself, aliased to the output, indexed like it
             pl.BlockSpec((1, g, bq, d),
-                         lambda h, w, qt, kt, mt: (0, 0, 0, 0),
+                         lambda h, w, qt, kt, mt: (h, 0, qt[w], 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
@@ -2315,6 +2375,7 @@ def _ffa_bwd_fused_pallas_gqa(
         _bwd_fused_kernel_gqa, softcap=params.softcap,
         scale=params.softmax_scale, bq=bq, bk=bk, g=g,
         clamp=_registry_mod().extent_clamp_enabled(),
+        readback=params.interpret is not True,
     )
     dq_g, dk_t, dv_t = _named.pallas_call(
         kernel,
@@ -2353,13 +2414,21 @@ def _use_gqa_pack_fused(
 def fused_bwd_feasible(
     params: FFAParams, sqp: int, d: int, dv: int, itemsize: int = 2
 ) -> bool:
-    """True when at least one fused-kernel variant's per-step VMEM
-    residency fits the budget — the guard that forces split mode even
-    under a 'fused' pin."""
+    """True when a fused-kernel variant can run this plan: its per-step
+    VMEM residency fits the budget, and the k-major list leaves a q tile's
+    dq window FUSED_DQ_REVISIT_DISTANCE grid steps before it comes back
+    (the read-modify-write of :func:`_bwd_fused_kernel`). The guard that
+    forces split mode even under a 'fused' pin."""
+    dist = params.min_revisit_distance
     if _use_gqa_pack_fused(params, sqp, d, dv, itemsize):
-        return True
+        return dist >= FUSED_DQ_REVISIT_DISTANCE
+    if params.group > 1:
+        # the unpacked body walks the group innermost: a head's window
+        # comes back every g steps, inside a run of one q tile too (a
+        # group of 2 is under the distance and runs the pair)
+        dist = min(dist, params.group)
     bq, bk = params.dkv_blocks()
-    return (
+    return dist >= FUSED_DQ_REVISIT_DISTANCE and (
         ffa_kernel_residency(
             "fused", bq, bk, d, head_dim_v=dv, dtype_bytes=itemsize,
             group=params.group, packed=False,
@@ -2393,34 +2462,32 @@ def ffa_bwd_mode(
     contents, which may be traced arrays under shard_map).
 
     In order: a 'split' pin (MAGI_ATTENTION_BACKEND_FFA_BWD); the
-    feasibility guards (plan meta layout, fused VMEM residency), either of
-    which forces 'split'; a 'fused' pin; else tile_policy.choose_bwd_mode
-    over the static shapes, memoized per key by the backend registry
-    (kernels/registry.py).
+    feasibility guards (plan meta layout, fused VMEM residency, the plan's
+    revisit distance), any of which forces 'split'; a 'fused' pin; else
+    tile_policy.choose_bwd_mode over the static shapes, memoized per key
+    by the backend registry (kernels/registry.py), which records every
+    outcome (``registry.last_choice("ffa_bwd")``).
     """
     from ..env import backend as env_backend
     from . import registry as _registry
-
-    pin = env_backend.ffa_bwd_pin()
-    if pin == "split":
-        return "split"
-    if meta_cols <= QVL:
-        # plan meta predates the QVF/QVL visit-flag columns (hand-built
-        # 13-col metas in older tests): the fused kernel cannot run
-        return "split"
-    if not fused_bwd_feasible(params, sqp, d, dv, itemsize):
-        return "split"
-    if pin == "fused":
-        return "fused"
     from .tile_policy import choose_bwd_mode
 
+    pin = env_backend.ffa_bwd_pin()
     key = bwd_mode_key(params, d, dv, itemsize)
+    # plan meta that predates the QVF/QVL visit-flag columns (hand-built
+    # 13-col metas in older tests) cannot drive the fused kernel
+    if pin != "split" and (
+        meta_cols <= QVL
+        or not fused_bwd_feasible(params, sqp, d, dv, itemsize)
+    ):
+        return _registry.note_choice("ffa_bwd", key, "split", "guard").name
     return _registry.resolve(
         "ffa_bwd",
         key,
         lambda: choose_bwd_mode(
             *key[:7], dv, itemsize=itemsize, group=params.group
         ),
+        pin=pin,
     ).name
 
 
@@ -2578,7 +2645,8 @@ PALLAS_CONTRACTS: dict[str, dict] = {
     # discipline; dq is a REVISIT-accumulated output — no scratch run
     # exists, the output window itself is zero-initialized under the
     # first-q-visit guard and scale-flushed under the last-q-visit guard
-    # (K2's revisit rule). ``revisit`` names that output and its guards.
+    # (K2's revisit rule). ``revisit`` names that output, its guards, and
+    # the aliased operand a later visit reads its partial sum back from.
     "_bwd_fused_kernel": dict(
         wrapper="_ffa_bwd_fused_pallas",
         scratch=("dk_scr", "dv_scr"),
@@ -2587,7 +2655,8 @@ PALLAS_CONTRACTS: dict[str, dict] = {
         init_guard="is_first",
         flush_guard="is_last",
         group_inner=dict(var="gi", count="group"),
-        revisit=dict(out="dq_ref", init_guard="qvf", flush_guard="qvl"),
+        revisit=dict(out="dq_ref", init_guard="qvf", flush_guard="qvl",
+                     readback="dqin_ref"),
     ),
     "_bwd_fused_kernel_gqa": dict(
         wrapper="_ffa_bwd_fused_pallas_gqa",
@@ -2597,7 +2666,8 @@ PALLAS_CONTRACTS: dict[str, dict] = {
         init_guard="is_first",
         flush_guard="is_last",
         group_inner=None,
-        revisit=dict(out="dq_ref", init_guard="qvf", flush_guard="qvl"),
+        revisit=dict(out="dq_ref", init_guard="qvf", flush_guard="qvl",
+                     readback="dqin_ref"),
     ),
     # Delta preprocessing: stateless map kernel — every grid step writes
     # its own block once, so there is no accumulator discipline to prove.
@@ -2788,7 +2858,7 @@ def resolve_bwd_overrides(
 
 def assemble_bwd_overrides(
     arrays: tuple, bq: int, bk: int, num_q_tiles: int, num_k_tiles: int,
-    build_triple,
+    build_triple, min_revisit_distance: int,
     policy_dq: tuple[int, int] | None = None,
     policy_dkv: tuple[int, int] | None = None,
 ) -> tuple[tuple, dict]:
@@ -2797,18 +2867,21 @@ def assemble_bwd_overrides(
 
     Args:
         arrays: the 6 fwd plan arrays (possibly rank-stacked).
-        build_triple: ``(blocks, kind) -> (triple, work_count)`` — kind
-            "dq" returns a q-major triple + its num_work cap; "dkv" a
-            k-major triple + its num_work_t cap.
+        build_triple: ``(blocks, kind)`` — kind "dq" returns a q-major
+            triple + its num_work cap; "dkv" a k-major triple + its
+            num_work_t cap + its list's min_revisit_distance.
+        min_revisit_distance: of ``arrays``' own k-major list (the least
+            over the ranks of a stack); a dkv override's list replaces it.
 
-    Returns ``(arrays, FFAParams-field overrides)`` — arrays extended to 12
-    when an override is active.
+    Returns ``(arrays, FFAParams fields)`` — arrays extended to 12 when an
+    override is active; the fields always carry ``min_revisit_distance``
+    of the list the one-pass backward would walk.
     """
     dq_blocks, dkv_blocks = resolve_bwd_overrides(
         bq, bk, num_q_tiles * bq, num_k_tiles * bk,
         policy_dq=policy_dq, policy_dkv=policy_dkv,
     )
-    overrides: dict = {}
+    overrides: dict = {"min_revisit_distance": min_revisit_distance}
     if not (dq_blocks or dkv_blocks):
         return tuple(arrays), overrides
     dq_triple = tuple(arrays[0:3])
@@ -2820,10 +2893,10 @@ def assemble_bwd_overrides(
             num_work_dq=w_dq,
         )
     if dkv_blocks:
-        dkv_triple, wt_dkv = build_triple(dkv_blocks, "dkv")
+        dkv_triple, wt_dkv, dist = build_triple(dkv_blocks, "dkv")
         overrides.update(
             block_q_dkv=dkv_blocks[0], block_k_dkv=dkv_blocks[1],
-            num_work_dkv=wt_dkv,
+            num_work_dkv=wt_dkv, min_revisit_distance=dist,
         )
     return tuple(arrays) + tuple(dq_triple) + tuple(dkv_triple), overrides
 
@@ -2840,10 +2913,13 @@ def apply_bwd_overrides(
         p = get_ffa_plan(qr, kr, d_lo, d_hi, sq, sk, *blocks)
         if kind == "dq":
             return plan_arrays(p)[0:3], p.num_work
-        return plan_arrays(p)[3:6], p.num_work_t
+        return plan_arrays(p)[3:6], p.num_work_t, p.min_revisit_distance
 
     return assemble_bwd_overrides(
         arrays, bq, bk, num_q_tiles, num_k_tiles, build_triple,
+        # the plan ``arrays`` came from: a cache hit
+        get_ffa_plan(
+            qr, kr, d_lo, d_hi, sq, sk, bq, bk).min_revisit_distance,
         policy_dq=policy_dq, policy_dkv=policy_dkv,
     )
 
@@ -3033,6 +3109,7 @@ def _mixed_params(
         softcap=softcap,
         group=group,
         interpret=_should_interpret(),
+        min_revisit_distance=plan.min_revisit_distance,
     )
 
 

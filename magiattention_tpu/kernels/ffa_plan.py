@@ -16,7 +16,7 @@ see kernels/mask_utils.types_to_bands.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import lru_cache
+from functools import cached_property, lru_cache
 
 import numpy as np
 
@@ -69,6 +69,47 @@ class FFAPlan:
     @property
     def num_work_t(self) -> int:
         return len(self.work_qt_t)
+
+    @cached_property
+    def min_revisit_distance(self) -> int:
+        """:func:`min_revisit_distance` of the k-major list — what the
+        one-pass backward's dq read-modify-write has to clear."""
+        return min_revisit_distance(self.work_qt_t)
+
+
+# min_revisit_distance of a list on which no q tile is ever left and visited
+# again: any distance a pipeline could ask for is met
+NO_REVISIT = 1 << 30
+
+
+def min_revisit_distance(work_qt: np.ndarray) -> int:
+    """Fewest grid steps between two NON-adjacent visits of one q tile.
+
+    Adjacent items of one q tile keep the dq window resident (its block
+    index does not change, nothing is written back or fetched) and do not
+    count. Once the walk moves off a q tile, the window is written back
+    after the run's last step ``i``; a later visit at step ``j`` fetches it
+    again, and the fetch is issued a step ahead — the distance ``j - i`` is
+    what must cover the pipeline's write-back (kernels/ffa.py
+    ``FUSED_DQ_REVISIT_DISTANCE``). Filler rows of ``pad_plan`` repeat the
+    last real item's tile, so they only lengthen its run. Returns
+    ``NO_REVISIT`` when no q tile is visited twice.
+    """
+    w = np.asarray(work_qt).astype(np.int64).ravel()
+    if len(w) < 3:
+        return NO_REVISIT
+    idx = np.arange(len(w))
+    run_start = np.concatenate([[True], w[1:] != w[:-1]])
+    run_end = np.concatenate([w[1:] != w[:-1], [True]])
+    starts, ends = idx[run_start], idx[run_end]
+    tiles = w[run_start]
+    # runs of one tile, in list order: stable sort by tile keeps them so
+    order = np.argsort(tiles, kind="stable")
+    t, s, e = tiles[order], starts[order], ends[order]
+    same = t[1:] == t[:-1]
+    if not same.any():
+        return NO_REVISIT
+    return int((s[1:] - e[:-1])[same].min())
 
 
 def _extend_meta_extents(
@@ -151,6 +192,61 @@ def _extend_meta_visits(meta13: np.ndarray, work_qt: np.ndarray) -> np.ndarray:
     ).astype(np.int32)
 
 
+def _late_revisit_order(
+    work_qt_t: np.ndarray, work_kt_t: np.ndarray, meta9_t: np.ndarray
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The k-major list with every k tile's run walked in the direction
+    that brings a q tile of an earlier run back as late as it can. dk and
+    dv sum over a run in any order; the one-pass backward's dq does care:
+    its window leaves VMEM when the walk moves off a q tile and is fetched
+    again at that tile's item in a later run (:func:`min_revisit_distance`,
+    kernels/ffa.py ``FUSED_DQ_REVISIT_DISTANCE``).
+
+    Each run, its items sorted by q tile, is walked from its last q tile
+    down unless walking it up keeps every returning tile further away. A
+    band's next k tile starts at or above this one's q tiles, so walking
+    down puts a run's length (causal) or its length + 2 (a window) between
+    the two visits, where walking up gives its length - 2: 4 grid steps,
+    in place of 2, at the end of every causal document (256 x 512 tiles).
+    Walking up wins where a short run sits between two longer ones over
+    the same q tiles (a chunked rank at cp > 1: d c b a | d c | a b c d
+    reads 3 where d c b a | d c | d c b a reads 2); the tile the walk is
+    standing on stays resident and counts as far away."""
+    n = len(work_kt_t)
+    if n < 2:
+        return work_qt_t, work_kt_t, meta9_t
+    brk = np.flatnonzero(work_kt_t[1:] != work_kt_t[:-1]) + 1
+    starts = np.concatenate([[0], brk])
+    ends = np.concatenate([brk, [n]])
+    # a run's items by q tile, so that two slices' items on one (q, k)
+    # tile pair are neighbours and the second finds the window resident
+    run = np.repeat(np.arange(len(starts)), ends - starts)
+    perm = np.lexsort((work_qt_t, run))
+    # the step a q tile was last visited at; far in the past when never
+    last = np.full(int(work_qt_t.max()) + 1, -NO_REVISIT, dtype=np.int64)
+
+    def nearest(tiles: np.ndarray, steps: np.ndarray) -> int:
+        gap = steps - last[tiles]
+        # resident: the head on the tile the walk stands on (a gap of 1),
+        # and an item on its neighbour's tile
+        gap[0] = NO_REVISIT if gap[0] == 1 else gap[0]
+        gap[1:][tiles[1:] == tiles[:-1]] = NO_REVISIT
+        return gap.min()
+
+    for s, e in zip(starts.tolist(), ends.tolist()):
+        steps = np.arange(s, e)
+        up = perm[s:e].copy()
+        down = up[::-1]
+        if nearest(work_qt_t[down], steps) >= nearest(work_qt_t[up], steps):
+            perm[s:e] = up = down
+        last[work_qt_t[up]] = steps
+    meta = meta9_t[perm]
+    meta[:, [IS_FIRST, IS_LAST]] = 0
+    meta[starts, IS_FIRST] = 1
+    meta[ends - 1, IS_LAST] = 1
+    return work_qt_t[perm], work_kt_t[perm], meta
+
+
 def plan_extent_stats(plan: FFAPlan) -> dict:
     """Executed-vs-padded element accounting from the extent columns.
 
@@ -228,6 +324,7 @@ def _record_plan_telemetry(
             num_k_tiles=plan.num_k_tiles,
             num_work=plan.num_work,
             num_work_t=plan.num_work_t,
+            min_revisit_distance=plan.min_revisit_distance,
             padded_elems=padded,
             band_elems=band,
             executed_elems=executed,
@@ -296,8 +393,9 @@ def build_ffa_plan(
                 num_q_tiles, num_k_tiles, block_q, block_k, BAND_INF,
             )
             # the C fill writes 9-col rows (fixed stride, csrc/magi_host.cpp);
-            # the extent and q-visit columns are appended here so native
-            # and Python plans stay bit-identical
+            # the k-major order, the extent and the q-visit columns are
+            # made here so native and Python plans stay bit-identical
+            arrays = (*arrays[:3], *_late_revisit_order(*arrays[3:6]))
             return _record_plan_telemetry(
                 FFAPlan(
                     work_qt=arrays[0], work_kt=arrays[1],
@@ -393,6 +491,8 @@ def build_ffa_plan(
         work_a = np.asarray(work_a, dtype=np.int32)
         work_b = np.asarray(work_b, dtype=np.int32)
         meta9 = np.stack(metas).astype(np.int32)
+        if not major_is_q:
+            work_a, work_b, meta9 = _late_revisit_order(work_a, work_b, meta9)
         return (
             work_a,
             work_b,

@@ -650,11 +650,13 @@ def _mixed_dispatch_key(
 #
 # Per work item the split backward spends 7 tile matmuls (dq pass: s, dp,
 # dq; dkv pass: s_t, dp_t, dk, dv) where the fused kernel spends 5 (s_t,
-# dp_t, dk, dv, dq) — the FlashAttention-2 work-partitioning count — and
-# the fused pass streams q/k/v/do from HBM once instead of twice, at the
-# price of a per-step fp32 read-modify-write of the revisited dq window.
-# The chooser models both terms from the STATIC plan counts (work items,
-# blocks, dims) so the decision is trace-time stable.
+# dp_t, dk, dv, dq) — the FlashAttention-2 work-partitioning count — at
+# the price of moving the dq window in and out every k-major step. On the
+# chip a grid step costs a fixed part plus the LARGER of its matmuls and
+# its DMA (the pipeline moves the next step's blocks under this step's
+# matmuls), so the chooser compares the two modes' grid steps in
+# microseconds, from the STATIC plan counts (work items, blocks, dims,
+# group): the decision is trace-time stable.
 # ---------------------------------------------------------------------------
 
 # tile matmuls per work item (asserted 7 -> 5 by unit test)
@@ -662,65 +664,68 @@ BWD_TILE_MATMULS_SPLIT_DQ = 3  # s, dp, dq
 BWD_TILE_MATMULS_SPLIT_DKV = 4  # s_t, dp_t, dk, dv
 BWD_TILE_MATMULS_SPLIT = BWD_TILE_MATMULS_SPLIT_DQ + BWD_TILE_MATMULS_SPLIT_DKV
 BWD_TILE_MATMULS_FUSED = 5  # s_t, dp_t, dk, dv, dq
-# MXU MAC-elements per HBM byte at which compute and memory time balance
-# (~v5e: 197 TF/s bf16 against 819 GB/s ≈ 240); converts the HBM term into
-# the same element units the MXU term is counted in
-BWD_MXU_ELEMS_PER_HBM_BYTE = 240
+# Readings of a TPU v5e, bf16, d 128, GQA-packed steps of 1024 rows x 512
+# keys (one tile matmul of 256 x 512 x 128 is 16.8 MMAC):
+# a grid step's fixed part — the dq body's 0.35 + 0.82 us per 256 rows
+# (PERF.md §6; my chip runs, PR 25)
+BWD_STEP_FIXED_US = 0.35
+# the q-major dq body: 0.82 us per 3 tile matmuls (same reading)
+BWD_QMAJOR_US_PER_GMAC = 16.3
+# the k-major bodies: dkv 3.60 us per 16 tile matmuls (PR 25), the
+# one-pass body 4.49 us per 20 (37.96 ms a layer over 8 x 1056 steps,
+# nemo12b.longdoc.cp1; my chip run, PR 30) — 82% of the MXU's 98.5 TMAC/s
+BWD_KMAJOR_US_PER_GMAC = 12.3
+# HBM at its published 819 GB/s (cellbench/peaks.py names the source)
+HBM_US_PER_MB = 1.0 / 0.819
 
 
-def bwd_mxu_elems(
-    mode: str,
-    w_dq: int,
-    bq_dq: int,
-    bk_dq: int,
-    wt: int,
-    bq_dkv: int,
-    bk_dkv: int,
-    d: int,
-) -> int:
-    """MXU MAC-element count of one backward under ``mode`` ("split" |
-    "fused"): tile matmuls per work item x the item's (bq, bk, d) MAC
-    volume. Under equal blocks and equal work counts the split/fused
-    ratio is exactly 7/5 — the fusion's recompute saving."""
-    if mode == "split":
-        return (
-            BWD_TILE_MATMULS_SPLIT_DQ * w_dq * bq_dq * bk_dq * d
-            + BWD_TILE_MATMULS_SPLIT_DKV * wt * bq_dkv * bk_dkv * d
-        )
-    return BWD_TILE_MATMULS_FUSED * wt * bq_dkv * bk_dkv * d
+# a backward body's tile matmuls a work item, and what a GMAC costs it
+_BWD_BODIES = {
+    "dq": (BWD_TILE_MATMULS_SPLIT_DQ, BWD_QMAJOR_US_PER_GMAC),
+    "dkv": (BWD_TILE_MATMULS_SPLIT_DKV, BWD_KMAJOR_US_PER_GMAC),
+    "fused": (BWD_TILE_MATMULS_FUSED, BWD_KMAJOR_US_PER_GMAC),
+}
 
 
-def bwd_hbm_bytes(
-    mode: str,
-    w_dq: int,
-    bq_dq: int,
-    bk_dq: int,
-    wt: int,
-    bq_dkv: int,
-    bk_dkv: int,
-    d: int,
-    dv: int,
-    itemsize: int = 2,
+def bwd_step_macs(kind: str, bq: int, bk: int, d: int, group: int = 1) -> int:
+    """MXU MACs of one work item of a backward body ("dq" | "dkv" |
+    "fused"): its tile matmuls x the (g x bq, bk, d) volume — a work item
+    covers the whole query group, in one packed step or in g plain ones."""
+    return _BWD_BODIES[kind][0] * group * bq * bk * d
+
+
+def bwd_step_bytes(
+    kind: str, bq: int, bk: int, d: int, dv: int, itemsize: int = 2,
     group: int = 1,
 ) -> int:
-    """Modeled HBM bytes streamed by one backward under ``mode``: per grid
-    item, the operand blocks fetched plus the output blocks written. The
-    fused mode drops the dq pass's whole stream but adds the revisited dq
-    window's fp32 read-modify-write every step."""
-    g = group
-    dq_stream = (
-        (bq_dq * d + bk_dq * d + bk_dq * dv + bq_dq * dv) * itemsize
-        + bq_dq * d * 4  # fp32 dq out
-    )
-    dkv_stream = (
-        (g * bq_dkv * d + bk_dkv * d + bk_dkv * dv + g * bq_dkv * dv)
-        * itemsize
-        + (bk_dkv * d + bk_dkv * dv) * 4  # fp32 dk/dv outs
-    )
-    if mode == "split":
-        return w_dq * dq_stream + wt * dkv_stream
-    # fused: one pass, plus 2x the fp32 dq window (read + write) per step
-    return wt * (dkv_stream + 2 * g * bq_dkv * d * 4)
+    """HBM bytes ONE grid step of a backward body moves — the blocks whose
+    index changes with the step; what stays for a run (the q-major body's
+    q, dO and dq, the k-major bodies' k, v, dk and dv) is not counted.
+    ``kind``: "dq" (k and v stream past the resident q rows), "dkv" (q, dO,
+    lse and delta stream past the resident k tile), "fused" (dkv's, plus
+    the fp32 dq window written back and fetched again)."""
+    rows = group * bq
+    if kind == "dq":
+        return bk * (d + dv) * itemsize
+    streamed = rows * (d + dv) * itemsize + 2 * rows * 4
+    if kind == "dkv":
+        return streamed
+    return streamed + 2 * rows * d * 4
+
+
+def bwd_step_us(
+    kind: str, bq: int, bk: int, d: int, dv: int, itemsize: int = 2,
+    group: int = 1,
+) -> float:
+    """Modeled microseconds of one grid step of a backward body on the
+    chip: the fixed part plus the larger of its matmuls' time and its
+    DMA's (the step's blocks move under the neighbouring step's
+    matmuls)."""
+    mxu = bwd_step_macs(kind, bq, bk, d, group) * (
+        _BWD_BODIES[kind][1] * 1e-9)
+    dma = bwd_step_bytes(kind, bq, bk, d, dv, itemsize, group) * (
+        HBM_US_PER_MB * 1e-6)
+    return BWD_STEP_FIXED_US + max(mxu, dma)
 
 
 def choose_bwd_mode(
@@ -735,21 +740,20 @@ def choose_bwd_mode(
     itemsize: int = 2,
     group: int = 1,
 ) -> str:
-    """"fused" or "split" by modeled cost (MXU elems + balanced HBM term).
+    """"fused" or "split" by the modeled time of the two modes' grid steps
+    (:func:`bwd_step_us`), a kv head.
 
-    Fused wins whenever the two plans are comparably sized (the common
-    case: 5/7 the recompute and half the operand streams); split wins when
-    the q-major dq plan is much cheaper than the k-major plan — e.g. a
-    mask whose k-major tiling fragments far worse than its q-major one,
-    where rerunning the cheap dq pass beats dragging dq through every
-    k-major step's fp32 window RMW. Feasibility (VMEM, plan meta columns)
-    is the caller's job (kernels/ffa.ffa_bwd_mode)."""
-    args = (w_dq, bq_dq, bk_dq, wt, bq_dkv, bk_dkv, d)
-    hbm = (dv, itemsize, group)
-    split_cost = bwd_mxu_elems("split", *args) + (
-        BWD_MXU_ELEMS_PER_HBM_BYTE * bwd_hbm_bytes("split", *args, *hbm)
-    )
-    fused_cost = bwd_mxu_elems("fused", *args) + (
-        BWD_MXU_ELEMS_PER_HBM_BYTE * bwd_hbm_bytes("fused", *args, *hbm)
-    )
-    return "fused" if fused_cost <= split_cost else "split"
+    Fused wins wherever the two lists are comparably sized and a step is
+    bound by its matmuls — every cell of the benchmark, by 31 to 39% of
+    the pair's time on the chip (PERF.md §6, PR 30). Split wins when the
+    dq window's traffic binds the one-pass step (thin k tiles under many
+    packed rows, wide fp32 heads), or when the q-major dq plan is much
+    cheaper than the k-major plan — a mask whose k-major tiling fragments
+    far worse than its q-major one. Feasibility (VMEM, plan meta columns,
+    the plan's revisit distance) is the caller's job
+    (kernels/ffa.ffa_bwd_mode)."""
+    hbm = (d, dv, itemsize, group)
+    split_us = w_dq * bwd_step_us("dq", bq_dq, bk_dq, *hbm) + (
+        wt * bwd_step_us("dkv", bq_dkv, bk_dkv, *hbm))
+    fused_us = wt * bwd_step_us("fused", bq_dkv, bk_dkv, *hbm)
+    return "fused" if fused_us <= split_us else "split"

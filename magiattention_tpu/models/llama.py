@@ -13,7 +13,7 @@ global position ids. Parameters are ZeRO-3-style sharded over the cp axis
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
+from functools import cached_property, partial
 
 import jax
 import jax.numpy as jnp
@@ -221,7 +221,56 @@ def loss_fn(
     return masked_ce(logits, labels_d)
 
 
-@partial(jax.jit, static_argnums=(1, 4), donate_argnums=(0,))
+# XLA's default memory scheduler orders a program three ways (list, DFS,
+# post-order) and keeps the one whose ESTIMATED peak is least. For the step
+# below it keeps the list order wherever its attention backward is the split
+# pair; with the one-pass call it keeps, at 32768 tokens a chip, a DFS order
+# that runs each layer's three MLP weight-gradient fusions AFTER the layer's
+# attention backward, so three [tokens, ffn] buffers live through it: temp
+# 5.57 -> 7.32 GiB compiled for a v5e, peak HBM 9.02 -> 10.77 GiB on the chip
+# (mistral-7b widths; my chip run, PR 30, PERF.md §6). The list order of the
+# same program reads 5.57 GiB. At 16384 tokens a chip the default already is
+# the list order: the compiled step is the same text with and without this
+# (tests/test_models/test_step_schedule.py compiles both for a v5e). Only
+# the TPU compiler knows the option.
+TPU_STEP_COMPILER_OPTIONS = {"xla_memory_scheduler": "list"}
+
+
+class _StepJit:
+    """``jax.jit(fn, **jit_kw)`` built on first use, with
+    ``TPU_STEP_COMPILER_OPTIONS`` where the backend is a TPU: which backend
+    runs is not known when this module is imported, and asking then would
+    start it. Compiler options are the outermost jit's alone, and JAX
+    refuses them on an inner one: called under another trace
+    (``jax.make_jaxpr``, a caller's own jit) the step is the plain jit."""
+
+    def __init__(self, fn, **jit_kw):
+        self._fn, self._jit_kw = fn, jit_kw
+        self.__doc__, self.__name__ = fn.__doc__, fn.__name__
+
+    @cached_property
+    def _inner(self):
+        return jax.jit(self._fn, **self._jit_kw)
+
+    @cached_property
+    def _jitted(self):
+        if jax.default_backend() != "tpu":
+            return self._inner
+        return jax.jit(
+            self._fn, **self._jit_kw,
+            compiler_options=TPU_STEP_COMPILER_OPTIONS,
+        )
+
+    def __call__(self, *args, **kwargs):
+        traced = any(isinstance(x, jax.core.Tracer)
+                     for x in jax.tree.leaves((args, kwargs)))
+        return (self._inner if traced else self._jitted)(*args, **kwargs)
+
+    def __getattr__(self, name):  # lower, trace, eval_shape, clear_cache
+        return getattr(self._jitted, name)
+
+
+@partial(_StepJit, static_argnums=(1, 4), donate_argnums=(0,))
 def train_step(
     params: dict,
     cfg: LlamaConfig,

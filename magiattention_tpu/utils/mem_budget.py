@@ -128,14 +128,15 @@ def ffa_kernel_residency(
         scratch = (bk * d + bk * dv) * f32
         inter = 2 * g * bq * bk * f32  # s_t + dp_t
     elif kind == "fused":
-        # one-pass backward: the dkv residency PLUS the revisited dq
-        # output window and its aliased zero-background input block (both
-        # fp32, both declared BlockSpecs so both pipeline-double-buffered)
+        # one-pass backward: the dkv residency PLUS the dq output window
+        # and the aliased dq operand a later visit reads its partial sum
+        # back from (same block, same index map; both fp32, both declared
+        # BlockSpecs so both pipeline-double-buffered)
         blocks = q_in + k_in + v_in
         blocks += g * bq * dv * dtype_bytes  # do
         blocks += 2 * (g * bq if packed else 8 * bq) * f32  # lse + delta
         blocks += (bk * d + bk * dv) * f32  # dk + dv outs (fp32)
-        blocks += 2 * g * bq * d * f32  # dq out + aliased dqz in (fp32)
+        blocks += 2 * g * bq * d * f32  # dq out + aliased dq in (fp32)
         scratch = (bk * d + bk * dv) * f32
         inter = 2 * g * bq * bk * f32  # s_t + dp_t
     elif kind == "delta":

@@ -99,6 +99,7 @@ def ffa_cases() -> dict:
         num_q_tiles=plan.num_q_tiles, num_k_tiles=plan.num_k_tiles,
         block_q=bq, block_k=bk, softmax_scale=float(D) ** -0.5, softcap=0.0,
         group=HQ // HK, interpret=ffa._should_interpret(),
+        min_revisit_distance=plan.min_revisit_distance,
     )
     hm = lambda x: x.transpose(1, 0, 2)  # noqa: E731  (S, h, d) -> (h, S, d)
     q_t, k_t, v_t, do_t, out_t = map(hm, (q, k, v, do, ro))

@@ -87,6 +87,23 @@ def test_revisit_overwrite_outside_guards_fires_k2():
     )
 
 
+@pytest.mark.parametrize("mutation,said", [
+    # the copy-in of the aliased operand taken out: what the chip ran before
+    # PR 30 (a later visit accumulates on whatever tile the window held)
+    (("        dq_ref[0] = dqin_ref[0]", "        pass"), "read back"),
+    # read back on every visit, the first too: the first-visit zero is lost
+    (("    @pl.when(moved & (qvf == 0))", "    @pl.when(moved)"),
+     "read back"),
+])
+def test_revisit_readback_is_required_by_k2(mutation, said):
+    src = _TOY_FUSED_KERNEL_SRC.replace(*mutation)
+    assert src != _TOY_FUSED_KERNEL_SRC
+    report = VerifyReport()
+    check_kernel_sources(report, src, _TOY_FUSED_CONTRACTS, "toy.py")
+    assert report.fired_rules() == {"K2"}
+    assert any(said in v.detail for v in report.violations)
+
+
 # -- K5 on the real repo ----------------------------------------------------
 
 
@@ -107,13 +124,14 @@ def test_k5_allowlist_entries_carry_a_proof():
 
 def test_seeded_mutations_fire_exactly_their_rule():
     results = run_seeded_mutations()
-    assert len(results) == 10
+    assert len(results) == 11
     assert {r["expected_rule"] for r in results} == {
         "K1", "K2", "K3", "K4", "K5"
     }
     assert {r["mutation"] for r in results} >= {
-        "corrupted_extent_row", "deleted_revisit_init", "oob_page_table",
-        "oob_block_table", "misrouted_scale_prefetch",
+        "corrupted_extent_row", "deleted_revisit_init",
+        "deleted_revisit_readback", "oob_page_table", "oob_block_table",
+        "misrouted_scale_prefetch",
     }
     for r in results:
         assert r["ok"], (

@@ -52,7 +52,9 @@ VARIANTS = [
       "_bwd_dkv_kernel_gqa"}),
     ("fused_gqa_packed", 2, {"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused"},
      {"_fwd_kernel_gqa", "_delta_kernel", "_bwd_fused_kernel_gqa"}),
-    ("fused_plain", 2,
+    # the plain one-pass body walks the group innermost: a head's dq window
+    # comes back every g steps, and a group of 2 is under the distance
+    ("fused_plain", 4,
      {"MAGI_ATTENTION_BACKEND_FFA_BWD": "fused",
       "MAGI_ATTENTION_FFA_GQA_PACK_DKV": "0", **PLAIN_Q_MAJOR},
      {"_fwd_kernel", "_delta_kernel", "_bwd_fused_kernel"}),
@@ -198,7 +200,8 @@ def test_no_pallas_call_site_passes_a_name():
 def test_the_benchmarks_kernel_report_is_unchanged(bare_env):
     """``cellbench.family_llama.pallas_kernels`` of a toy ``train_step``:
     the set the cells report, name for name — the packed bodies since
-    PR 25, where PR 22's and PR 24's runs had the plain fwd and dq."""
+    PR 25, where PR 22's and PR 24's runs had the plain fwd and dq, and
+    the one-pass backward since PR 30, where the split pair ran."""
     from jax.sharding import Mesh
 
     from cellbench import family_llama as family
@@ -216,7 +219,7 @@ def test_the_benchmarks_kernel_report_is_unchanged(bare_env):
     )(params, tokens, tokens)
     assert family.pallas_kernels(jaxpr) == {
         "_fwd_kernel_gqa": True, "_delta_kernel": True,
-        "_bwd_dq_kernel_gqa": True, "_bwd_dkv_kernel_gqa": True,
+        "_bwd_fused_kernel_gqa": True,
     }
     stacks = {
         eqn.params["jaxpr"].debug_info.func_name:

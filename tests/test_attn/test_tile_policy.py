@@ -198,8 +198,11 @@ def test_cp_runtime_honors_the_tile_choice(monkeypatch, chooser):
         assert rt._bq % 16 == 0 and rt._bk % 128 == 0
         return
     overrides = rt._merged_dims[4]
+    # every plan group carries its list's revisit distance beside the tiles
+    assert overrides["min_revisit_distance"] >= 2
     if chooser == "default_g4":
-        assert not overrides and len(rt._merged_arrays) == 6
+        assert set(overrides) == {"min_revisit_distance"}
+        assert len(rt._merged_arrays) == 6
         want, source = "fwd256x512g4 dq256x512g4 dkv256x512g4", "default"
     else:
         # the 12 arrays are fwd6 + dq3 + dkv3 with only dkv on its own tile
@@ -442,3 +445,76 @@ def test_tiles_and_bodies_per_pass(monkeypatch, case):
              if kind == "ffa_plan"}
     assert {(int(bq), int(bk))
             for bq, bk in re.findall(r"(\d+)x(\d+)", got)} == plans
+
+
+# -- the backward mode, from the static shapes alone -------------------------
+
+# (W of the q-major list, W of the k-major list, group): what the four cells
+# and PR 27's g = 1 probe resolve (tests/test_support/test_registry.py has
+# the whole keys), and the mode the chip measured best there — the one-pass
+# backward, by 31 to 39% of the pair's time (my chip runs, PR 30: fwd + bwd
+# of a layer 88.6 -> 64.2 ms longdoc, 32.4 -> 25.5 packed, 99.2 -> 74.8 on
+# the window, 30.9 -> 22.8 at g = 1 and 8 kv heads)
+BWD_CELL_SHAPES = {
+    "nemo12b.longdoc.cp1": (1056, 1056, 4),
+    "nemo12b.longdoc.cp4": (1040, 1043, 4),
+    "mistral7b.swa32k.cp1": (1096, 1096, 4),
+    "nemo12b.packed.cp1": (315, 315, 4),
+    "probe.g1": (1056, 1056, 1),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(BWD_CELL_SHAPES))
+def test_bwd_rule_returns_what_the_chip_measured_best(cell):
+    from magiattention_tpu.kernels.tile_policy import (
+        bwd_step_us, choose_bwd_mode,
+    )
+
+    w, wt, g = BWD_CELL_SHAPES[cell]
+    assert choose_bwd_mode(
+        w, 256, 512, wt, 256, 512, 128, 128, itemsize=2, group=g) == "fused"
+    # and by the margin the chip read, not by a hair: the pair's two steps
+    # against the one-pass step, 7.3 v. 4.5 us at g = 4
+    pair = bwd_step_us("dq", 256, 512, 128, 128, 2, g) + bwd_step_us(
+        "dkv", 256, 512, 128, 128, 2, g)
+    one = bwd_step_us("fused", 256, 512, 128, 128, 2, g)
+    assert 0.55 < one / pair < 0.70
+
+
+# q rows a chip (the padded q length ``ffa_bwd_mode`` is given)
+BWD_CELL_ROWS = {"mistral7b.swa32k.cp1": 32768, "nemo12b.longdoc.cp4": 8192}
+
+
+@pytest.mark.parametrize("cell", sorted(BWD_CELL_SHAPES))
+def test_bwd_mode_of_a_cell_follows_its_plans_revisit_distance(
+    cell, monkeypatch
+):
+    """Through ``ffa_bwd_mode``: the cells' lists clear the distance the
+    pipeline needs and resolve to fused, whatever the rows a chip (the
+    window cell's 32768 too: what its step peaks at in HBM is the step's
+    schedule, ``models/llama.py:TPU_STEP_COMPILER_OPTIONS``, and no row count
+    in the kernel's rule); the same shapes over a list under the distance
+    (or one nobody measured) resolve to split."""
+    from magiattention_tpu.kernels import ffa, registry
+
+    w, wt, g = BWD_CELL_SHAPES[cell]
+    rows = BWD_CELL_ROWS.get(cell, 16384)
+    unpinned = "fused"
+
+    def mode(dist):
+        params = ffa.FFAParams(
+            num_work=w, num_work_t=wt, num_q_tiles=rows // 256,
+            num_k_tiles=32, block_q=256, block_k=512, softmax_scale=1.0,
+            softcap=0.0, group=g, interpret=True, min_revisit_distance=dist)
+        return ffa.resolved_bwd_mode(params, rows, 128, 128, 2)
+
+    registry.reset_registry()
+    assert mode(4) == unpinned
+    assert registry.last_choice("ffa_bwd") == unpinned
+    assert mode(ffa.FUSED_DQ_REVISIT_DISTANCE) == unpinned
+    assert mode(ffa.FUSED_DQ_REVISIT_DISTANCE - 1) == "split"
+    assert registry.last_choice("ffa_bwd") == "split"
+    assert mode(0) == "split"
+    monkeypatch.setenv("MAGI_ATTENTION_BACKEND_FFA_BWD", "fused")
+    assert mode(4) == "fused"
+    assert mode(0) == "split"  # no pin lifts a feasibility guard

@@ -110,6 +110,9 @@ def _bwd_mode(key):
         num_work=w_dq, num_work_t=wt, num_q_tiles=64, num_k_tiles=32,
         block_q=bq, block_k=bk, softmax_scale=1.0, softcap=0.0,
         group=group, interpret=True,
+        # the cells' k-major lists leave a q tile for 4 steps and more (2
+        # at cp 4): the guard before the rule lets them through
+        min_revisit_distance=4,
     )
     return resolved_bwd_mode(params, 64 * bq, d, dv, itemsize)
 
@@ -303,7 +306,7 @@ def test_ffa_bwd_pin_matrix(monkeypatch):
     params = FFAParams(
         num_work=4, num_work_t=4, num_q_tiles=2, num_k_tiles=2,
         block_q=128, block_k=128, softmax_scale=1.0, softcap=0.0,
-        group=1, interpret=True,
+        group=1, interpret=True, min_revisit_distance=3,
     )
     sqp, d, dv, itemsize = 256, 32, 32, 4
     assert fused_bwd_feasible(params, sqp, d, dv, itemsize)
