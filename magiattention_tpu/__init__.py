@@ -40,6 +40,7 @@ def __getattr__(name):
         "undispatch",
         "calc_attn",
         "get_position_ids",
+        "get_document_starts",
         "get_mesh",
         "roll",
         "roll_simple",

@@ -693,6 +693,63 @@ def capture_bsp_contracts(spec: BlockSparseAuditSpec) -> list[KernelContract]:
     return contracts
 
 
+def capture_ssd_contracts(
+    tokens: int = 512, heads: int = 64, p: int = 64, groups: int = 8,
+    n: int = 128,
+) -> list[KernelContract]:
+    """Drive both scan wrappers of ``kernels/ssd.py`` (the forward that
+    saves its states, and the backward) under capture, at the head layout
+    of the benchmark's hybrid cell."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels import ssd
+
+    dims = (heads // groups, p, n, groups)
+    x2 = jnp.zeros((tokens, heads * p), jnp.bfloat16)
+    bc = jnp.zeros((tokens, groups * n), jnp.bfloat16)
+    per_head = jnp.zeros((heads, tokens), jnp.float32)
+    sr = jnp.zeros((ssd.SEG_ROWS, tokens), jnp.float32)
+    hin = jnp.zeros(
+        (tokens // ssd.CHUNK, groups, n, heads // groups * p), jnp.float32)
+    res = (x2, bc, bc, per_head, per_head, sr, hin)
+    contracts: list[KernelContract] = []
+    with jax.default_device(jax.devices("cpu")[0]):
+        for drive in (
+            lambda: ssd._fwd_call(
+                x2, bc, bc, per_head, per_head, sr, dims, save=True),
+            lambda: ssd._ssd_core_bwd(dims, res, x2),
+        ):
+            cap = _capture_pallas()
+            with cap:
+                try:
+                    drive()
+                except _Captured:
+                    pass
+            contracts.extend(cap.contracts)
+    return contracts
+
+
+def check_scan_contract(
+    report: VerifyReport, contract: KernelContract, site: str
+) -> None:
+    """K1, K3 and K4 on a captured scan contract. K1 without a model of
+    the body's intermediates (``mem_budget.ffa_kernel_residency`` knows
+    attention tiles): the declared blocks, double-buffered, and the scratch
+    must leave half of the allowed VMEM to them; the chip's compiler has the
+    last word (``tests/test_models/test_step_schedule.py``)."""
+    report.mark_run("K1")
+    declared = _declared_bytes(contract)
+    if 2 * declared > VMEM_ALLOWED_BYTES:
+        report.add(
+            "K1", ERROR, site,
+            f"VMEM budget: declared blocks and scratch take {declared} "
+            f"bytes/step, over half of the allowed {VMEM_ALLOWED_BYTES}",
+        )
+    check_k3_bounds(report, contract, site)
+    check_k4_dtypes(report, contract, site)
+
+
 # ---------------------------------------------------------------------------
 # contract geometry helpers
 # ---------------------------------------------------------------------------
@@ -1139,8 +1196,10 @@ def _pallas_contracts() -> dict:
     from ..kernels.block_sparse import PALLAS_CONTRACTS as bsp_contracts
     from ..kernels.ffa import PALLAS_CONTRACTS as ffa_contracts
     from ..kernels.paged_decode import PALLAS_CONTRACTS as decode_contracts
+    from ..kernels.ssd import PALLAS_CONTRACTS as ssd_contracts
 
-    return {**ffa_contracts, **decode_contracts, **bsp_contracts}
+    return {**ffa_contracts, **decode_contracts, **bsp_contracts,
+            **ssd_contracts}
 
 
 def _contract_sources() -> list[tuple[str, str, dict]]:
@@ -1149,6 +1208,7 @@ def _contract_sources() -> list[tuple[str, str, dict]]:
     from ..kernels.block_sparse import PALLAS_CONTRACTS as bsp_contracts
     from ..kernels.ffa import PALLAS_CONTRACTS as ffa_contracts
     from ..kernels.paged_decode import PALLAS_CONTRACTS as decode_contracts
+    from ..kernels.ssd import PALLAS_CONTRACTS as ssd_contracts
 
     kdir = _kernels_dir()
     return [
@@ -1163,6 +1223,7 @@ def _contract_sources() -> list[tuple[str, str, dict]]:
             (kdir / "block_sparse.py").read_text(),
             bsp_contracts,
         ),
+        ("kernels/ssd.py", (kdir / "ssd.py").read_text(), ssd_contracts),
     ]
 
 
@@ -1885,6 +1946,22 @@ def run_kernel_audit(
                     "vmem_allowed_bytes": VMEM_ALLOWED_BYTES,
                 }
             )
+
+    # the scan's two calls: no plan metadata, a dense (groups, chunks) grid
+    for contract in capture_ssd_contracts():
+        captured_kernels.add(contract.kernel_name)
+        check_scan_contract(report, contract, f"ssd:{contract.kernel_name}")
+        rows.append(
+            {
+                "config": "ssd",
+                "kernel": contract.kernel_name,
+                "grid": list(contract.grid),
+                "vmem_bytes": _declared_bytes(contract),
+                # no model of the body's intermediates: K1's own margin
+                "vmem_total_bytes": 2 * _declared_bytes(contract),
+                "vmem_allowed_bytes": VMEM_ALLOWED_BYTES,
+            }
+        )
 
     site_kernels = {
         s.kernel_name for s in sites if s.kernel_name in declared

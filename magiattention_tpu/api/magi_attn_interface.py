@@ -424,6 +424,18 @@ def get_position_ids(key: DistAttnRuntimeKey) -> jax.Array:
     return _mgr(key).get_position_ids()
 
 
+def get_document_starts(key: DistAttnRuntimeKey) -> jax.Array:
+    """For each dispatched row, the global row of its document's first token
+    (``int32``), in the same order as :func:`get_position_ids`: rank-major,
+    each rank's chunks in the order its plan holds them, so at cp 1 natural
+    order. ``get_position_ids(key) - get_document_starts(key)`` is a token's
+    position inside its document; a layer that carries state along a
+    document (a recurrence, a causal convolution) resets where the value
+    changes. The documents are the key's own: its slices' q and k ranges,
+    merged where they overlap."""
+    return _mgr(key).get_document_starts()
+
+
 def get_mesh(key: DistAttnRuntimeKey):
     """The ``jax.sharding.Mesh`` the key's runtime was planned for (model
     code composing further parallelism — e.g. expert-parallel shard_maps —

@@ -755,6 +755,32 @@ class DistAttnRuntimeMgr:
 
         return jnp.asarray(self.dispatch_meta_q.position_ids.reshape(-1))
 
+    def get_document_starts(self) -> jax.Array:
+        """The global row of the first token of each dispatched row's
+        document, in the order of :meth:`get_position_ids` (rank-major, each
+        rank's chunks in its plan's order, pad rows 0). A document is a run
+        of rows that the mask ties together: the slices' q and k ranges,
+        merged where they overlap, are the documents; a row no slice covers
+        is a document of its own."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        key = self.key
+        spans = sorted(
+            (min(q[0], k[0]), max(q[1], k[1]))
+            for q, k in zip(key.q_ranges, key.k_ranges))
+        starts = np.arange(key.total_seqlen_q, dtype=np.int32)
+        lo = hi = -1
+        for s, e in spans + [(key.total_seqlen_q, key.total_seqlen_q)]:
+            if s >= hi:  # the run that ended at ``hi`` is one document
+                if hi > lo:
+                    starts[lo:hi] = lo
+                lo, hi = s, e
+            else:
+                hi = max(hi, e)
+        pos = self.dispatch_meta_q.position_ids.reshape(-1)
+        return jnp.asarray(starts[pos])
+
     def get_xattn_args(
         self,
         ref_xattn_q_ranges: AttnRanges,

@@ -332,6 +332,13 @@ register_backend(
 register_backend(
     "serve_decode", "dense", 5, "dense jnp softmax — last resort")
 register_backend(
+    "ssd", "pallas_chunked", 0,
+    "chunked state-space scan, Pallas forward and backward (kernels/ssd.py)")
+register_backend(
+    "moe_grouped", "ragged_dot", 0,
+    "rows sorted by expert, jax.lax.ragged_dot over the experts held "
+    "(models/moe.py)")
+register_backend(
     "nsa_slc", "block_sparse_pallas", 0,
     "gather-free Pallas block-sparse slc kernel")
 register_backend(
@@ -357,4 +364,7 @@ PIN_KEYS: dict[str, tuple[str, ...]] = {
         "MAGI_ATTENTION_FFA_BLOCK_Q_DKV", "MAGI_ATTENTION_FFA_BLOCK_K_DKV",
         "MAGI_ATTENTION_FFA_AUTO_TILE"),
     "nsa_slc": ("MAGI_ATTENTION_BACKEND_NSA_SLC",),
+    # one backend each and no pin: the call site notes its choice
+    "ssd": (),
+    "moe_grouped": (),
 }

@@ -33,7 +33,7 @@ class LlamaConfig:
     n_kv_heads: int = 4
     head_dim: int = 64
     ffn_hidden: int = 1408
-    rope_theta: float = 10000.0
+    rope_theta: float | None = 10000.0  # None: no rotary embedding
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
     # rematerialize each layer in backward (jax.checkpoint) — trades FLOPs
@@ -131,14 +131,17 @@ def _rope(x, pos, theta):
 
 def attn_block(x, lyr, cfg, pos, attn_key):
     """Pre-norm attention sub-block on the dispatched layout (shared by the
-    Llama and MoE families — ONE source of truth for qkv/rope/CP-attn/wo)."""
+    Llama, MoE and hybrid families — ONE source of truth for
+    qkv/rope/CP-attn/wo). ``cfg.rope_theta`` of ``None`` is a block without
+    a rotary embedding (``pos`` is then unused)."""
     dt = x.dtype
     h = _rms_norm(x, lyr["attn_norm"], cfg.norm_eps)
     q = (h @ lyr["wq"].astype(dt)).reshape(-1, cfg.n_heads, cfg.head_dim)
     k = (h @ lyr["wk"].astype(dt)).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
     v = (h @ lyr["wv"].astype(dt)).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-    q = _rope(q, pos, cfg.rope_theta)
-    k = _rope(k, pos, cfg.rope_theta)
+    if cfg.rope_theta is not None:
+        q = _rope(q, pos, cfg.rope_theta)
+        k = _rope(k, pos, cfg.rope_theta)
     attn_out, _ = calc_attn(q, k, v, attn_key)
     attn_out = attn_out.reshape(-1, cfg.n_heads * cfg.head_dim)
     return x + attn_out @ lyr["wo"].astype(dt)

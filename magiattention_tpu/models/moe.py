@@ -35,11 +35,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..api import dispatch, get_mesh, get_position_ids
 from jax import shard_map
 from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
+from ..kernels import registry
 from .llama import (
     LlamaConfig,
     _rms_norm,
@@ -268,6 +270,142 @@ def moe_ffn(h, lyr, cfg: MoEConfig, mesh=None, ep_axis=None):
         out_specs=(P(ep_axis), P()),
     )
     return fn(h, *args)
+
+
+# ---------------------------------------------------------------------------
+# a chip's share of an expert layer that drops no token
+# ---------------------------------------------------------------------------
+
+ROUTES_NAME = "moe_routes"
+ROUTES_SAVED = jax.checkpoint_policies.save_only_these_names(ROUTES_NAME)
+
+
+def route_sigmoid_topk(h, router, bias, top_k: int, scale: float):
+    """DeepSeek-V3-style routing over ALL the experts ``router`` is wide:
+    ``s = sigmoid(h W_r)`` in float32, the ``top_k`` of ``s + bias`` chosen
+    (``bias`` a buffer: it picks, it neither weighs nor learns), weights
+    ``s[chosen] / sum(s[chosen]) * scale``. Returns ``(chosen ids (S, K)
+    int32, weights (S, K) float32, s (S, n_experts) float32)``."""
+    s = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST))
+    _, topi = jax.lax.top_k(jax.lax.stop_gradient(s + bias), top_k)
+    topi = checkpoint_name(topi, ROUTES_NAME)  # saved under remat, see below
+    chosen = jnp.take_along_axis(s, topi, axis=-1)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scale
+    return topi, weights, s
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation whose inverse is known: the backward
+    is the gather ``g[inverse]``, not a scatter-add."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return x[perm], (perm, inverse)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inverse = res
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _local_expert_ids(topi, held: int, offset: int):
+    """``(mine, ids)``: whether each chosen expert is one of the ``held``
+    from ``offset``, and its number among them (``held`` for one that is
+    held elsewhere)."""
+    local = topi - offset
+    mine = (local >= 0) & (local < held)
+    return mine, jnp.where(mine, local, held)
+
+
+def held_expert_rows(topi, held: int, expert_offset: int = 0):
+    """Rows routed to each of the ``held`` experts, ``(held,)`` int32, from
+    the chosen ids of :func:`dropless_moe_ffn`."""
+    _, ids = _local_expert_ids(topi, held, expert_offset)
+    return jnp.zeros((held + 1,), jnp.int32).at[ids.reshape(-1)].add(1)[:held]
+
+
+def _held_experts_block(h, topi, weights, w_up, w_down, offset: int):
+    """The held experts' part of the layer for one block of tokens: every
+    (token, choice) pair is a row; rows are sorted by local expert id, the
+    pairs of experts held elsewhere last, past the groups, where the grouped
+    product computes nothing. The buffer is the block's worst case
+    (``tokens x top_k`` rows), so no row is ever dropped. Returns ``(the
+    block's output, the group sizes the grouped product was given)``."""
+    (sb, k), held = topi.shape, w_up.shape[0]
+    mine, gid = _local_expert_ids(topi, held, offset)
+    order = jnp.argsort(gid.reshape(-1), stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(sb * k, dtype=order.dtype))
+    sizes = held_expert_rows(topi, held, offset)
+    live = (jnp.arange(sb * k) < jnp.sum(sizes))[:, None]
+    # past the groups the grouped product writes nothing, forward or
+    # backward: what it leaves there is masked on the way in and out
+    rows = jnp.where(
+        live, _permute_rows(jnp.repeat(h, k, axis=0), order, inverse), 0)
+    grouped = partial(
+        jax.lax.ragged_dot, group_sizes=sizes,
+        preferred_element_type=jnp.float32)  # not every backend's default
+    up = grouped(rows, w_up)
+    act = jnp.where(live, jnp.square(jax.nn.relu(up)), 0).astype(h.dtype)
+    out = jnp.where(live, grouped(act, w_down), 0).astype(h.dtype)
+    back = _permute_rows(out, inverse, order).reshape(sb, k, -1)
+    gate = jnp.where(mine, weights, 0.0).astype(h.dtype)
+    return jnp.einsum(
+        "sk,skd->sd", gate, back, preferred_element_type=jnp.float32
+    ).astype(h.dtype), sizes
+
+
+def dropless_moe_ffn(
+    h, lyr, *, top_k: int, scale: float, expert_offset: int = 0,
+    token_block: int = 8192,
+):
+    """One chip's share of an expert layer that drops no token.
+
+    The layer is told which experts it holds: ``lyr["w_up"]`` ``(held, dim,
+    ffn)`` and ``lyr["w_down"]`` ``(held, ffn, dim)`` are experts
+    ``expert_offset .. expert_offset + held`` of the ``lyr["router"]``'s
+    ``n_experts`` columns. It routes over all of them
+    (:func:`route_sigmoid_topk`), computes ``w_down relu(w_up h)^2`` of its
+    own experts for the rows routed to them, adds the shared expert
+    (``ws_up``, ``ws_down``, every token) and leaves out what the experts
+    held elsewhere would have added: on one chip the layer runs without its
+    exchange, and summing the routed parts of all the shares with the
+    shared expert counted once gives the whole layer. Tokens go through in
+    blocks of ``token_block`` (each block rematerialised in the backward),
+    which bounds the worst-case row buffer.
+
+    Under ``jax.checkpoint`` the chosen ids must be SAVED, not recomputed
+    (``policy=ROUTES_SAVED``): the recomputed forward is another XLA
+    program region, free to keep a bf16 value in float32, and a score that
+    moves in its last bits flips the sixth expert of a token in a few
+    hundred, whose gradient then belongs to an expert the forward never ran.
+
+    Returns ``(y (S, dim), {"topi": chosen expert ids (S, K), "scores": the
+    router's scores (S, n_experts) float32, "group_rows": the rows the
+    grouped product took for each held expert (held,)})``.
+    """
+    dt = h.dtype
+    s, dim = h.shape
+    topi, weights, scores = route_sigmoid_topk(
+        h, lyr["router"], lyr["e_bias"], top_k, scale)
+    w_up, w_down = lyr["w_up"].astype(dt), lyr["w_down"].astype(dt)
+    registry.note_choice(
+        "moe_grouped", (s, dim, *w_up.shape, top_k), "ragged_dot", "default")
+    sb = token_block if s % token_block == 0 else s
+    block = jax.checkpoint(
+        lambda args: _held_experts_block(*args, w_up, w_down, expert_offset))
+    routed, sizes = jax.lax.map(block, tuple(
+        v.reshape(s // sb, sb, -1) for v in (h, topi, weights)))
+    shared = jnp.square(jax.nn.relu(h @ lyr["ws_up"].astype(dt))) @ (
+        lyr["ws_down"].astype(dt))
+    return routed.reshape(s, dim) + shared, {
+        "topi": topi, "scores": scores, "group_rows": jnp.sum(sizes, axis=0)}
 
 
 # ---------------------------------------------------------------------------

@@ -194,7 +194,7 @@ def test_no_pallas_call_site_passes_a_name():
                     # every site goes through the helper
                     assert ast.unparse(node.func) == "_named.pallas_call", (
                         path.name, node.lineno)
-    assert sites == 15  # 9 FFA + 3 paged decode + 2 block sparse + helper
+    assert sites == 17  # 9 FFA + 3 paged decode + 2 block sparse + 2 scan + helper
 
 
 def test_the_benchmarks_kernel_report_is_unchanged(bare_env):

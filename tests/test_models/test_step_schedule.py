@@ -129,3 +129,49 @@ def test_list_order_is_the_default_order_at_16384_tokens_a_chip(
                lowered.compile(compiler_options=opts).as_text())
         for opts in (None, llama.TPU_STEP_COMPILER_OPTIONS)]
     assert text[0] == text[1]
+
+
+def test_the_hybrid_step_compiles_for_a_v5e_chip_and_fits_it(
+    compiled_for_one_v5e_chip,
+):
+    """``nemotron3nano.packed32k.cp1`` at its timed size, 32768 tokens at the
+    published widths: the scan's two Pallas bodies and FFA at 32 q / 2 kv
+    heads pass Mosaic, the held experts' grouped products are the TPU
+    compiler's own ragged-dot calls, every name the benchmark's event
+    classes look for is there, and masters plus temporaries leave the
+    chip's 15.75 GiB some room."""
+    from magiattention_tpu.models import hybrid
+
+    mesh, sharding = compiled_for_one_v5e_chip
+    cell = manifest.load_cell(manifest.ROOT, "nemotron3nano.packed32k.cp1")
+    family = manifest.load_family(manifest.ROOT, cell.config["family"])
+    cfg, tokens, window, _ = run.cell_sizes(cell, family, 0)
+    spec = traffic_gen.make_mask(
+        cell.traffic, tokens, window, 0,
+        manifest.load_generator(manifest.ROOT, cell.traffic["generator"]))
+    mcfg = family.model_config(cfg)
+    shapes = jax.eval_shape(
+        partial(hybrid.init_params, mcfg), jax.random.PRNGKey(0))
+    weights = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    assert weights == pytest.approx(1625e6, rel=1e-3)  # 6.05 GiB of fp32
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        shapes)
+    toks = jax.ShapeDtypeStruct((tokens,), jnp.int32, sharding=sharding)
+    compiled = hybrid.train_step.lower(
+        params, mcfg, toks, toks, family.make_key(spec, mesh)
+    ).compile(compiler_options=llama.TPU_STEP_COMPILER_OPTIONS)
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) / GIB < 15.0
+    names = re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       compiled.as_text())
+    kinds = {re.sub(r"[.][0-9]+$", "", n) for n in names}
+    # forward, re-forward (decorated by the transform), backward a block
+    assert sum("magi_ssd_fwd_kernel" in n for n in names) == 2 * 4
+    assert sum("magi_ssd_bwd_kernel" in n for n in names) == 4
+    assert {"magi_fwd_kernel", "magi_delta_kernel",
+            "magi_bwd_fused_kernel"} <= kinds
+    assert any(n.startswith("ragged-dot") for n in names)
+    claimed = re.compile(r"magi_|^ragged-dot")
+    assert all(claimed.search(n) for n in names), kinds
