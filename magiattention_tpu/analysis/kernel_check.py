@@ -750,6 +750,82 @@ def check_scan_contract(
     check_k4_dtypes(report, contract, site)
 
 
+def capture_grouped_contracts(
+    tokens: int = 8192, top_k: int = 6, experts: int = 128, held: int = 32,
+    dim: int = 2688, ffn: int = 1856,
+) -> list[KernelContract]:
+    """Drive the grouped matmul's two wrappers (``kernels/grouped_matmul.py``)
+    under capture at the shapes of the benchmark's hybrid cell: the up
+    product (float32 out; its weight is kept ``K``-minor, so the block is
+    read transposed), the down product (rounded once), their two ``d rows``
+    (the other reading of each weight) and ``dW`` (both are ``[held, ffn,
+    dim]`` as stored); every held expert given its expected rows, the rest
+    of the buffer past the groups."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels import grouped_matmul, tile_policy
+
+    m, rows_a_group = tokens * top_k, tokens * top_k // experts
+    tile = tile_policy.grouped_row_tile(rows_a_group)
+    sizes = jnp.full((held,), rows_a_group, jnp.int32)
+    rows = jnp.zeros((m, dim), jnp.bfloat16)
+    act = jnp.zeros((m, ffn), jnp.bfloat16)
+    w_down = jnp.zeros((held, ffn, dim), jnp.bfloat16)
+    contracts: list[KernelContract] = []
+    with jax.default_device(jax.devices("cpu")[0]):
+        w_up, k_minor = grouped_matmul._stored(
+            jnp.zeros((held, dim, ffn), jnp.bfloat16))
+        for drive in (
+            lambda: grouped_matmul._product_call(
+                rows, w_up, sizes, tile, jnp.float32, k_minor),
+            lambda: grouped_matmul._product_call(
+                act, w_down, sizes, tile, jnp.bfloat16, False),
+            lambda: grouped_matmul._product_call(
+                act, w_up, sizes, tile, jnp.bfloat16, not k_minor),
+            lambda: grouped_matmul._product_call(
+                rows, w_down, sizes, tile, jnp.bfloat16, True),
+            lambda: grouped_matmul._dw_call(
+                act, rows, sizes, tile, jnp.bfloat16),
+        ):
+            cap = _capture_pallas()
+            with cap:
+                try:
+                    drive()
+                except _Captured:
+                    pass
+            contracts.extend(cap.contracts)
+    return contracts
+
+
+def grouped_residency(contract: KernelContract) -> int:
+    """Bytes of VMEM a grouped-matmul step holds: the declared blocks,
+    double-buffered, the scratch, and the one intermediate of the body, the
+    float32 product of the MXU at the size of the output block (the row
+    tile by the column block, or ``dW``'s block before it is added in)."""
+    product = _block_bytes(contract.out_specs[0].block_shape, "float32")
+    return _declared_bytes(contract) + product
+
+
+def check_grouped_contract(
+    report: VerifyReport, contract: KernelContract, site: str
+) -> None:
+    """K1 and K4 on a captured grouped-matmul contract. K3's block bounds
+    are not asked: a column block may end past a width that is no multiple
+    of it (1856 in blocks of 640), which Pallas clips; the visit tables are
+    held to a count by hand in ``tests/test_attn/test_grouped_matmul.py``."""
+    report.mark_run("K1")
+    total = grouped_residency(contract)
+    if total > VMEM_ALLOWED_BYTES:
+        report.add(
+            "K1", ERROR, site,
+            f"VMEM budget: {total} bytes/step (declared blocks, scratch "
+            f"and the float32 product) exceeds the allowed "
+            f"{VMEM_ALLOWED_BYTES}",
+        )
+    check_k4_dtypes(report, contract, site)
+
+
 # ---------------------------------------------------------------------------
 # contract geometry helpers
 # ---------------------------------------------------------------------------
@@ -1190,16 +1266,23 @@ def check_k4_dtypes(
                 f"passthrough output dtype {got} != operand dtype "
                 f"{input_dtype}",
             )
+        elif want == "f32_or_input" and got not in ("float32", input_dtype):
+            report.add(
+                "K4", ERROR, f"{site} out[{i}]",
+                f"output dtype {got} is neither the float32 accumulator "
+                f"nor its one rounding to the operand dtype {input_dtype}",
+            )
 
 
 def _pallas_contracts() -> dict:
     from ..kernels.block_sparse import PALLAS_CONTRACTS as bsp_contracts
     from ..kernels.ffa import PALLAS_CONTRACTS as ffa_contracts
+    from ..kernels.grouped_matmul import PALLAS_CONTRACTS as grouped_contracts
     from ..kernels.paged_decode import PALLAS_CONTRACTS as decode_contracts
     from ..kernels.ssd import PALLAS_CONTRACTS as ssd_contracts
 
     return {**ffa_contracts, **decode_contracts, **bsp_contracts,
-            **ssd_contracts}
+            **ssd_contracts, **grouped_contracts}
 
 
 def _contract_sources() -> list[tuple[str, str, dict]]:
@@ -1207,6 +1290,7 @@ def _contract_sources() -> list[tuple[str, str, dict]]:
     PALLAS_CONTRACTS — the K2/K4 source-rule sweep iterates these."""
     from ..kernels.block_sparse import PALLAS_CONTRACTS as bsp_contracts
     from ..kernels.ffa import PALLAS_CONTRACTS as ffa_contracts
+    from ..kernels.grouped_matmul import PALLAS_CONTRACTS as grouped_contracts
     from ..kernels.paged_decode import PALLAS_CONTRACTS as decode_contracts
     from ..kernels.ssd import PALLAS_CONTRACTS as ssd_contracts
 
@@ -1224,6 +1308,11 @@ def _contract_sources() -> list[tuple[str, str, dict]]:
             bsp_contracts,
         ),
         ("kernels/ssd.py", (kdir / "ssd.py").read_text(), ssd_contracts),
+        (
+            "kernels/grouped_matmul.py",
+            (kdir / "grouped_matmul.py").read_text(),
+            grouped_contracts,
+        ),
     ]
 
 
@@ -1959,6 +2048,23 @@ def run_kernel_audit(
                 "vmem_bytes": _declared_bytes(contract),
                 # no model of the body's intermediates: K1's own margin
                 "vmem_total_bytes": 2 * _declared_bytes(contract),
+                "vmem_allowed_bytes": VMEM_ALLOWED_BYTES,
+            }
+        )
+
+    # the grouped matmul's two bodies at the hybrid cell's shapes: a grid
+    # of (column blocks, live row-tile visits)
+    for contract in capture_grouped_contracts():
+        captured_kernels.add(contract.kernel_name)
+        check_grouped_contract(
+            report, contract, f"grouped:{contract.kernel_name}")
+        rows.append(
+            {
+                "config": "moe_grouped",
+                "kernel": contract.kernel_name,
+                "grid": list(contract.grid),
+                "vmem_bytes": _declared_bytes(contract),
+                "vmem_total_bytes": grouped_residency(contract),
                 "vmem_allowed_bytes": VMEM_ALLOWED_BYTES,
             }
         )

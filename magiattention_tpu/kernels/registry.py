@@ -335,9 +335,11 @@ register_backend(
     "ssd", "pallas_chunked", 0,
     "chunked state-space scan, Pallas forward and backward (kernels/ssd.py)")
 register_backend(
-    "moe_grouped", "ragged_dot", 0,
-    "rows sorted by expert, jax.lax.ragged_dot over the experts held "
-    "(models/moe.py)")
+    "moe_grouped", "pallas_grouped", 0,
+    "rows sorted by expert, a Pallas grouped matmul over the live row "
+    "tiles of the experts held, forward, d rows and dW "
+    "(kernels/grouped_matmul.py; the row tile, tile_policy."
+    "grouped_row_tile, is noted under moe_grouped_tiles)")
 register_backend(
     "nsa_slc", "block_sparse_pallas", 0,
     "gather-free Pallas block-sparse slc kernel")
@@ -367,4 +369,5 @@ PIN_KEYS: dict[str, tuple[str, ...]] = {
     # one backend each and no pin: the call site notes its choice
     "ssd": (),
     "moe_grouped": (),
+    "moe_grouped_tiles": (),
 }

@@ -757,3 +757,82 @@ def choose_bwd_mode(
         wt * bwd_step_us("dkv", bq_dkv, bk_dkv, *hbm))
     fused_us = wt * bwd_step_us("fused", bq_dkv, bk_dkv, *hbm)
     return "fused" if fused_us <= split_us else "split"
+
+
+# ---------------------------------------------------------------------------
+# the grouped matmul's tiles (kernels/grouped_matmul.py): rules over static
+# shapes, as the FFA tiles are
+# ---------------------------------------------------------------------------
+
+GROUPED_ROW_TILES = (512, 256, 128, 64, 32, 16)
+# how many row tiles a group of the expected size should fill: a group's
+# first and last tile are shared with its neighbours (boundaries fall
+# anywhere), so with r tiles a group the MXU is given (r + 1) / r times the
+# live rows
+GROUPED_TILES_PER_GROUP = 3
+# VMEM one double-buffered weight block of the product body, and the dW
+# body's accumulator with its double-buffered output block, may take: half
+# of what a kernel is allowed, the rest is the row tiles, the result and
+# Mosaic's own
+GROUPED_BLOCK_BUDGET = 7 * 1024 * 1024
+
+
+def grouped_row_tile(rows_per_group: int) -> int:
+    """Row tile of the grouped matmul for groups expected to hold
+    ``rows_per_group`` rows (an expert layer: ``tokens x top_k / n_experts``,
+    known at trace time): the largest of ``GROUPED_ROW_TILES`` that still
+    gives such a group ``GROUPED_TILES_PER_GROUP`` tiles. A larger tile
+    spends its MXU passes on rows masked away at the groups' boundaries; a
+    smaller one pays the grid step's fixed cost more often."""
+    for tile in GROUPED_ROW_TILES:
+        if tile * GROUPED_TILES_PER_GROUP <= rows_per_group:
+            return tile
+    return GROUPED_ROW_TILES[-1]
+
+
+def grouped_weight_k_minor(k: int, n: int) -> bool:
+    """Whether the bodies are given each group's weight ``[K, N]`` as its
+    transpose ``[N, K]``: where ``N`` is no multiple of the lanes and ``K``
+    is (an expert's up projection, 2688 x 1856). A ``[G, K, N]`` array with
+    such an ``N`` minor is padded to the next 128 in HBM and its column
+    blocks end off the lane grid; XLA itself keeps such a parameter
+    ``K``-minor, so asking for it that way also saves the relayout."""
+    return n % NUM_LANES != 0 and k % NUM_LANES == 0
+
+
+def _even_col_tile(n: int, widest: int) -> int:
+    """``n`` columns in the fewest lane-aligned blocks of at most ``widest``,
+    evened out (1856 under 682 -> 3 blocks of 640, not 512 x 3 + 320); all
+    of ``n`` where it fits (a block as wide as the array needs no
+    alignment)."""
+    if n <= widest:
+        return n
+    blocks = _round_up(n, NUM_LANES) // NUM_LANES
+    widest = max(widest // NUM_LANES, 1)
+    return NUM_LANES * -(-blocks // -(-blocks // widest))
+
+
+def grouped_col_tile(k: int, n: int, itemsize: int) -> int:
+    """Columns of a group's weight the product body holds at a time: all of
+    ``K`` deep (one contraction), double-buffered within
+    ``GROUPED_BLOCK_BUDGET``."""
+    return _even_col_tile(n, GROUPED_BLOCK_BUDGET // (2 * k * itemsize))
+
+
+def grouped_dw_tiles(k: int, n: int, itemsize: int) -> tuple[int, int]:
+    """``(tk, tn)`` of the dW body's block of ``dW[g]``: the float32
+    accumulator and the double-buffered output block within
+    ``GROUPED_BLOCK_BUDGET``, and of the lane-aligned even splits that fit,
+    the one that reads least (the rows once a column block, ``dy`` once a
+    row block of ``K``)."""
+    most = GROUPED_BLOCK_BUDGET // (4 + 2 * itemsize)
+
+    def splits(x: int) -> set[int]:
+        lanes = _round_up(x, NUM_LANES) // NUM_LANES
+        return {x} | {
+            NUM_LANES * -(-lanes // parts) for parts in range(2, lanes + 1)}
+
+    return min(
+        ((-(-n // tn) * k + -(-k // tk) * n, -tk * tn), (tk, tn))
+        for tk in splits(k) for tn in splits(n)
+        if tk * tn <= most or (tk, tn) == (NUM_LANES, NUM_LANES))[1]

@@ -41,7 +41,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..api import dispatch, get_mesh, get_position_ids
 from jax import shard_map
 from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
-from ..kernels import registry
+from ..kernels import registry, tile_policy
+from ..kernels.grouped_matmul import grouped_matmul, note_tile_stats
 from .llama import (
     LlamaConfig,
     _rms_norm,
@@ -330,7 +331,8 @@ def held_expert_rows(topi, held: int, expert_offset: int = 0):
     return jnp.zeros((held + 1,), jnp.int32).at[ids.reshape(-1)].add(1)[:held]
 
 
-def _held_experts_block(h, topi, weights, w_up, w_down, offset: int):
+def _held_experts_block(h, topi, weights, w_up, w_down, offset: int,
+                        tile_rows: int):
     """The held experts' part of the layer for one block of tokens: every
     (token, choice) pair is a row; rows are sorted by local expert id, the
     pairs of experts held elsewhere last, past the groups, where the grouped
@@ -348,12 +350,11 @@ def _held_experts_block(h, topi, weights, w_up, w_down, offset: int):
     # backward: what it leaves there is masked on the way in and out
     rows = jnp.where(
         live, _permute_rows(jnp.repeat(h, k, axis=0), order, inverse), 0)
-    grouped = partial(
-        jax.lax.ragged_dot, group_sizes=sizes,
-        preferred_element_type=jnp.float32)  # not every backend's default
-    up = grouped(rows, w_up)
+    note_tile_stats(sizes, tile_rows)
+    grouped = partial(grouped_matmul, group_sizes=sizes, tile_rows=tile_rows)
+    up = grouped(rows, w_up)  # float32: relu^2 is taken before the rounding
     act = jnp.where(live, jnp.square(jax.nn.relu(up)), 0).astype(h.dtype)
-    out = jnp.where(live, grouped(act, w_down), 0).astype(h.dtype)
+    out = jnp.where(live, grouped(act, w_down, out_dtype=h.dtype), 0)
     back = _permute_rows(out, inverse, order).reshape(sb, k, -1)
     gate = jnp.where(mine, weights, 0.0).astype(h.dtype)
     return jnp.einsum(
@@ -395,11 +396,18 @@ def dropless_moe_ffn(
     topi, weights, scores = route_sigmoid_topk(
         h, lyr["router"], lyr["e_bias"], top_k, scale)
     w_up, w_down = lyr["w_up"].astype(dt), lyr["w_down"].astype(dt)
-    registry.note_choice(
-        "moe_grouped", (s, dim, *w_up.shape, top_k), "ragged_dot", "default")
     sb = token_block if s % token_block == 0 else s
+    # the rows an expert expects of a block are known here, the sizes it
+    # gets are not: the row tile is the rule's for that expectation
+    tile_rows = tile_policy.grouped_row_tile(
+        sb * top_k // lyr["router"].shape[-1])
+    key = (s, dim, *w_up.shape, top_k)
+    registry.note_choice("moe_grouped", key, "pallas_grouped", "default")
+    registry.note_choice(
+        "moe_grouped_tiles", key, f"rows{tile_rows}", "shape_rule")
     block = jax.checkpoint(
-        lambda args: _held_experts_block(*args, w_up, w_down, expert_offset))
+        lambda args: _held_experts_block(
+            *args, w_up, w_down, expert_offset, tile_rows))
     routed, sizes = jax.lax.map(block, tuple(
         v.reshape(s // sb, sb, -1) for v in (h, topi, weights)))
     shared = jnp.square(jax.nn.relu(h @ lyr["ws_up"].astype(dt))) @ (

@@ -36,12 +36,13 @@ from magiattention_tpu.analysis.violation import VerifyReport
 
 def test_discovery_finds_every_pallas_site():
     sites = discover_pallas_sites()
-    assert len(sites) == 16
+    assert len(sites) == 18
     names = {s.kernel_name for s in sites}
     assert names == set(_pallas_contracts())
     assert {s.relpath for s in sites} == {
         "kernels/ffa.py", "kernels/paged_decode.py",
         "kernels/block_sparse.py", "kernels/ssd.py",
+        "kernels/grouped_matmul.py",
     }
 
 

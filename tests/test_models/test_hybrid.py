@@ -52,7 +52,8 @@ def test_the_step_agrees_with_the_plain_reference(toy):
     assert list(checks) == list(reference_nemotron_h.CHECKS)
     assert all(c["ok"] for c in checks.values()), checks
     assert registry.last_choice("ssd") == "pallas_chunked"
-    assert registry.last_choice("moe_grouped") == "ragged_dot"
+    assert registry.last_choice("moe_grouped") == "pallas_grouped"
+    assert registry.last_choice("moe_grouped_tiles") == "rows16"
 
 
 def test_a_scan_accumulated_in_bf16_fails_a_named_check(toy, monkeypatch):
@@ -71,13 +72,13 @@ def test_a_scan_accumulated_in_bf16_fails_a_named_check(toy, monkeypatch):
 def test_a_dropped_routed_row_fails_a_named_check(toy, monkeypatch):
     """One routed row of the first held expert left out of the grouped
     product in every block of tokens."""
-    grouped = jax.lax.ragged_dot
+    grouped = moe.grouped_matmul
 
     def one_row_short(rows, w, group_sizes, **kw):
         out = grouped(rows, w, group_sizes, **kw)
         return out.at[0].set(0)  # the first sorted row is expert 0's
 
-    monkeypatch.setattr(jax.lax, "ragged_dot", one_row_short)
+    monkeypatch.setattr(moe, "grouped_matmul", one_row_short)
     checks = _compare(*toy, lens=[100, 50, 129, 105], seed=3)
     failed = {name for name, c in checks.items() if not c["ok"]}
     assert "grad_expert_w_up" in failed, checks

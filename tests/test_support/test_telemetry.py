@@ -176,7 +176,8 @@ def test_kernel_audit_report_round_trip(tmp_path, monkeypatch, capsys):
     agg = mod.aggregate(mod.load_records([str(tmp_path)]))
     ka = agg["kernel_audit"]
     assert ka["runs"] == 1
-    assert ka["kernels"] == 16  # 9 ffa + 3 paged-decode + 2 block-sparse + 2 scan
+    # 9 ffa + 3 paged-decode + 2 block-sparse + 2 scan + 2 grouped matmul
+    assert ka["kernels"] == 18
     assert ka["configs"] >= 1
     assert ka["rules_run"] == ["K1", "K2", "K3", "K4", "K5"]
     assert ka["errors_total"] == 0 and ka["warnings_total"] == 0
