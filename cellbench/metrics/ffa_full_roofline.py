@@ -1,0 +1,8 @@
+"""Share of its roofline the FFA calls under the key labelled ``full``
+reach: the group's least time on its own mask over its kernels' time."""
+
+from cellbench import keyed_ffa
+
+
+def read(ctx):
+    return keyed_ffa.roofline(ctx, "full")

@@ -47,5 +47,6 @@ from .magi_attn_interface import (  # noqa: F401
     make_varlen_key_for_new_mask_after_dispatch,
     roll,
     roll_simple,
+    same_dispatch,
     undispatch,
 )

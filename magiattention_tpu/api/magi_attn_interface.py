@@ -137,11 +137,20 @@ def magi_attn_flex_key(
     head_axis: str | None = None,
     chunk_size: int | None = None,
     dist_attn_config: DistAttnConfig | None = None,
+    label: str | None = None,
 ) -> DistAttnRuntimeKey:
     """Plan a flexible-mask distributed attention; returns the runtime key.
 
     ``head_axis`` (optional) names a mesh axis to tensor-parallel-shard the
     head dimension over — attention runs TP x CP in one shard_map.
+
+    ``label`` (optional) is a short name for the mask, for a model that
+    attends under several keys a step (``"full"`` here, ``"window"`` on the
+    key :func:`make_varlen_key_for_new_mask_after_dispatch` makes of it):
+    it follows the kernel bodies' names in a device trace
+    (``magi_fwd_kernel_window``) and keys the registry's record of the
+    calls' tiles and backward mode (``registry.labelled_choices``). It
+    changes no plan; a key without one names everything as before.
 
     The mask is ``(q_ranges, k_ranges, attn_mask_type)`` slice metadata in
     global coordinates (ref :442). ``total_seqlen_q`` must be pre-padded to
@@ -197,6 +206,7 @@ def magi_attn_flex_key(
         # rides the key, so the plan re-solves exactly when it changes
         # (None when detection is off or every rank is healthy)
         capacities=telemetry_health.active_capacities(cp_size),
+        label=label,
     )
     _runtime_dict.get_or_create(key, mesh)
     _most_recent_key = key
@@ -215,6 +225,7 @@ def magi_attn_varlen_key(
     head_axis: str | None = None,
     chunk_size: int | None = None,
     dist_attn_config: DistAttnConfig | None = None,
+    label: str | None = None,
 ) -> DistAttnRuntimeKey:
     """Varlen (cu_seqlens) convenience wrapper (ref :160; causal defaults
     False, matching the reference and the re-key variant). ``window_size``
@@ -235,6 +246,7 @@ def magi_attn_varlen_key(
         head_axis=head_axis,
         chunk_size=chunk_size,
         dist_attn_config=dist_attn_config,
+        label=label,
     )
 
 
@@ -244,8 +256,10 @@ def make_flex_key_for_new_mask_after_dispatch(
     attn_mask_type,
     key_for_dispatch: DistAttnRuntimeKey,
     dist_attn_config: DistAttnConfig | None = None,
+    label: str | None = None,
 ) -> DistAttnRuntimeKey:
-    """New mask, same dispatch solution (ref :1320).
+    """New mask, same dispatch solution (ref :1320); ``label`` as in
+    :func:`magi_attn_flex_key`, and not inherited from ``key_for_dispatch``.
 
     For hybrid-attn models applying several masks in one pass: one mask is
     chosen for dispatch (load balance + comm optimization follow it); the
@@ -287,6 +301,7 @@ def make_flex_key_for_new_mask_after_dispatch(
         # the pinned partitions already embody the dispatch key's capacity
         # weighting; carry the vector so the signature stays consistent
         capacities=old.capacities,
+        label=label,
     )
     _runtime_dict.get_or_create(key, mgr0.mesh)
     _most_recent_key = key
@@ -301,16 +316,22 @@ def make_varlen_key_for_new_mask_after_dispatch(
     window_size: tuple[int, int] = (-1, -1),
     global_window_size: int = 0,
     dist_attn_config: DistAttnConfig | None = None,
+    label: str | None = None,
 ) -> DistAttnRuntimeKey:
     """Varlen convenience form of re-keying (ref :1172) — ONE compile
     path with :func:`magi_attn_varlen_key`, so a model created with
-    windows + global sinks re-keys to the identical mask."""
+    windows + global sinks re-keys to the identical mask. A model whose
+    layers attend under two masks a step (window layers and full layers,
+    ``models/hybrid.py``) plans the full mask, which owns the dispatch, and
+    makes the window key of it here: tensors dispatched under either key
+    are in the same layout."""
     q_ranges, k_ranges, types = infer_attn_mask_from_cu_seqlens(
         cu_seqlens_q, cu_seqlens_k, causal,
         window_size=window_size, global_window_size=global_window_size,
     )
     return make_flex_key_for_new_mask_after_dispatch(
-        q_ranges, k_ranges, types, key_for_dispatch, dist_attn_config
+        q_ranges, k_ranges, types, key_for_dispatch, dist_attn_config,
+        label=label,
     )
 
 
@@ -434,6 +455,16 @@ def get_document_starts(key: DistAttnRuntimeKey) -> jax.Array:
     changes. The documents are the key's own: its slices' q and k ranges,
     merged where they overlap."""
     return _mgr(key).get_document_starts()
+
+
+def same_dispatch(key_a: DistAttnRuntimeKey, key_b: DistAttnRuntimeKey) -> bool:
+    """Whether the two keys lay a sequence out alike: a tensor dispatched
+    under one is in place for ``calc_attn`` under the other. True of a key
+    and one made of it by ``make_*_key_for_new_mask_after_dispatch``."""
+    import numpy as np
+
+    a, b = _mgr(key_a).dispatch_meta_q, _mgr(key_b).dispatch_meta_q
+    return np.array_equal(a.position_ids, b.position_ids)
 
 
 def get_mesh(key: DistAttnRuntimeKey):

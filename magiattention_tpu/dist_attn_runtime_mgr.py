@@ -92,6 +92,11 @@ class DistAttnRuntimeKey:
     # re-solves exactly when the vector changes and the plan control plane
     # caches/persists/broadcasts weighted plans like any other.
     capacities: tuple[float, ...] | None = None
+    # a short name for the mask ("window", "full") where a model attends
+    # under several keys a step: it rides in the kernels' scope names
+    # (kernels/_named.py), in the registry's record of the calls' tiles and
+    # backward mode and in the telemetry records, and changes no plan
+    label: str | None = None
 
 
 def _plan_signature(key: DistAttnRuntimeKey) -> tuple:
@@ -632,6 +637,7 @@ class DistAttnRuntimeMgr:
             # auto (overlap iff the solver produced >1 stage) when enabled,
             # forced single merged kernel when disabled
             use_overlap=None if overlap_cfg.enable else False,
+            label=key.label,
         )
         self._record_comm_plan()
         self._maybe_verify()

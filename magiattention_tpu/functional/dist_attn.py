@@ -293,7 +293,8 @@ def _ragged_arrays(s) -> tuple[jax.Array, ...]:
 
 def _stack_plans(args: list[AttnArg], sq: int, sk: int, bq: int, bk: int,
                  policy_dq: tuple[int, int] | None = None,
-                 policy_dkv: tuple[int, int] | None = None):
+                 policy_dkv: tuple[int, int] | None = None,
+                 label: str | None = None):
     """Per-rank FFA plans -> rank-stacked arrays padded to a common size.
 
     Returns ``(stacked_arrays, dims)`` where dims feeds
@@ -310,7 +311,8 @@ def _stack_plans(args: list[AttnArg], sq: int, sk: int, bq: int, bk: int,
     def build_stack(blq: int, blk: int, fields: tuple[str, ...]):
         plans = [
             build_ffa_plan(
-                a.q_ranges, a.k_ranges, a.d_lo, a.d_hi, sq, sk, blq, blk
+                a.q_ranges, a.k_ranges, a.d_lo, a.d_hi, sq, sk, blq, blk,
+                label=label,
             )
             for a in args
         ]
@@ -487,6 +489,9 @@ class DistAttnRuntime(DeferredTilePolicy):
     # §2.8; on TPU the attention itself runs TP-sharded in the same
     # shard_map, no host framework needed)
     head_axis: str | None = None
+    # the runtime key's label (DistAttnRuntimeKey.label): handed to every
+    # FFA call's params and to the plans' telemetry records
+    label: str | None = None
 
     def __post_init__(self) -> None:
         cm, km = self.comm_meta, self.calc_meta
@@ -599,7 +604,7 @@ class DistAttnRuntime(DeferredTilePolicy):
         # merged (no-overlap) plan
         self._merged_arrays, self._merged_dims = _stack_plans(
             km.merged_args, shard, kv_shard + total_recv, bq, bk,
-            policy_dq=pol_dq, policy_dkv=pol_dkv,
+            policy_dq=pol_dq, policy_dkv=pol_dkv, label=self.label,
         )
 
         if self.use_overlap:
@@ -608,7 +613,7 @@ class DistAttnRuntime(DeferredTilePolicy):
             self._host_arrays, self._host_dims = _stack_plans(
                 km.host_args, shard, kv_shard,
                 bq, min(bk, _ceil_to(kv_shard, 128)),
-                policy_dq=pol_dq, policy_dkv=pol_dkv,
+                policy_dq=pol_dq, policy_dkv=pol_dkv, label=self.label,
             )
             self._stage_arrays = []
             self._stage_dims = []
@@ -617,7 +622,7 @@ class DistAttnRuntime(DeferredTilePolicy):
                 sa, sdims = _stack_plans(
                     km.remote_args_per_stage[st], shard, rl,
                     bq, min(bk, _ceil_to(rl, 128)),
-                    policy_dq=pol_dq, policy_dkv=pol_dkv,
+                    policy_dq=pol_dq, policy_dkv=pol_dkv, label=self.label,
                 )
                 self._stage_arrays.append(sa)
                 self._stage_dims.append(sdims)
@@ -811,6 +816,7 @@ class DistAttnRuntime(DeferredTilePolicy):
             # the max-logits output costs an (hq, sqp, 128) fp32 HBM write
             # per kernel call — emitted only when the caller asks
             emit_max_logits=emit_max_logits,
+            label=self.label,
         )
 
     @instrument_scope(name="DistAttnRuntime.calc_attn")

@@ -24,6 +24,11 @@ of the multi-stage path, ``DistAttnRuntime.calc_attn``) survive in
 * The prefix tells the library's kernels from any other Pallas kernel of a
   user's model, and keeps every name clear of a collective primitive's
   (``all_to_all``, ``ppermute``, ``psum``, ...).
+* A call made for a labelled runtime key (``DistAttnRuntimeKey.label``, e.g.
+  ``window`` and ``full`` where a model attends under two masks a step)
+  carries the label after the body's name, ``magi_fwd_kernel_window``: the
+  body's name is still there for whoever looks for it, and a trace tells
+  one key's calls from another's. Without a label the name is the body's.
 """
 
 from __future__ import annotations
@@ -44,12 +49,13 @@ def kernel_scope_name(kernel) -> str:
     return KERNEL_SCOPE_PREFIX + kernel.__name__
 
 
-def pallas_call(kernel, **kwargs):
-    """``pl.pallas_call(kernel, **kwargs)``, bound under the kernel's name.
+def pallas_call(kernel, label: str | None = None, **kwargs):
+    """``pl.pallas_call(kernel, **kwargs)``, bound under the kernel's name
+    (and ``_<label>`` after it, where the caller has one).
     ``pl.pallas_call`` is looked up when called, so the contract capture of
     ``analysis/kernel_check.py`` still intercepts it."""
     call = pl.pallas_call(kernel, **kwargs)
-    scope = kernel_scope_name(kernel)
+    scope = kernel_scope_name(kernel) + (f"_{label}" if label else "")
 
     def bound(*operands):
         with jax.named_scope(scope):

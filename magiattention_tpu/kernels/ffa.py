@@ -142,6 +142,10 @@ class FFAParams:
     # beside num_work_t: the plan's arrays may be traced, the mode may read
     # only statics. 0 = not known, which keeps the backward split.
     min_revisit_distance: int = 0
+    # the runtime key's label (DistAttnRuntimeKey.label), or None: it rides
+    # after the body's name in every Pallas call's scope and keys the
+    # registry's record of this call's tiles and backward mode
+    label: str | None = None
 
     def dq_blocks(self) -> tuple[int, int]:
         return (self.block_q_dq or self.block_q,
@@ -462,6 +466,7 @@ def _ffa_fwd_pallas(params: FFAParams, work_qt, work_kt, meta, q_t, k_t, v_t):
     lse_shape = jax.ShapeDtypeStruct((hq, sqp, NUM_LANES), jnp.float32)
     outs = _named.pallas_call(
         kernel,
+        label=params.label,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hq, sqp, dv), q_t.dtype),
@@ -692,6 +697,7 @@ def _ffa_fwd_pallas_gqa(
     )
     outs = _named.pallas_call(
         kernel,
+        label=params.label,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, g, sqp, dv), q_t.dtype),
@@ -968,6 +974,7 @@ def _ffa_bwd_dq_pallas(
     )
     (dq_t,) = _named.pallas_call(
         kernel,
+        label=params.label,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((hq, sqp, d), jnp.float32)],
         interpret=params.interpret,
@@ -1188,6 +1195,7 @@ def _ffa_bwd_dq_pallas_gqa(
     )
     (dq_g,) = _named.pallas_call(
         kernel,
+        label=params.label,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((hk, g, sqp, d), jnp.float32)],
         interpret=params.interpret,
@@ -1456,6 +1464,7 @@ def _ffa_bwd_dkv_pallas(
     )
     dk_t, dv_t = _named.pallas_call(
         kernel,
+        label=params.label,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, skp, d), jnp.float32),
@@ -1681,6 +1690,7 @@ def _ffa_bwd_dkv_pallas_gqa(
     )
     dk_t, dv_t = _named.pallas_call(
         kernel,
+        label=params.label,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, skp, d), jnp.float32),
@@ -1747,7 +1757,8 @@ def _delta_kernel(o_ref, do_ref, delta_ref, *, bq: int):
     delta_ref[0] = jnp.broadcast_to(col, (bq, NUM_LANES))
 
 
-def _ffa_delta_pallas(out_t, do_t, block_q: int, interpret: bool):
+def _ffa_delta_pallas(out_t, do_t, block_q: int, interpret: bool,
+                      label: str | None = None):
     """Tiled delta kernel over head-major padded (hq, sqp, dv) arrays.
 
     ``block_q`` must divide sqp (always true for the fwd padded geometry:
@@ -1758,6 +1769,7 @@ def _ffa_delta_pallas(out_t, do_t, block_q: int, interpret: bool):
     nqt = sqp // bq
     (delta_b,) = _named.pallas_call(
         partial(_delta_kernel, bq=bq),
+        label=label,
         grid=(hq, nqt),
         in_specs=[
             pl.BlockSpec((1, bq, dv), lambda h, i: (h, i, 0),
@@ -1782,7 +1794,8 @@ def ffa_delta_pallas_dispatch(params: FFAParams, out_t, do_t):
     """delta preprocessing entry used by every backward path (mirrors the
     fwd/dq/dkv dispatch naming so the static kernel checker drives it the
     same way)."""
-    return _ffa_delta_pallas(out_t, do_t, params.block_q, params.interpret)
+    return _ffa_delta_pallas(
+        out_t, do_t, params.block_q, params.interpret, params.label)
 
 
 # ---------------------------------------------------------------------------
@@ -2118,6 +2131,7 @@ def _ffa_bwd_fused_pallas(
     )
     dq_t, dk_t, dv_t = _named.pallas_call(
         kernel,
+        label=params.label,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hq, sqp, d), jnp.float32),
@@ -2379,6 +2393,7 @@ def _ffa_bwd_fused_pallas_gqa(
     )
     dq_g, dk_t, dv_t = _named.pallas_call(
         kernel,
+        label=params.label,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, g, sqp, d), jnp.float32),
@@ -2480,7 +2495,8 @@ def ffa_bwd_mode(
         meta_cols <= QVL
         or not fused_bwd_feasible(params, sqp, d, dv, itemsize)
     ):
-        return _registry.note_choice("ffa_bwd", key, "split", "guard").name
+        return _registry.note_choice(
+            "ffa_bwd", key, "split", "guard", label=params.label).name
     return _registry.resolve(
         "ffa_bwd",
         key,
@@ -2488,6 +2504,7 @@ def ffa_bwd_mode(
             *key[:7], dv, itemsize=itemsize, group=params.group
         ),
         pin=pin,
+        label=params.label,
     ).name
 
 
@@ -2963,7 +2980,8 @@ def note_tiles(
             for (tag, (bq, bk)), on in zip(passes, packed)
         )
     _registry_mod().note_choice(
-        "ffa_tiles", (sqp, d, dv, itemsize, params.group), name, source)
+        "ffa_tiles", (sqp, d, dv, itemsize, params.group), name, source,
+        label=params.label)
     return name
 
 

@@ -298,8 +298,10 @@ def _record_plan_telemetry(
     kr: np.ndarray,
     d_lo: np.ndarray,
     d_hi: np.ndarray,
+    label: str | None = None,
 ) -> FFAPlan:
-    """Gated per-build record: the padded grid work the kernel would execute
+    """Gated per-build record (with the runtime key's ``label``, if it has
+    one): the padded grid work the kernel would execute
     un-clamped, the post-clamp executed elements (live extents), and the
     true band area it needed — the estimated-vs-executed FLOP ratio at plan
     time (multiply elems by 4 * head_dim * num_heads_q for fwd FLOPs; the
@@ -332,6 +334,7 @@ def _record_plan_telemetry(
             executed_ratio=executed / band if band else 1.0,
             extent_clamp=ffa_extent_clamp(),
             frag_histogram=fragmentation_histogram(ratios),
+            **({} if label is None else {"label": label}),
         )
     return plan
 
@@ -359,8 +362,10 @@ def build_ffa_plan(
     seqlen_k: int,
     block_q: int,
     block_k: int,
+    label: str | None = None,
 ) -> FFAPlan:
-    """Build the work-item lists for the given band-slice metadata.
+    """Build the work-item lists for the given band-slice metadata
+    (``label``: the runtime key's, for the telemetry record alone).
 
     When ``MAGI_ATTENTION_RANGE_MERGE`` is on (default), band-compatible
     adjacent slices are merged first (mask_utils.merge_band_slices — the ref
@@ -415,7 +420,7 @@ def build_ffa_plan(
                     num_q_tiles=num_q_tiles, num_k_tiles=num_k_tiles,
                     block_q=block_q, block_k=block_k,
                 ),
-                q_ranges, k_ranges, d_lo, d_hi,
+                q_ranges, k_ranges, d_lo, d_hi, label,
             )
         except ImportError:
             if mode == "1":
@@ -518,7 +523,7 @@ def build_ffa_plan(
             block_q=block_q,
             block_k=block_k,
         ),
-        q_ranges, k_ranges, d_lo, d_hi,
+        q_ranges, k_ranges, d_lo, d_hi, label,
     )
 
 

@@ -17,6 +17,13 @@ Telemetry is told of every choice (one ``backend_select`` record per
 decision, key and choice) and is asked nothing: no file, no measured
 history and no earlier run decides a kernel.
 
+A call made for a labelled runtime key (``DistAttnRuntimeKey.label``: a
+model that attends under two masks a step labels them, say ``window`` and
+``full``) hands its label along: the choice is then also kept per label
+(``last_choice(decision, label=...)``, ``labelled_choices(decision)``) and
+the record carries it. The rule and the memo do not see the label: the same
+shapes get the same answer under any.
+
 Rank-ordered backend registrations double as the resilience ladders:
 ``ladder("serve_decode")`` is the decode fallback order and
 ``ladder("calc_attn")[-1]`` is the reference rung the resilience module
@@ -93,7 +100,8 @@ class BackendRegistry:
         self._lock = threading.Lock()
         self._memo: dict[tuple[str, Any], BackendChoice] = {}
         self._last: dict[str, tuple[Any, str]] = {}
-        self._announced: set[tuple[str, Any, str]] = set()
+        self._last_by_label: dict[tuple[str, str], str] = {}
+        self._announced: set[tuple[str, Any, str, str | None]] = set()
         self.stats: dict[str, int] = {
             "resolves": 0,
             "pins": 0,
@@ -101,15 +109,23 @@ class BackendRegistry:
             "heuristic_calls": 0,
         }
 
-    def _announce(self, decision: str, key: Any, choice: BackendChoice) -> None:
-        """One ``backend_select`` telemetry record per (decision, key,
-        choice) — selection provenance without per-step record spam."""
-        if not telemetry.enabled():
-            return
-        tag = (decision, _memo_key(key), choice.name)
+    def _settle(
+        self, decision: str, key: Any, choice: BackendChoice,
+        label: str | None, announce: bool = True,
+    ) -> BackendChoice:
+        """Keep ``choice`` as the decision's last (and its label's), and
+        tell telemetry once per (decision, key, choice, label): selection
+        provenance without per-step record spam."""
+        with self._lock:
+            self._last[decision] = (key, choice.name)
+            if label is not None:
+                self._last_by_label[decision, label] = choice.name
+        if not (announce and telemetry.enabled()):
+            return choice
+        tag = (decision, _memo_key(key), choice.name, label)
         with self._lock:
             if tag in self._announced:
-                return
+                return choice
             self._announced.add(tag)
         telemetry.record_event(
             "backend_select",
@@ -117,7 +133,9 @@ class BackendRegistry:
             key=list(key) if isinstance(key, tuple) else key,
             choice=choice.name,
             source=choice.source,
+            **({} if label is None else {"label": label}),
         )
+        return choice
 
     def resolve(
         self,
@@ -125,48 +143,49 @@ class BackendRegistry:
         key: Any,
         heuristic: Callable[[], str],
         pin: str | None = None,
+        label: str | None = None,
     ) -> BackendChoice:
         with self._lock:
             self.stats["resolves"] += 1
         if pin is not None:
-            choice = BackendChoice(pin, "pin")
             with self._lock:
                 self.stats["pins"] += 1
-                self._last[decision] = (key, pin)
-            self._announce(decision, key, choice)
-            return choice
+            return self._settle(
+                decision, key, BackendChoice(pin, "pin"), label)
 
         ck = (decision, _memo_key(key))
         with self._lock:
             hit = self._memo.get(ck)
             if hit is not None:
                 self.stats["memo_hits"] += 1
-                self._last[decision] = (key, hit.name)
-                return hit
+        if hit is not None:  # announced when it was made
+            return self._settle(
+                decision, key, hit, label, announce=label is not None)
 
         choice = BackendChoice(heuristic(), "heuristic")
         with self._lock:
             self.stats["heuristic_calls"] += 1
             self._memo[ck] = choice
-            self._last[decision] = (key, choice.name)
-        self._announce(decision, key, choice)
-        return choice
+        return self._settle(decision, key, choice, label)
 
     def note(
-        self, decision: str, key: Any, name: str, source: str
+        self, decision: str, key: Any, name: str, source: str,
+        label: str | None = None,
     ) -> BackendChoice:
         """Record a choice the call site computed itself (a rule over
         shapes, nothing to resolve against): ``last_choice`` and one
         ``backend_select`` record, no memo."""
-        choice = BackendChoice(name, source)
-        with self._lock:
-            self._last[decision] = (key, name)
-        self._announce(decision, key, choice)
-        return choice
+        return self._settle(
+            decision, key, BackendChoice(name, source), label)
 
     def last(self, decision: str) -> tuple[Any, str] | None:
         with self._lock:
             return self._last.get(decision)
+
+    def labelled(self, decision: str) -> dict[str, str]:
+        with self._lock:
+            return {label: name for (d, label), name
+                    in self._last_by_label.items() if d == decision}
 
 
 _registry: BackendRegistry | None = None
@@ -193,23 +212,36 @@ def resolve(
     key: Any,
     heuristic: Callable[[], str],
     pin: str | None = None,
+    label: str | None = None,
 ) -> BackendChoice:
-    return get_registry().resolve(decision, key, heuristic, pin=pin)
+    return get_registry().resolve(
+        decision, key, heuristic, pin=pin, label=label)
 
 
 def note_choice(
-    decision: str, key: Any, name: str, source: str
+    decision: str, key: Any, name: str, source: str,
+    label: str | None = None,
 ) -> BackendChoice:
-    return get_registry().note(decision, key, name, source)
+    return get_registry().note(decision, key, name, source, label=label)
 
 
 def stats() -> dict[str, int]:
     return dict(get_registry().stats)
 
 
-def last_choice(decision: str) -> str | None:
+def last_choice(decision: str, label: str | None = None) -> str | None:
+    """The decision's last choice in this process; with ``label`` the last
+    one made for a runtime key of that label."""
+    if label is not None:
+        return get_registry().labelled(decision).get(label)
     last = get_registry().last(decision)
     return None if last is None else last[1]
+
+
+def labelled_choices(decision: str) -> dict[str, str]:
+    """``{label: last choice}`` of the calls made for labelled runtime
+    keys; empty where no key carries a label."""
+    return get_registry().labelled(decision)
 
 
 # -- call-site conveniences (the env reads kernel code used to do) ----------

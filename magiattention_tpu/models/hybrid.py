@@ -1,26 +1,44 @@
-"""A decoder built from a layer pattern: Mamba-2, expert and attention mixers.
+"""A decoder built from a layer pattern: Mamba-2, expert, dense and attention
+mixers.
 
-The block builder of the hybrid families (``nemotron_h``): ``pattern`` is a
-string with one letter a block, and every block is ``x + mixer(RMSNorm(x))``
-with ONE mixer:
+The block builder of the hybrid families (``nemotron_h``, ``afmoe``):
+``pattern`` is a string with one letter a block, and every block is ``x +
+mixer(RMSNorm(x))`` with ONE mixer (``x + RMSNorm(mixer(RMSNorm(x)))`` where
+``post_norm`` is set: a norm of its own on the mixer's output):
 
 * ``M`` — a Mamba-2 mixer: ``in_proj`` to ``[z | x B C | dt]``, a causal
   depthwise convolution and SiLU over ``x B C``, the chunked state-space scan
   (``kernels/ssd.py``), ``D`` skip, a gated group RMSNorm, ``out_proj``. The
   scan's state and the convolution's taps stop at a document's first token;
 * ``E`` — this chip's share of a dropless expert layer with a shared expert
-  (``models/moe.py:dropless_moe_ffn``);
+  (``models/moe.py:dropless_moe_ffn``), the experts ``relu2`` (two matrices)
+  or ``swiglu`` (three) by ``expert_act``;
+* ``D`` — a dense SwiGLU MLP (``models/llama.py:swiglu_mlp``), ``dense_ffn``
+  wide;
 * ``*`` — attention through ``models/llama.py:attn_block`` (``calc_attn`` on
-  the dispatched layout), without a rotary embedding when ``rope_theta`` is
-  ``None``.
+  the dispatched layout) under the step's key;
+* ``W`` — the same block under the step's WINDOW key: a second runtime key,
+  made of the first after dispatch
+  (``api.make_varlen_key_for_new_mask_after_dispatch``), so that both kinds
+  of layer see one layout. A decoder whose layers alternate between a
+  sliding window and the full mask is ``W`` and ``*`` blocks in one
+  pattern, and its step takes both keys.
+
+An attention block of kind ``k`` rotates q and k where ``rope_theta`` is set
+and ``k`` is in ``rope_in`` (a family with a rotary embedding in its window
+layers only says ``rope_in="W"``); ``qk_norm`` and ``attn_gate`` give it
+the per-head q/k norms and the sigmoid output gate that ``attn_block``
+reads from the layer's leaves. A model layer of attention and then an MLP
+is two blocks (``W`` then ``D`` or ``E``).
 
 Everything else is the Llama family's, used and not copied:
-``embed_dispatched``, ``_rms_norm``, ``masked_ce``, ``_StepJit`` with
-``TPU_STEP_COMPILER_OPTIONS``; fp32 masters, bf16 activations, ``remat`` of
-a block, plain SGD. The documents' boundaries come from the runtime key
-(``api.get_document_starts``). At cp > 1 the scan's state would have to
-cross ``dispatch``'s chunk permutation, which nothing here does yet: the
-forward refuses such a key by name.
+``embed_dispatched`` (times ``embed_scale``), ``_rms_norm``, ``masked_ce``,
+``_StepJit`` with ``TPU_STEP_COMPILER_OPTIONS``; fp32 masters, bf16
+activations, ``remat`` of a block, plain SGD. The documents' boundaries come
+from the runtime key (``api.get_document_starts``). At cp > 1 the scan's
+state would have to cross ``dispatch``'s chunk permutation, which nothing
+here does yet: the forward of a pattern with an ``M`` refuses such a key by
+name.
 """
 
 from __future__ import annotations
@@ -32,7 +50,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..api import dispatch, get_document_starts, get_position_ids
+from ..api import (
+    dispatch,
+    get_document_starts,
+    get_position_ids,
+    same_dispatch,
+)
 from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
 from ..kernels import ssd
 from .llama import (
@@ -41,10 +64,12 @@ from .llama import (
     attn_block,
     embed_dispatched,
     masked_ce,
+    swiglu_mlp,
 )
-from .moe import ROUTES_SAVED, dropless_moe_ffn
+from .moe import EXPERT_ACTS, ROUTES_SAVED, dropless_moe_ffn
 
-MIXERS = ("M", "E", "*")
+MIXERS = ("M", "E", "D", "*", "W")
+ATTENTION = ("*", "W")
 
 
 @dataclass(frozen=True)
@@ -53,11 +78,18 @@ class HybridConfig:
     dim: int = 256
     pattern: str = "ME*"
     norm_eps: float = 1e-5
-    # '*': attention (the names attn_block reads)
+    post_norm: bool = False  # RMSNorm on every mixer's output as well
+    embed_scale: float | None = None  # the embedding times this
+    # '*', 'W': attention (the names attn_block reads)
     n_heads: int = 4
     n_kv_heads: int = 1
     head_dim: int = 64
     rope_theta: float | None = None
+    rope_in: str = "*W"  # the attention kinds that rotate, given a theta
+    qk_norm: bool = False  # RMSNorm over each head's channels of q and k
+    attn_gate: bool = False  # the output times sigmoid(h w_attn_gate)
+    # 'D': dense SwiGLU MLP
+    dense_ffn: int = 512
     # 'M': Mamba-2
     mamba_heads: int = 8
     mamba_head_dim: int = 64
@@ -77,6 +109,7 @@ class HybridConfig:
     expert_ffn: int = 128
     shared_ffn: int = 256
     routed_scale: float = 1.0
+    expert_act: str = "relu2"  # or "swiglu": gate and up side by side
     moe_token_block: int = 8192
     dtype: str = "bfloat16"
     remat: bool = False
@@ -86,6 +119,12 @@ class HybridConfig:
         if bad or not self.pattern:
             raise ValueError(
                 f"pattern {self.pattern!r}: one of {MIXERS} a block")
+        if set(self.rope_in) - set(ATTENTION):
+            raise ValueError(
+                f"rope_in {self.rope_in!r}: attention kinds, of {ATTENTION}")
+        if self.expert_act not in EXPERT_ACTS:
+            raise ValueError(
+                f"expert_act {self.expert_act!r}: one of {EXPERT_ACTS}")
         if self.chunk_size != ssd.CHUNK:
             raise ValueError(
                 f"chunk_size {self.chunk_size}: the scan kernel's chunk is "
@@ -140,30 +179,58 @@ def _init_mamba(cfg: HybridConfig, key) -> dict:
 def _init_experts(cfg: HybridConfig, key) -> dict:
     k = jax.random.split(key, 5)
     held, dim, f = cfg.experts_held, cfg.dim, cfg.expert_ffn
+    up = 2 if cfg.expert_act == "swiglu" else 1  # gate and up side by side
     return {
         "norm": jnp.ones((dim,), jnp.float32),
         "router": _dense(k[0], (dim, cfg.n_experts), dim),
         "e_bias": jnp.zeros((cfg.n_experts,), jnp.float32),
-        "w_up": _dense(k[1], (held, dim, f), dim),
+        "w_up": _dense(k[1], (held, dim, up * f), dim),
         "w_down": _dense(k[2], (held, f, dim), f),
-        "ws_up": _dense(k[3], (dim, cfg.shared_ffn), dim),
+        "ws_up": _dense(k[3], (dim, up * cfg.shared_ffn), dim),
         "ws_down": _dense(k[4], (cfg.shared_ffn, dim), cfg.shared_ffn),
+    }
+
+
+def _init_dense(cfg: HybridConfig, key) -> dict:
+    k = jax.random.split(key, 3)
+    dim, f = cfg.dim, cfg.dense_ffn
+    return {
+        "norm": jnp.ones((dim,), jnp.float32),
+        "w_gate": _dense(k[0], (dim, f), dim),
+        "w_up": _dense(k[1], (dim, f), dim),
+        "w_down": _dense(k[2], (f, dim), f),
     }
 
 
 def _init_attention(cfg: HybridConfig, key) -> dict:
     k = jax.random.split(key, 4)
     dim, dh = cfg.dim, cfg.head_dim
-    return {
+    lyr = {
         "attn_norm": jnp.ones((dim,), jnp.float32),
         "wq": _dense(k[0], (dim, cfg.n_heads * dh), dim),
         "wk": _dense(k[1], (dim, cfg.n_kv_heads * dh), dim),
         "wv": _dense(k[2], (dim, cfg.n_kv_heads * dh), dim),
         "wo": _dense(k[3], (cfg.n_heads * dh, dim), cfg.n_heads * dh),
     }
+    if cfg.qk_norm:
+        lyr["q_norm"] = jnp.ones((dh,), jnp.float32)
+        lyr["k_norm"] = jnp.ones((dh,), jnp.float32)
+    if cfg.attn_gate:  # a key of its own: the four above stay as they were
+        lyr["w_attn_gate"] = _dense(
+            jax.random.fold_in(key, 4), (dim, cfg.n_heads * dh), dim)
+    return lyr
 
 
-_INIT = {"M": _init_mamba, "E": _init_experts, "*": _init_attention}
+_INIT = {"M": _init_mamba, "E": _init_experts, "D": _init_dense,
+         "*": _init_attention, "W": _init_attention}
+
+
+def _init_block(cfg: HybridConfig, kind: str, key) -> dict:
+    lyr = _INIT[kind](cfg, key)
+    if cfg.post_norm:
+        lyr["attn_post_norm" if kind in ATTENTION else "post_norm"] = (
+            jnp.ones((cfg.dim,), jnp.float32))
+    return lyr
 
 
 def init_params(cfg: HybridConfig, key: jax.Array) -> dict:
@@ -176,7 +243,7 @@ def init_params(cfg: HybridConfig, key: jax.Array) -> dict:
         "final_norm": jnp.ones((cfg.dim,), jnp.float32),
         "lm_head": _dense(ks[1], (cfg.dim, cfg.vocab_size), cfg.dim),
         "layers": [
-            _INIT[kind](cfg, k) for kind, k in zip(cfg.pattern, ks[2:])],
+            _init_block(cfg, kind, k) for kind, k in zip(cfg.pattern, ks[2:])],
     }
 
 
@@ -241,36 +308,71 @@ def _refuse_cp(attn_key: DistAttnRuntimeKey) -> None:
             "(ROADMAP B1). Plan the key for a one-device cp axis.")
 
 
+def _check_window_key(cfg, attn_key, window_key) -> None:
+    if "W" not in cfg.pattern:
+        return
+    if window_key is None:
+        raise ValueError(
+            f"pattern {cfg.pattern!r} has window blocks ('W'): the step "
+            "takes their runtime key as window_key, made of attn_key by "
+            "api.make_varlen_key_for_new_mask_after_dispatch")
+    if not same_dispatch(attn_key, window_key):
+        raise ValueError(
+            "window_key lays the sequence out otherwise than attn_key: make "
+            "it of attn_key with api.make_*_key_for_new_mask_after_dispatch, "
+            "which reuses the dispatch")
+
+
 def forward(
     params: dict, cfg: HybridConfig, tokens: jax.Array,
     attn_key: DistAttnRuntimeKey, with_routes: bool = False,
+    window_key: DistAttnRuntimeKey | None = None,
 ):
     """Logits ``(total_seqlen, vocab)`` float32 in dispatched order (at cp 1
     natural order); with ``with_routes`` also each ``E`` block's routing,
     ``[{"topi", "scores", "group_rows"}]``
-    (:func:`~.moe.dropless_moe_ffn`)."""
-    _refuse_cp(attn_key)
+    (:func:`~.moe.dropless_moe_ffn`). ``attn_key`` owns the dispatch, the
+    positions and the documents' starts and is the ``*`` blocks' mask;
+    ``window_key`` is the ``W`` blocks'."""
+    _check_window_key(cfg, attn_key, window_key)
     dt = cfg.jdtype
-    x = embed_dispatched(params["embed"], tokens, attn_key, dt)
+    x = embed_dispatched(
+        params["embed"], tokens, attn_key, dt, scale=cfg.embed_scale)
     pos = get_position_ids(attn_key)
-    starts = get_document_starts(attn_key)
-    pos_in_doc, seg_rows = pos - starts, ssd.segment_rows(starts)
+    if "M" in cfg.pattern:
+        _refuse_cp(attn_key)
+        starts = get_document_starts(attn_key)
+        pos_in_doc, seg_rows = pos - starts, ssd.segment_rows(starts)
+
+    def joined(x, y, lyr):
+        if "post_norm" in lyr:
+            y = _rms_norm(y, lyr["post_norm"], cfg.norm_eps)
+        return x + y
 
     def mamba(x, lyr):
         h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
-        return x + mamba_mixer(h, lyr, cfg, pos_in_doc, seg_rows), None
+        return joined(
+            x, mamba_mixer(h, lyr, cfg, pos_in_doc, seg_rows), lyr), None
 
     def experts(x, lyr):
         h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
         y, routes = dropless_moe_ffn(
             h, lyr, top_k=cfg.top_k, scale=cfg.routed_scale,
-            expert_offset=cfg.expert_offset, token_block=cfg.moe_token_block)
-        return x + y, routes
+            expert_offset=cfg.expert_offset, token_block=cfg.moe_token_block,
+            act=cfg.expert_act)
+        return joined(x, y, lyr), routes
 
-    def attention(x, lyr):
-        return attn_block(x, lyr, cfg, pos, attn_key), None
+    def dense(x, lyr):
+        h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
+        return joined(x, swiglu_mlp(
+            h, lyr["w_gate"], lyr["w_up"], lyr["w_down"]), lyr), None
 
-    blocks = {"M": mamba, "E": experts, "*": attention}
+    def attention(kind, key):
+        return lambda x, lyr: (attn_block(
+            x, lyr, cfg, pos, key, rope=kind in cfg.rope_in), None)
+
+    blocks = {"M": mamba, "E": experts, "D": dense,
+              "*": attention("*", attn_key), "W": attention("W", window_key)}
     if cfg.remat:  # an E block's chosen experts are saved, never recomputed
         blocks = {kind: jax.checkpoint(fn, policy=ROUTES_SAVED)
                   for kind, fn in blocks.items()}
@@ -284,35 +386,44 @@ def forward(
     return (logits, routes) if with_routes else logits
 
 
-def loss_fn(params, cfg, tokens, labels, attn_key) -> jax.Array:
+def loss_fn(params, cfg, tokens, labels, attn_key, window_key=None):
     """Next-token cross entropy on the dispatched layout; the
-    configuration has no auxiliary routing loss."""
+    configurations have no auxiliary routing loss."""
     return masked_ce(
-        forward(params, cfg, tokens, attn_key), dispatch(labels, attn_key))
+        forward(params, cfg, tokens, attn_key, window_key=window_key),
+        dispatch(labels, attn_key))
 
 
-@partial(_StepJit, static_argnums=(1, 4), donate_argnums=(0,))
+@partial(_StepJit, static_argnums=(1, 4), static_argnames=("window_key",),
+         donate_argnums=(0,))
 def train_step(
     params: dict, cfg: HybridConfig, tokens: jax.Array, labels: jax.Array,
-    attn_key: DistAttnRuntimeKey, lr: float = 1e-4,
+    attn_key: DistAttnRuntimeKey, lr: float = 1e-4, *,
+    window_key: DistAttnRuntimeKey | None = None,
 ) -> tuple[dict, jax.Array]:
-    """One SGD step, as ``llama.train_step``."""
+    """One SGD step, as ``llama.train_step``. Both keys are static
+    arguments of the one program: the ``*`` blocks attend under
+    ``attn_key``, the ``W`` blocks under ``window_key``."""
     loss, grads = jax.value_and_grad(loss_fn)(
-        params, cfg, tokens, labels, attn_key)
+        params, cfg, tokens, labels, attn_key, window_key)
     params = jax.tree.map(
         lambda p, g: p - lr * g.astype(p.dtype), params, grads)
     return params, loss
 
 
-@partial(jax.jit, static_argnums=(1, 3))
-def routing_counters(params, cfg: HybridConfig, tokens, attn_key) -> dict:
+@partial(jax.jit, static_argnums=(1, 3), static_argnames=("window_key",))
+def routing_counters(
+    params, cfg: HybridConfig, tokens, attn_key, *, window_key=None,
+) -> dict:
     """What the expert layers of one forward did with ``tokens``, from the
     program's own routing, per ``E`` block: ``rows_routed`` ``(blocks,)``,
     the (token, choice) pairs whose chosen expert is one of those held;
     ``rows_per_expert`` ``(blocks, held)``, the rows the grouped product
     took for each held expert. A layer that drops no row has their sums
     equal."""
-    _, routes = forward(params, cfg, tokens, attn_key, with_routes=True)
+    _, routes = forward(
+        params, cfg, tokens, attn_key, with_routes=True,
+        window_key=window_key)
     local = jnp.stack([r["topi"] for r in routes]) - cfg.expert_offset
     return {
         "rows_routed": jnp.sum(

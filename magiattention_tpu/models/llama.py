@@ -129,22 +129,57 @@ def _rope(x, pos, theta):
     ).astype(x.dtype)
 
 
-def attn_block(x, lyr, cfg, pos, attn_key):
+def attn_block(x, lyr, cfg, pos, attn_key, rope: bool = True):
     """Pre-norm attention sub-block on the dispatched layout (shared by the
     Llama, MoE and hybrid families — ONE source of truth for
     qkv/rope/CP-attn/wo). ``cfg.rope_theta`` of ``None`` is a block without
-    a rotary embedding (``pos`` is then unused)."""
+    a rotary embedding (``pos`` is then unused), and so is ``rope=False``:
+    a model whose layers differ in it says so per block.
+
+    What else a family's block has it brings as leaves of ``lyr``, and a
+    layer without them traces to the plain block:
+
+    * ``q_norm``, ``k_norm`` ``(head_dim,)`` — RMSNorm over each head's
+      channels of q and k, one weight for all the heads, before the rotary
+      embedding;
+    * ``w_attn_gate`` ``(dim, n_heads * head_dim)`` — the attention's output
+      times ``sigmoid(h @ w_attn_gate)``, ``h`` the block's normed input,
+      before ``wo`` (one gate a head channel; the product in float32,
+      rounded once);
+    * ``attn_post_norm`` ``(dim,)`` — RMSNorm of the sub-block's output
+      before it joins the residual stream.
+    """
     dt = x.dtype
     h = _rms_norm(x, lyr["attn_norm"], cfg.norm_eps)
     q = (h @ lyr["wq"].astype(dt)).reshape(-1, cfg.n_heads, cfg.head_dim)
     k = (h @ lyr["wk"].astype(dt)).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
     v = (h @ lyr["wv"].astype(dt)).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-    if cfg.rope_theta is not None:
+    if "q_norm" in lyr:
+        q = _rms_norm(q, lyr["q_norm"], cfg.norm_eps)
+        k = _rms_norm(k, lyr["k_norm"], cfg.norm_eps)
+    if rope and cfg.rope_theta is not None:
         q = _rope(q, pos, cfg.rope_theta)
         k = _rope(k, pos, cfg.rope_theta)
     attn_out, _ = calc_attn(q, k, v, attn_key)
     attn_out = attn_out.reshape(-1, cfg.n_heads * cfg.head_dim)
-    return x + attn_out @ lyr["wo"].astype(dt)
+    if "w_attn_gate" in lyr:
+        gate = jax.nn.sigmoid(jnp.dot(
+            h, lyr["w_attn_gate"].astype(dt),
+            preferred_element_type=jnp.float32))
+        attn_out = (attn_out.astype(jnp.float32) * gate).astype(dt)
+    y = attn_out @ lyr["wo"].astype(dt)
+    if "attn_post_norm" in lyr:
+        y = _rms_norm(y, lyr["attn_post_norm"], cfg.norm_eps)
+    return x + y
+
+
+def swiglu_mlp(h, w_gate, w_up, w_down):
+    """``(silu(h w_gate) * (h w_up)) w_down`` in ``h``'s type: the Llama
+    block's MLP, and the hybrid builder's dense block."""
+    dt = h.dtype
+    gate = jax.nn.silu(h @ w_gate.astype(dt))
+    up = h @ w_up.astype(dt)
+    return (gate * up) @ w_down.astype(dt)
 
 
 def masked_ce(logits, labels):
@@ -160,7 +195,7 @@ def masked_ce(logits, labels):
     )
 
 
-def embed_dispatched(embed, tokens, attn_key, dtype):
+def embed_dispatched(embed, tokens, attn_key, dtype, scale=None):
     """Rows of ``embed`` for ``tokens`` (natural order) in DISPATCHED order
     (shared by the Llama and MoE families — ONE source of truth for the way
     in): the ids are dispatched, then looked up, so nothing ``dim`` wide
@@ -168,8 +203,13 @@ def embed_dispatched(embed, tokens, attn_key, dtype):
     ``dispatch(jnp.take(embed, tokens, axis=0).astype(dtype), attn_key)``,
     which at cp > 1 builds all ``total_seqlen`` rows on every chip and
     all-reduces them each step. The lookup stays float32, then the cast:
-    the backward's scatter-add into the table is float32."""
-    return jnp.take(embed, dispatch(tokens, attn_key), axis=0).astype(dtype)
+    the backward's scatter-add into the table is float32. ``scale`` (a
+    model whose embedding is multiplied by ``sqrt(dim)``) is applied to the
+    float32 rows, before the cast."""
+    rows = jnp.take(embed, dispatch(tokens, attn_key), axis=0)
+    if scale is not None:
+        rows = rows * scale
+    return rows.astype(dtype)
 
 
 def forward(
@@ -195,9 +235,7 @@ def forward(
     def layer(x, lyr):
         x = attn_block(x, lyr, cfg, pos, attn_key)
         h = _rms_norm(x, lyr["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(h @ lyr["w_gate"].astype(dt))
-        up = h @ lyr["w_up"].astype(dt)
-        return x + (gate * up) @ lyr["w_down"].astype(dt)
+        return x + swiglu_mlp(h, lyr["w_gate"], lyr["w_up"], lyr["w_down"])
 
     if cfg.remat:
         layer = jax.checkpoint(layer)

@@ -331,8 +331,22 @@ def held_expert_rows(topi, held: int, expert_offset: int = 0):
     return jnp.zeros((held + 1,), jnp.int32).at[ids.reshape(-1)].add(1)[:held]
 
 
+EXPERT_ACTS = ("relu2", "swiglu")
+
+
+def _expert_act(up, act: str):
+    """An expert's activation on the float32 result of its up product,
+    taken before the rounding: ``relu(up)^2``, or for ``swiglu``, whose up
+    product is gate and up side by side ``(.., 2 ffn)``, ``silu(gate) *
+    up``."""
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(up))
+    gate, up = jnp.split(up, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
 def _held_experts_block(h, topi, weights, w_up, w_down, offset: int,
-                        tile_rows: int):
+                        tile_rows: int, act: str):
     """The held experts' part of the layer for one block of tokens: every
     (token, choice) pair is a row; rows are sorted by local expert id, the
     pairs of experts held elsewhere last, past the groups, where the grouped
@@ -352,9 +366,9 @@ def _held_experts_block(h, topi, weights, w_up, w_down, offset: int,
         live, _permute_rows(jnp.repeat(h, k, axis=0), order, inverse), 0)
     note_tile_stats(sizes, tile_rows)
     grouped = partial(grouped_matmul, group_sizes=sizes, tile_rows=tile_rows)
-    up = grouped(rows, w_up)  # float32: relu^2 is taken before the rounding
-    act = jnp.where(live, jnp.square(jax.nn.relu(up)), 0).astype(h.dtype)
-    out = jnp.where(live, grouped(act, w_down, out_dtype=h.dtype), 0)
+    up = grouped(rows, w_up)  # float32: the activation before the rounding
+    inner = jnp.where(live, _expert_act(up, act), 0).astype(h.dtype)
+    out = jnp.where(live, grouped(inner, w_down, out_dtype=h.dtype), 0)
     back = _permute_rows(out, inverse, order).reshape(sb, k, -1)
     gate = jnp.where(mine, weights, 0.0).astype(h.dtype)
     return jnp.einsum(
@@ -364,7 +378,7 @@ def _held_experts_block(h, topi, weights, w_up, w_down, offset: int,
 
 def dropless_moe_ffn(
     h, lyr, *, top_k: int, scale: float, expert_offset: int = 0,
-    token_block: int = 8192,
+    token_block: int = 8192, act: str = "relu2",
 ):
     """One chip's share of an expert layer that drops no token.
 
@@ -381,6 +395,12 @@ def dropless_moe_ffn(
     blocks of ``token_block`` (each block rematerialised in the backward),
     which bounds the worst-case row buffer.
 
+    ``act="swiglu"`` is the gated form, three matrices an expert: ``w_up``
+    ``(held, dim, 2 ffn)`` holds each expert's gate and up projections side
+    by side, so that both are ONE grouped product, and the expert is
+    ``w_down (silu(gate) * up)``, the activation taken of the float32
+    product before the rounding; ``ws_up`` ``(dim, 2 shared_ffn)`` likewise.
+
     Under ``jax.checkpoint`` the chosen ids must be SAVED, not recomputed
     (``policy=ROUTES_SAVED``): the recomputed forward is another XLA
     program region, free to keep a bf16 value in float32, and a score that
@@ -391,6 +411,8 @@ def dropless_moe_ffn(
     router's scores (S, n_experts) float32, "group_rows": the rows the
     grouped product took for each held expert (held,)})``.
     """
+    if act not in EXPERT_ACTS:
+        raise ValueError(f"act {act!r}: one of {EXPERT_ACTS}")
     dt = h.dtype
     s, dim = h.shape
     topi, weights, scores = route_sigmoid_topk(
@@ -407,11 +429,16 @@ def dropless_moe_ffn(
         "moe_grouped_tiles", key, f"rows{tile_rows}", "shape_rule")
     block = jax.checkpoint(
         lambda args: _held_experts_block(
-            *args, w_up, w_down, expert_offset, tile_rows))
+            *args, w_up, w_down, expert_offset, tile_rows, act))
     routed, sizes = jax.lax.map(block, tuple(
         v.reshape(s // sb, sb, -1) for v in (h, topi, weights)))
-    shared = jnp.square(jax.nn.relu(h @ lyr["ws_up"].astype(dt))) @ (
-        lyr["ws_down"].astype(dt))
+    if act == "relu2":
+        shared = jnp.square(jax.nn.relu(h @ lyr["ws_up"].astype(dt))) @ (
+            lyr["ws_down"].astype(dt))
+    else:
+        shared = _expert_act(jnp.dot(
+            h, lyr["ws_up"].astype(dt), preferred_element_type=jnp.float32),
+            act).astype(dt) @ lyr["ws_down"].astype(dt)
     return routed.reshape(s, dim) + shared, {
         "topi": topi, "scores": scores, "group_rows": jnp.sum(sizes, axis=0)}
 
