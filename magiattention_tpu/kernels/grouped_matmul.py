@@ -114,22 +114,32 @@ def tile_stats(group_sizes, tile_rows: int):
     return visits, float(sizes.sum() / (visits * tile_rows)) if visits else 1.0
 
 
-def _record_tile_stats(group_sizes, *, tile_rows: int) -> None:
+def _record_tile_stats(
+    group_sizes, *, tile_rows: int, row_buffer: int | None
+) -> None:
     visits, fill = tile_stats(group_sizes, tile_rows)
+    live_rows = int(np.sum(group_sizes))
+    sized = {} if row_buffer is None else {
+        "row_buffer": row_buffer, "fitted": live_rows <= row_buffer}
     telemetry.record_event(
         "grouped_matmul_plan", groups=int(np.size(group_sizes)),
-        live_rows=int(np.sum(group_sizes)), tile_rows=tile_rows,
-        tile_visits=visits, tile_fill=fill)
+        live_rows=live_rows, tile_rows=tile_rows,
+        tile_visits=visits, tile_fill=fill, **sized)
 
 
-def note_tile_stats(group_sizes, tile_rows: int) -> None:
+def note_tile_stats(
+    group_sizes, tile_rows: int, row_buffer: int | None = None
+) -> None:
     """Tell telemetry the :func:`tile_stats` of one plan (the sizes one
-    block of rows was sorted into; every product of the block shares them).
+    block of rows was sorted into; every product of the block shares them)
+    and, where the caller sized a ``row_buffer`` by what it expected
+    (``models/moe.py``), that size and whether the rows ``fitted`` it.
     Gated: with telemetry off nothing is traced into the program. It
     observes and steers nothing."""
     if telemetry.enabled():
         jax.debug.callback(
-            partial(_record_tile_stats, tile_rows=tile_rows), group_sizes)
+            partial(_record_tile_stats, tile_rows=tile_rows,
+                    row_buffer=row_buffer), group_sizes)
 
 
 def _own_rows(group_ref, tile_ref, span_ref, v, tile_rows: int):

@@ -371,7 +371,8 @@ register_backend(
     "rows sorted by expert, a Pallas grouped matmul over the live row "
     "tiles of the experts held, forward, d rows and dW "
     "(kernels/grouped_matmul.py; the row tile, tile_policy."
-    "grouped_row_tile, is noted under moe_grouped_tiles)")
+    "grouped_row_tile, is noted under moe_grouped_tiles; the row buffer, "
+    "tile_policy.grouped_row_capacity, under moe_row_buffer)")
 register_backend(
     "nsa_slc", "block_sparse_pallas", 0,
     "gather-free Pallas block-sparse slc kernel")
@@ -402,4 +403,5 @@ PIN_KEYS: dict[str, tuple[str, ...]] = {
     "ssd": (),
     "moe_grouped": (),
     "moe_grouped_tiles": (),
+    "moe_row_buffer": (),
 }

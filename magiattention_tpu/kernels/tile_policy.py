@@ -790,6 +790,26 @@ def grouped_row_tile(rows_per_group: int) -> int:
     return GROUPED_ROW_TILES[-1]
 
 
+# how far above the rows a block of tokens EXPECTS for the experts held its
+# row buffer reaches: a block whose live rows pass it runs at the worst case
+# (models/moe.py), so the margin buys how rarely that happens with the size
+# of every pass over the buffer
+GROUPED_ROW_MARGIN = 1.5
+
+
+def grouped_row_capacity(
+    expected_rows: float, worst_rows: int, tile_rows: int
+) -> int:
+    """Rows of the buffer a block of tokens sorts its (token, choice) pairs
+    into: ``GROUPED_ROW_MARGIN`` times the rows it expects for the experts
+    held (``tokens x top_k x held / n_experts``), rounded up to whole row
+    tiles, never above the worst case ``tokens x top_k``. A chip that holds
+    every expert expects the worst case and gets it."""
+    rows = _round_up(
+        int(np.ceil(expected_rows * GROUPED_ROW_MARGIN)), tile_rows)
+    return min(rows, worst_rows)
+
+
 def grouped_weight_k_minor(k: int, n: int) -> bool:
     """Whether the bodies are given each group's weight ``[K, N]`` as its
     transpose ``[N, K]``: where ``N`` is no multiple of the lanes and ``K``

@@ -330,7 +330,7 @@ def forward(
 ):
     """Logits ``(total_seqlen, vocab)`` float32 in dispatched order (at cp 1
     natural order); with ``with_routes`` also each ``E`` block's routing,
-    ``[{"topi", "scores", "group_rows"}]``
+    ``[{"topi", "scores", "group_rows", "block_rows", "blocks_fitted"}]``
     (:func:`~.moe.dropless_moe_ffn`). ``attn_key`` owns the dispatch, the
     positions and the documents' starts and is the ``*`` blocks' mask;
     ``window_key`` is the ``W`` blocks'."""
@@ -420,7 +420,10 @@ def routing_counters(
     the (token, choice) pairs whose chosen expert is one of those held;
     ``rows_per_expert`` ``(blocks, held)``, the rows the grouped product
     took for each held expert. A layer that drops no row has their sums
-    equal."""
+    equal. ``block_rows`` ``(blocks, token blocks)``, the rows each block of
+    tokens had for the experts held, and ``blocks_fitted`` ``(blocks,)``, how
+    many of a layer's token blocks fitted the row buffer sized by what a
+    block expects (the others ran at the worst case)."""
     _, routes = forward(
         params, cfg, tokens, attn_key, with_routes=True,
         window_key=window_key)
@@ -429,4 +432,6 @@ def routing_counters(
         "rows_routed": jnp.sum(
             (local >= 0) & (local < cfg.experts_held), axis=(1, 2)),
         "rows_per_expert": jnp.stack([r["group_rows"] for r in routes]),
+        "block_rows": jnp.stack([r["block_rows"] for r in routes]),
+        "blocks_fitted": jnp.stack([r["blocks_fitted"] for r in routes]),
     }

@@ -345,35 +345,111 @@ def _expert_act(up, act: str):
     return jax.nn.silu(gate) * up
 
 
-def _held_experts_block(h, topi, weights, w_up, w_down, offset: int,
-                        tile_rows: int, act: str):
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _token_rows(h, pairs, slot, k: int):
+    """``h[pairs // k]``: the tokens' rows of the (token, choice) pairs
+    ``pairs`` (the head of the sorted order), without a copy of ``h`` a
+    choice. ``slot`` is the place of every pair in the sorted order, so the
+    backward is a gather too: a pair takes ``g[slot]`` where its slot is one
+    of ``g``'s rows, and a token sums its ``k`` pairs."""
+    return h[pairs // k]
+
+
+def _token_rows_fwd(h, pairs, slot, k):
+    return h[pairs // k], slot
+
+
+def _token_rows_bwd(k, slot, g):
+    rows = g.shape[0]
+    by_pair = g[jnp.minimum(slot, rows - 1)]
+    if rows < slot.shape[0]:  # a buffer below the worst case: pairs past it
+        by_pair = jnp.where((slot < rows)[:, None], by_pair, 0)
+    # what a copy of ``h`` a choice would have had for its transpose
+    (dh,) = jax.linear_transpose(
+        partial(jnp.repeat, repeats=k, axis=0),
+        jax.ShapeDtypeStruct((slot.shape[0] // k, g.shape[-1]), g.dtype),
+    )(by_pair)
+    return dh, None, None
+
+
+_token_rows.defvjp(_token_rows_fwd, _token_rows_bwd)
+
+
+def _held_experts_block(h, topi, weights, w_up, w_down, sizes, *,
+                        offset: int, tile_rows: int, act: str, capacity: int):
     """The held experts' part of the layer for one block of tokens: every
     (token, choice) pair is a row; rows are sorted by local expert id, the
     pairs of experts held elsewhere last, past the groups, where the grouped
-    product computes nothing. The buffer is the block's worst case
-    (``tokens x top_k`` rows), so no row is ever dropped. Returns ``(the
-    block's output, the group sizes the grouped product was given)``."""
-    (sb, k), held = topi.shape, w_up.shape[0]
-    mine, gid = _local_expert_ids(topi, held, offset)
+    product computes nothing. The buffer in expert order holds the first
+    ``capacity`` rows of that order: the caller gives ``tokens x top_k``,
+    the worst case, or a smaller size to a block whose ``sizes`` (the group
+    sizes the grouped product is given, :func:`held_expert_rows`) sum to no
+    more, so no row is ever dropped. The way back is indexed by (token,
+    choice): a pair past the buffer reads its last row and weighs it 0."""
+    sb, k = topi.shape
+    mine, gid = _local_expert_ids(topi, w_up.shape[0], offset)
     order = jnp.argsort(gid.reshape(-1), stable=True)
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(sb * k, dtype=order.dtype))
-    sizes = held_expert_rows(topi, held, offset)
-    live = (jnp.arange(sb * k) < jnp.sum(sizes))[:, None]
+    head = order[:capacity]
+    live = (jnp.arange(capacity) < jnp.sum(sizes))[:, None]
     # past the groups the grouped product writes nothing, forward or
     # backward: what it leaves there is masked on the way in and out
-    rows = jnp.where(
-        live, _permute_rows(jnp.repeat(h, k, axis=0), order, inverse), 0)
-    note_tile_stats(sizes, tile_rows)
+    rows = jnp.where(live, _token_rows(h, head, inverse, k), 0)
     grouped = partial(grouped_matmul, group_sizes=sizes, tile_rows=tile_rows)
     up = grouped(rows, w_up)  # float32: the activation before the rounding
     inner = jnp.where(live, _expert_act(up, act), 0).astype(h.dtype)
     out = jnp.where(live, grouped(inner, w_down, out_dtype=h.dtype), 0)
-    back = _permute_rows(out, inverse, order).reshape(sb, k, -1)
+    back = _permute_rows(
+        out, jnp.minimum(inverse, capacity - 1), head).reshape(sb, k, -1)
     gate = jnp.where(mine, weights, 0.0).astype(h.dtype)
     return jnp.einsum(
         "sk,skd->sd", gate, back, preferred_element_type=jnp.float32
-    ).astype(h.dtype), sizes
+    ).astype(h.dtype)
+
+
+def _tiered_experts_block(capacity: int, worst: int, **static):
+    """:func:`_held_experts_block` at ``capacity`` rows for a block of
+    tokens whose ``sizes`` fit, at ``worst`` (``tokens x top_k``) for one
+    whose do not (and for every block, without a branch, where ``capacity``
+    is the worst case). Rematerialised: the residuals are the block's
+    inputs, and the backward makes the forward again at the size the
+    forward ran at. A ``custom_vjp`` and not ``jax.checkpoint`` round a
+    ``lax.cond``, whose branches would hand the backward each other's
+    residuals, zero-filled and worst-case-sized: here both ``cond``s carry
+    block-shaped values only."""
+
+    def at(rows: int):
+        return partial(_held_experts_block, capacity=rows, **static)
+
+    def grads_at(rows: int):
+        def grads(dy, h, topi, weights, w_up, w_down, sizes):
+            return jax.vjp(
+                lambda h, weights, w_up, w_down: at(rows)(
+                    h, topi, weights, w_up, w_down, sizes),
+                h, weights, w_up, w_down)[1](dy)
+        return grads
+
+    def tiered(fn_at, *args):
+        if capacity >= worst:
+            return fn_at(worst)(*args)
+        return jax.lax.cond(  # args[-1]: the block's sizes
+            jnp.sum(args[-1]) <= capacity, fn_at(capacity), fn_at(worst),
+            *args)
+
+    @jax.custom_vjp
+    def block(h, topi, weights, w_up, w_down, sizes):
+        return tiered(at, h, topi, weights, w_up, w_down, sizes)
+
+    def block_fwd(*args):
+        return tiered(at, *args), args
+
+    def block_bwd(args, dy):
+        dh, dweights, dw_up, dw_down = tiered(grads_at, dy, *args)
+        return dh, None, dweights, dw_up, dw_down, None
+
+    block.defvjp(block_fwd, block_bwd)
+    return block
 
 
 def dropless_moe_ffn(
@@ -392,8 +468,17 @@ def dropless_moe_ffn(
     held elsewhere would have added: on one chip the layer runs without its
     exchange, and summing the routed parts of all the shares with the
     shared expert counted once gives the whole layer. Tokens go through in
-    blocks of ``token_block`` (each block rematerialised in the backward),
-    which bounds the worst-case row buffer.
+    blocks of ``token_block`` (each block rematerialised in the backward).
+
+    **The row buffer.** A block sorts its ``token_block x top_k`` (token,
+    choice) pairs by expert and runs the experts held on the head of that
+    order. The buffer is sized by what a block EXPECTS for the experts held
+    (``tile_policy.grouped_row_capacity``: ``held / n_experts`` of the
+    pairs, times a margin, in whole row tiles); a block counts the rows it
+    got, and one whose rows do not fit runs the same code at the worst
+    case, ``token_block x top_k`` rows, so no row is ever dropped and
+    nothing is discarded to a capacity. A chip that holds every expert
+    expects the worst case: one size, no branch.
 
     ``act="swiglu"`` is the gated form, three matrices an expert: ``w_up``
     ``(held, dim, 2 ffn)`` holds each expert's gate and up projections side
@@ -409,7 +494,9 @@ def dropless_moe_ffn(
 
     Returns ``(y (S, dim), {"topi": chosen expert ids (S, K), "scores": the
     router's scores (S, n_experts) float32, "group_rows": the rows the
-    grouped product took for each held expert (held,)})``.
+    grouped product took for each held expert (held,), "block_rows": the
+    rows each block of tokens had for the experts held (blocks,),
+    "blocks_fitted": how many of them fitted the expected buffer})``.
     """
     if act not in EXPERT_ACTS:
         raise ValueError(f"act {act!r}: one of {EXPERT_ACTS}")
@@ -419,18 +506,29 @@ def dropless_moe_ffn(
         h, lyr["router"], lyr["e_bias"], top_k, scale)
     w_up, w_down = lyr["w_up"].astype(dt), lyr["w_down"].astype(dt)
     sb = token_block if s % token_block == 0 else s
+    held, n_experts = w_up.shape[0], lyr["router"].shape[-1]
     # the rows an expert expects of a block are known here, the sizes it
-    # gets are not: the row tile is the rule's for that expectation
-    tile_rows = tile_policy.grouped_row_tile(
-        sb * top_k // lyr["router"].shape[-1])
+    # gets are not: the row tile and the buffer are the rules' for that
+    # expectation
+    worst = sb * top_k  # a block's (token, choice) pairs
+    tile_rows = tile_policy.grouped_row_tile(worst // n_experts)
+    capacity = tile_policy.grouped_row_capacity(
+        worst * held / n_experts, worst, tile_rows)
     key = (s, dim, *w_up.shape, top_k)
     registry.note_choice("moe_grouped", key, "pallas_grouped", "default")
     registry.note_choice(
         "moe_grouped_tiles", key, f"rows{tile_rows}", "shape_rule")
-    block = jax.checkpoint(
-        lambda args: _held_experts_block(
-            *args, w_up, w_down, expert_offset, tile_rows, act))
-    routed, sizes = jax.lax.map(block, tuple(
+    registry.note_choice(
+        "moe_row_buffer", key, f"rows{capacity}of{worst}", "shape_rule")
+    block = _tiered_experts_block(
+        capacity, worst, offset=expert_offset, tile_rows=tile_rows, act=act)
+
+    def one_block(args):
+        sizes = held_expert_rows(args[1], held, expert_offset)
+        note_tile_stats(sizes, tile_rows, row_buffer=capacity)
+        return block(*args, w_up, w_down, sizes), sizes
+
+    routed, sizes = jax.lax.map(one_block, tuple(
         v.reshape(s // sb, sb, -1) for v in (h, topi, weights)))
     if act == "relu2":
         shared = jnp.square(jax.nn.relu(h @ lyr["ws_up"].astype(dt))) @ (
@@ -439,8 +537,11 @@ def dropless_moe_ffn(
         shared = _expert_act(jnp.dot(
             h, lyr["ws_up"].astype(dt), preferred_element_type=jnp.float32),
             act).astype(dt) @ lyr["ws_down"].astype(dt)
+    block_rows = jnp.sum(sizes, axis=1)
     return routed.reshape(s, dim) + shared, {
-        "topi": topi, "scores": scores, "group_rows": jnp.sum(sizes, axis=0)}
+        "topi": topi, "scores": scores, "group_rows": jnp.sum(sizes, axis=0),
+        "block_rows": block_rows,
+        "blocks_fitted": jnp.sum(block_rows <= capacity)}
 
 
 # ---------------------------------------------------------------------------

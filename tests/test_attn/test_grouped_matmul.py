@@ -188,6 +188,32 @@ def test_the_tiles_are_rules_over_static_shapes():
     assert tile_policy.grouped_dw_tiles(64, 32, 2) == (64, 32)
 
 
+@pytest.mark.parametrize("tokens, top_k, held, n_experts, want", [
+    (8192, 8, 32, 128, 24576),   # trinitymini.longdocs32k.cp1: of 65536
+    (8192, 6, 32, 128, 18432),   # nemotron3nano.packed32k.cp1: of 49152
+    (8192, 6, 128, 128, 49152),  # every expert held: the worst case
+    (8192, 6, 96, 128, 49152),   # a margin that reaches it
+    (256, 6, 8, 32, 576),        # the toy's: 384 expected, 16-row tiles
+    (100, 6, 8, 32, 240),        # 150 expected -> 225 -> whole 16-row tiles
+    (10, 3, 1, 8, 16),           # 3.75 expected -> 6 -> one tile
+    (4, 3, 1, 8, 12),            # one tile is more than the worst case
+])
+def test_the_row_buffer_is_a_rule_over_what_a_block_expects(
+        tokens, top_k, held, n_experts, want):
+    """``GROUPED_ROW_MARGIN`` (1.5) times the pairs expected for the experts
+    held, in whole row tiles, never above ``tokens x top_k``."""
+    worst = tokens * top_k
+    tile = tile_policy.grouped_row_tile(worst // n_experts)
+    expected = worst * held / n_experts
+    got = tile_policy.grouped_row_capacity(expected, worst, tile)
+    assert got == want
+    assert expected <= got <= worst
+    assert got == worst or got % tile == 0
+    assert (got == worst) == (
+        held == n_experts or expected * tile_policy.GROUPED_ROW_MARGIN
+        > worst - tile)
+
+
 def test_the_default_row_tile_is_the_rules_for_an_even_share():
     """Without ``tile_rows`` the rows a group expects are ``M / G``."""
     rows = jnp.ones((96, 64), jnp.bfloat16)
@@ -207,27 +233,38 @@ def test_operands_that_make_no_grouped_product_are_refused():
 def test_the_registry_knows_one_backend_and_no_pin():
     assert registry.backends_for("moe_grouped") == ("pallas_grouped",)
     assert registry.PIN_KEYS["moe_grouped"] == ()
+    assert registry.PIN_KEYS["moe_row_buffer"] == ()  # a rule, no key
 
 
+@pytest.mark.parametrize("row_buffer, sized", [
+    (None, {}),
+    (48, {"row_buffer": 48, "fitted": True}),
+    (37, {"row_buffer": 37, "fitted": True}),
+    (36, {"row_buffer": 36, "fitted": False}),
+])
 def test_telemetry_is_told_a_plans_tile_stats_and_only_when_on(
-        monkeypatch, tmp_path):
+        monkeypatch, tmp_path, row_buffer, sized):
     """Gated and observing: with telemetry off nothing is traced into the
-    program; on, one ``grouped_matmul_plan`` record a plan."""
+    program; on, one ``grouped_matmul_plan`` record a plan, with the row
+    buffer the caller sized and whether the plan's rows fitted it."""
     from magiattention_tpu import telemetry
 
     sizes = jnp.asarray((10, 22, 5), jnp.int32)
-    note = jax.jit(lambda s: (gm.note_tile_stats(s, 16), s.sum())[1])
+    note = jax.jit(
+        lambda s: (gm.note_tile_stats(s, 16, row_buffer), s.sum())[1])
     monkeypatch.delenv("MAGI_ATTENTION_TELEMETRY", raising=False)
     assert "callback" not in str(jax.make_jaxpr(note)(sizes))
     monkeypatch.setenv("MAGI_ATTENTION_TELEMETRY", "1")
     monkeypatch.setenv("MAGI_ATTENTION_TELEMETRY_DIR", str(tmp_path))
     telemetry.reset()
     try:
-        jax.block_until_ready(jax.jit(
-            lambda s: (gm.note_tile_stats(s, 16), s.sum())[1])(sizes))
+        jax.block_until_ready(jax.jit(lambda s: (
+            gm.note_tile_stats(s, 16, row_buffer), s.sum())[1])(sizes))
         jax.effects_barrier()
         record = telemetry.get_collector().last_event["grouped_matmul_plan"]
     finally:
         telemetry.reset()
     assert (record["tile_visits"], record["live_rows"]) == (4, 37)
     assert record["tile_fill"] == pytest.approx(37 / 64)
+    assert {k: record.get(k) for k in ("row_buffer", "fitted")} == {
+        "row_buffer": None, "fitted": None, **sized}

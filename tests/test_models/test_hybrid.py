@@ -154,6 +154,16 @@ def test_routing_counters(toy):
         counted["rows_per_expert"].sum(axis=-1), counted["rows_routed"])
     # 6 of 32 picked, 8 held: a quarter of the choices, give or take
     assert 0.15 < counted["rows_routed"].sum() / (blocks * 256 * 6) < 0.35
+    # a block of tokens' rows, and how many blocks fitted the buffer sized
+    # by that quarter (the rule's 576 of 1536 pairs): all of them
+    token_blocks = 256 // min(mcfg.moe_token_block, 256)
+    assert counted["block_rows"].shape == (blocks, token_blocks)
+    np.testing.assert_array_equal(
+        counted["block_rows"].sum(axis=-1), counted["rows_routed"])
+    assert registry.last_choice("moe_row_buffer") == (
+        f"rows{576 // token_blocks}of{1536 // token_blocks}")
+    np.testing.assert_array_equal(
+        counted["blocks_fitted"], [token_blocks] * blocks)
 
 
 def test_routing_counters_see_a_grouped_product_that_takes_fewer_rows(

@@ -175,7 +175,12 @@ def test_the_hybrid_step_compiles_for_a_v5e_chip_and_fits_it(
     # an expert layer's products: up and down in the forward's loop over
     # token blocks; in the backward's, a block's re-forward of both, d act
     # and d rows, and the two dW (the layer's own re-forward saves nothing
-    # the blocks' does not, and XLA drops it)
-    assert sum("magi_ragged_dot_kernel" in n for n in names) == 4 * (2 + 4)
-    assert sum("magi_ragged_dot_dw_kernel" in n for n in names) == 4 * 2
+    # the blocks' does not, and XLA drops it). Each stands in the program
+    # twice since PR 34, at the row buffer a block expects and at the worst
+    # case, the two branches of a block's ``conditional``, of which one
+    # runs: the calls a step makes are the 96 + 32 of before
+    assert registry.last_choice("moe_row_buffer") == "rows18432of49152"
+    assert len(re.findall(r" conditional\(", compiled.as_text())) == 4 * 2
+    assert sum("magi_ragged_dot_kernel" in n for n in names) == 2 * 4 * (2 + 4)
+    assert sum("magi_ragged_dot_dw_kernel" in n for n in names) == 2 * 4 * 2
     assert all("magi_" in n for n in names), kinds
