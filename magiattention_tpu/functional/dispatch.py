@@ -15,10 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.profiling import instrument_scope
 
-
-@instrument_scope
 def dispatch_func(
     x: jax.Array,
     position_ids: np.ndarray,
@@ -42,7 +39,6 @@ def dispatch_func(
     )
 
 
-@instrument_scope
 def undispatch_func(
     y: jax.Array,
     unpermute_index: np.ndarray,

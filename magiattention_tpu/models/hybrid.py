@@ -58,6 +58,7 @@ from ..api import (
 )
 from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
 from ..kernels import ssd
+from ..utils.profiling import REGION, profile_scope
 from .llama import (
     _rms_norm,
     _StepJit,
@@ -341,8 +342,9 @@ def forward(
     pos = get_position_ids(attn_key)
     if "M" in cfg.pattern:
         _refuse_cp(attn_key)
-        starts = get_document_starts(attn_key)
-        pos_in_doc, seg_rows = pos - starts, ssd.segment_rows(starts)
+        with profile_scope(REGION.ssm):
+            starts = get_document_starts(attn_key)
+            pos_in_doc, seg_rows = pos - starts, ssd.segment_rows(starts)
 
     def joined(x, y, lyr):
         if "post_norm" in lyr:
@@ -350,22 +352,28 @@ def forward(
         return x + y
 
     def mamba(x, lyr):
-        h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
-        return joined(
-            x, mamba_mixer(h, lyr, cfg, pos_in_doc, seg_rows), lyr), None
+        with profile_scope(REGION.ssm):
+            h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
+            return joined(
+                x, mamba_mixer(h, lyr, cfg, pos_in_doc, seg_rows), lyr), None
 
     def experts(x, lyr):
-        h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
+        # the block's norm and its way back to the residual stream are with
+        # the shared expert: what every token pays, whatever its route
+        with profile_scope(REGION.moe_shared):
+            h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
         y, routes = dropless_moe_ffn(
             h, lyr, top_k=cfg.top_k, scale=cfg.routed_scale,
             expert_offset=cfg.expert_offset, token_block=cfg.moe_token_block,
             act=cfg.expert_act)
-        return joined(x, y, lyr), routes
+        with profile_scope(REGION.moe_shared):
+            return joined(x, y, lyr), routes
 
     def dense(x, lyr):
-        h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
-        return joined(x, swiglu_mlp(
-            h, lyr["w_gate"], lyr["w_up"], lyr["w_down"]), lyr), None
+        with profile_scope(REGION.mlp):
+            h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
+            return joined(x, swiglu_mlp(
+                h, lyr["w_gate"], lyr["w_up"], lyr["w_down"]), lyr), None
 
     def attention(kind, key):
         return lambda x, lyr: (attn_block(
@@ -381,17 +389,18 @@ def forward(
         x, routed = blocks[kind](x, lyr)
         if routed is not None:
             routes.append(routed)
-    x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    with profile_scope(REGION.head_loss):
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
     return (logits, routes) if with_routes else logits
 
 
 def loss_fn(params, cfg, tokens, labels, attn_key, window_key=None):
     """Next-token cross entropy on the dispatched layout; the
     configurations have no auxiliary routing loss."""
-    return masked_ce(
-        forward(params, cfg, tokens, attn_key, window_key=window_key),
-        dispatch(labels, attn_key))
+    logits = forward(params, cfg, tokens, attn_key, window_key=window_key)
+    with profile_scope(REGION.head_loss):
+        return masked_ce(logits, dispatch(labels, attn_key))
 
 
 @partial(_StepJit, static_argnums=(1, 4), static_argnames=("window_key",),
@@ -406,8 +415,9 @@ def train_step(
     ``attn_key``, the ``W`` blocks under ``window_key``."""
     loss, grads = jax.value_and_grad(loss_fn)(
         params, cfg, tokens, labels, attn_key, window_key)
-    params = jax.tree.map(
-        lambda p, g: p - lr * g.astype(p.dtype), params, grads)
+    with profile_scope(REGION.update):
+        params = jax.tree.map(
+            lambda p, g: p - lr * g.astype(p.dtype), params, grads)
     return params, loss
 
 

@@ -22,6 +22,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..api import calc_attn, dispatch, get_position_ids
 from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
+from ..utils.profiling import (
+    REGION,
+    abstract_signature,
+    note_step_call,
+    profile_scope,
+)
 
 
 @dataclass(frozen=True)
@@ -150,27 +156,31 @@ def attn_block(x, lyr, cfg, pos, attn_key, rope: bool = True):
       before it joins the residual stream.
     """
     dt = x.dtype
-    h = _rms_norm(x, lyr["attn_norm"], cfg.norm_eps)
-    q = (h @ lyr["wq"].astype(dt)).reshape(-1, cfg.n_heads, cfg.head_dim)
-    k = (h @ lyr["wk"].astype(dt)).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ lyr["wv"].astype(dt)).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-    if "q_norm" in lyr:
-        q = _rms_norm(q, lyr["q_norm"], cfg.norm_eps)
-        k = _rms_norm(k, lyr["k_norm"], cfg.norm_eps)
-    if rope and cfg.rope_theta is not None:
-        q = _rope(q, pos, cfg.rope_theta)
-        k = _rope(k, pos, cfg.rope_theta)
+    with profile_scope(REGION.attn_qkv):
+        h = _rms_norm(x, lyr["attn_norm"], cfg.norm_eps)
+        q = (h @ lyr["wq"].astype(dt)).reshape(-1, cfg.n_heads, cfg.head_dim)
+        k = (h @ lyr["wk"].astype(dt)).reshape(
+            -1, cfg.n_kv_heads, cfg.head_dim)
+        v = (h @ lyr["wv"].astype(dt)).reshape(
+            -1, cfg.n_kv_heads, cfg.head_dim)
+        if "q_norm" in lyr:
+            q = _rms_norm(q, lyr["q_norm"], cfg.norm_eps)
+            k = _rms_norm(k, lyr["k_norm"], cfg.norm_eps)
+        if rope and cfg.rope_theta is not None:
+            q = _rope(q, pos, cfg.rope_theta)
+            k = _rope(k, pos, cfg.rope_theta)
     attn_out, _ = calc_attn(q, k, v, attn_key)
-    attn_out = attn_out.reshape(-1, cfg.n_heads * cfg.head_dim)
-    if "w_attn_gate" in lyr:
-        gate = jax.nn.sigmoid(jnp.dot(
-            h, lyr["w_attn_gate"].astype(dt),
-            preferred_element_type=jnp.float32))
-        attn_out = (attn_out.astype(jnp.float32) * gate).astype(dt)
-    y = attn_out @ lyr["wo"].astype(dt)
-    if "attn_post_norm" in lyr:
-        y = _rms_norm(y, lyr["attn_post_norm"], cfg.norm_eps)
-    return x + y
+    with profile_scope(REGION.attn_out):
+        attn_out = attn_out.reshape(-1, cfg.n_heads * cfg.head_dim)
+        if "w_attn_gate" in lyr:
+            gate = jax.nn.sigmoid(jnp.dot(
+                h, lyr["w_attn_gate"].astype(dt),
+                preferred_element_type=jnp.float32))
+            attn_out = (attn_out.astype(jnp.float32) * gate).astype(dt)
+        y = attn_out @ lyr["wo"].astype(dt)
+        if "attn_post_norm" in lyr:
+            y = _rms_norm(y, lyr["attn_post_norm"], cfg.norm_eps)
+        return x + y
 
 
 def swiglu_mlp(h, w_gate, w_up, w_down):
@@ -206,10 +216,11 @@ def embed_dispatched(embed, tokens, attn_key, dtype, scale=None):
     the backward's scatter-add into the table is float32. ``scale`` (a
     model whose embedding is multiplied by ``sqrt(dim)``) is applied to the
     float32 rows, before the cast."""
-    rows = jnp.take(embed, dispatch(tokens, attn_key), axis=0)
-    if scale is not None:
-        rows = rows * scale
-    return rows.astype(dtype)
+    with profile_scope(REGION.embed):
+        rows = jnp.take(embed, dispatch(tokens, attn_key), axis=0)
+        if scale is not None:
+            rows = rows * scale
+        return rows.astype(dtype)
 
 
 def forward(
@@ -234,8 +245,10 @@ def forward(
 
     def layer(x, lyr):
         x = attn_block(x, lyr, cfg, pos, attn_key)
-        h = _rms_norm(x, lyr["mlp_norm"], cfg.norm_eps)
-        return x + swiglu_mlp(h, lyr["w_gate"], lyr["w_up"], lyr["w_down"])
+        with profile_scope(REGION.mlp):
+            h = _rms_norm(x, lyr["mlp_norm"], cfg.norm_eps)
+            return x + swiglu_mlp(
+                h, lyr["w_gate"], lyr["w_up"], lyr["w_down"])
 
     if cfg.remat:
         layer = jax.checkpoint(layer)
@@ -243,8 +256,9 @@ def forward(
     for lyr in params["layers"]:
         x = layer(x, lyr)
 
-    x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    with profile_scope(REGION.head_loss):
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
 
 
 def loss_fn(
@@ -258,8 +272,9 @@ def loss_fn(
     are dispatched with the same permutation — cheaper than undispatching
     the logits)."""
     logits = forward(params, cfg, tokens, attn_key)
-    labels_d = dispatch(labels, attn_key)
-    return masked_ce(logits, labels_d)
+    with profile_scope(REGION.head_loss):
+        labels_d = dispatch(labels, attn_key)
+        return masked_ce(logits, labels_d)
 
 
 # XLA's default memory scheduler orders a program three ways (list, DFS,
@@ -283,11 +298,23 @@ class _StepJit:
     runs is not known when this module is imported, and asking then would
     start it. Compiler options are the outermost jit's alone, and JAX
     refuses them on an inner one: called under another trace
-    (``jax.make_jaxpr``, a caller's own jit) the step is the plain jit."""
+    (``jax.make_jaxpr``, a caller's own jit) the step is the plain jit.
+
+    Under ``MAGI_ATTENTION_PROFILE_MODE`` a call outside a trace is kept in
+    ``last_call``, and ``signature`` is its abstract form (shape, dtype and
+    sharding of each array, the static arguments as they are), from which
+    ``utils/profiling.py:compiled_step_texts`` makes the compiled program's
+    text again; both ``None`` with the flag off."""
 
     def __init__(self, fn, **jit_kw):
         self._fn, self._jit_kw = fn, jit_kw
         self.__doc__, self.__name__ = fn.__doc__, fn.__name__
+        self.name = f"{fn.__module__}.{fn.__qualname__}"
+        self.last_call = None
+
+    @property
+    def signature(self):
+        return self.last_call and abstract_signature(*self.last_call)
 
     @cached_property
     def _inner(self):
@@ -305,7 +332,10 @@ class _StepJit:
     def __call__(self, *args, **kwargs):
         traced = any(isinstance(x, jax.core.Tracer)
                      for x in jax.tree.leaves((args, kwargs)))
-        return (self._inner if traced else self._jitted)(*args, **kwargs)
+        if traced:
+            return self._inner(*args, **kwargs)
+        note_step_call(self, args, kwargs)
+        return self._jitted(*args, **kwargs)
 
     def __getattr__(self, name):  # lower, trace, eval_shape, clear_cache
         return getattr(self._jitted, name)
@@ -324,7 +354,9 @@ def train_step(
     loss, grads = jax.value_and_grad(loss_fn)(
         params, cfg, tokens, labels, attn_key
     )
-    params = jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype), params, grads)
+    with profile_scope(REGION.update):
+        params = jax.tree.map(
+            lambda p, g: p - lr * g.astype(p.dtype), params, grads)
     return params, loss
 
 

@@ -43,6 +43,7 @@ from jax import shard_map
 from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
 from ..kernels import registry, tile_policy
 from ..kernels.grouped_matmul import grouped_matmul, note_tile_stats
+from ..utils.profiling import REGION, profile_scope
 from .llama import (
     LlamaConfig,
     _rms_norm,
@@ -387,25 +388,30 @@ def _held_experts_block(h, topi, weights, w_up, w_down, sizes, *,
     more, so no row is ever dropped. The way back is indexed by (token,
     choice): a pair past the buffer reads its last row and weighs it 0."""
     sb, k = topi.shape
-    mine, gid = _local_expert_ids(topi, w_up.shape[0], offset)
-    order = jnp.argsort(gid.reshape(-1), stable=True)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(sb * k, dtype=order.dtype))
-    head = order[:capacity]
-    live = (jnp.arange(capacity) < jnp.sum(sizes))[:, None]
+    with profile_scope(REGION.moe_route):
+        mine, gid = _local_expert_ids(topi, w_up.shape[0], offset)
+        order = jnp.argsort(gid.reshape(-1), stable=True)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(sb * k, dtype=order.dtype))
+        head = order[:capacity]
+        live = (jnp.arange(capacity) < jnp.sum(sizes))[:, None]
     # past the groups the grouped product writes nothing, forward or
     # backward: what it leaves there is masked on the way in and out
-    rows = jnp.where(live, _token_rows(h, head, inverse, k), 0)
+    with profile_scope(REGION.moe_rows):
+        rows = jnp.where(live, _token_rows(h, head, inverse, k), 0)
     grouped = partial(grouped_matmul, group_sizes=sizes, tile_rows=tile_rows)
-    up = grouped(rows, w_up)  # float32: the activation before the rounding
-    inner = jnp.where(live, _expert_act(up, act), 0).astype(h.dtype)
-    out = jnp.where(live, grouped(inner, w_down, out_dtype=h.dtype), 0)
-    back = _permute_rows(
-        out, jnp.minimum(inverse, capacity - 1), head).reshape(sb, k, -1)
-    gate = jnp.where(mine, weights, 0.0).astype(h.dtype)
-    return jnp.einsum(
-        "sk,skd->sd", gate, back, preferred_element_type=jnp.float32
-    ).astype(h.dtype)
+    with profile_scope(REGION.moe_experts):
+        up = grouped(rows, w_up)  # float32: the activation before rounding
+        inner = jnp.where(live, _expert_act(up, act), 0).astype(h.dtype)
+        out = grouped(inner, w_down, out_dtype=h.dtype)
+    with profile_scope(REGION.moe_rows):
+        out = jnp.where(live, out, 0)
+        back = _permute_rows(
+            out, jnp.minimum(inverse, capacity - 1), head).reshape(sb, k, -1)
+        gate = jnp.where(mine, weights, 0.0).astype(h.dtype)
+        return jnp.einsum(
+            "sk,skd->sd", gate, back, preferred_element_type=jnp.float32
+        ).astype(h.dtype)
 
 
 def _tiered_experts_block(capacity: int, worst: int, **static):
@@ -502,9 +508,11 @@ def dropless_moe_ffn(
         raise ValueError(f"act {act!r}: one of {EXPERT_ACTS}")
     dt = h.dtype
     s, dim = h.shape
-    topi, weights, scores = route_sigmoid_topk(
-        h, lyr["router"], lyr["e_bias"], top_k, scale)
-    w_up, w_down = lyr["w_up"].astype(dt), lyr["w_down"].astype(dt)
+    with profile_scope(REGION.moe_route):
+        topi, weights, scores = route_sigmoid_topk(
+            h, lyr["router"], lyr["e_bias"], top_k, scale)
+    with profile_scope(REGION.moe_experts):
+        w_up, w_down = lyr["w_up"].astype(dt), lyr["w_down"].astype(dt)
     sb = token_block if s % token_block == 0 else s
     held, n_experts = w_up.shape[0], lyr["router"].shape[-1]
     # the rows an expert expects of a block are known here, the sizes it
@@ -524,21 +532,29 @@ def dropless_moe_ffn(
         capacity, worst, offset=expert_offset, tile_rows=tile_rows, act=act)
 
     def one_block(args):
-        sizes = held_expert_rows(args[1], held, expert_offset)
+        with profile_scope(REGION.moe_route):
+            sizes = held_expert_rows(args[1], held, expert_offset)
         note_tile_stats(sizes, tile_rows, row_buffer=capacity)
         return block(*args, w_up, w_down, sizes), sizes
 
-    routed, sizes = jax.lax.map(one_block, tuple(
-        v.reshape(s // sb, sb, -1) for v in (h, topi, weights)))
-    if act == "relu2":
-        shared = jnp.square(jax.nn.relu(h @ lyr["ws_up"].astype(dt))) @ (
-            lyr["ws_down"].astype(dt))
-    else:
-        shared = _expert_act(jnp.dot(
-            h, lyr["ws_up"].astype(dt), preferred_element_type=jnp.float32),
-            act).astype(dt) @ lyr["ws_down"].astype(dt)
+    # what the loop over the blocks adds round them (a block's rows cut out
+    # and its result put back, the relayouts XLA makes for the weighted
+    # sum) carries the loop's scope: row movement
+    with profile_scope(REGION.moe_rows):
+        routed, sizes = jax.lax.map(one_block, tuple(
+            v.reshape(s // sb, sb, -1) for v in (h, topi, weights)))
+    with profile_scope(REGION.moe_shared):
+        if act == "relu2":
+            shared = jnp.square(jax.nn.relu(h @ lyr["ws_up"].astype(dt))) @ (
+                lyr["ws_down"].astype(dt))
+        else:
+            shared = _expert_act(jnp.dot(
+                h, lyr["ws_up"].astype(dt),
+                preferred_element_type=jnp.float32),
+                act).astype(dt) @ lyr["ws_down"].astype(dt)
+        y = routed.reshape(s, dim) + shared
     block_rows = jnp.sum(sizes, axis=1)
-    return routed.reshape(s, dim) + shared, {
+    return y, {
         "topi": topi, "scores": scores, "group_rows": jnp.sum(sizes, axis=0),
         "block_rows": block_rows,
         "blocks_fitted": jnp.sum(block_rows <= capacity)}

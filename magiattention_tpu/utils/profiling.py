@@ -16,7 +16,19 @@ primitive's name where XLA has an instruction of that kind
 
 * the scopes here are gated on ``MAGI_ATTENTION_PROFILE_MODE`` as the
   reference gates its NVTX ranges (which cost at run time), and they show
-  in ``op_name`` / ``compiled.as_text()``, not as names in a trace;
+  in ``op_name`` / ``compiled.as_text()``, not as names in a trace. The
+  compiled program is what joins the two: :func:`instruction_scopes` reads
+  its text into ``{instruction name: (scopes, pass)}``, and a trace's
+  events are looked up in that table by their names
+  (docs/observability.md, "Reading a device trace by region");
+* a step built by ``models/llama.py:_StepJit`` hands out its own compiled
+  text: under the flag it remembers the abstract signature of its last
+  call, and :func:`compiled_step_texts` lowers and compiles from it after
+  the steps (the jit's own executable in the process that ran it, 0.2-1.3 s
+  on the chip; elsewhere a load from the persistent cache);
+* the model's regions are spelled once, in :data:`MODEL_REGIONS`; the model
+  files open them with :func:`profile_scope` and the benchmark's reader
+  (``cellbench/regions.py``) imports the same tuple;
 * a kernel's identity is not gated: ``kernels/_named.py`` binds every
   Pallas call under ``magi<body>`` (``magi_fwd_kernel``,
   ``magi_bwd_dq_kernel``, ...), always, and that innermost scope is what a
@@ -32,7 +44,9 @@ primitive's name where XLA has an instruction of that kind
 from __future__ import annotations
 
 import functools
+import re
 from contextlib import contextmanager
+from types import SimpleNamespace
 from typing import Callable
 
 import jax
@@ -132,3 +146,242 @@ class switch_profile:
 
     def __exit__(self, *exc_info) -> None:
         self.stop()
+
+
+# ---------------------------------------------------------------------------
+# the model's regions, and the join from a compiled step to a device trace
+# ---------------------------------------------------------------------------
+
+# The one place that spells the regions the model files open (llama.py,
+# hybrid.py, moe.py: ``with profile_scope(<name>)`` round code that is there)
+# and the benchmark's reader sums (cellbench/regions.py). PERF.md §3 says
+# what each encloses. None holds ``magi_``, ``ragged_dot`` or a collective's
+# name: the benchmark matches those anywhere in an instruction's name.
+MODEL_REGIONS = (
+    "embed", "attn_qkv", "attn_out", "mlp", "ssm", "moe_route", "moe_rows",
+    "moe_experts", "moe_shared", "head_loss", "update",
+)
+# ``with profile_scope(REGION.mlp)``: the names as attributes, so that a model
+# file spells none and a misspelt one fails where it is written
+REGION = SimpleNamespace(**{name: name for name in MODEL_REGIONS})
+# the span DistAttnRuntime.calc_attn has always had: between attn_qkv and
+# attn_out, the kernels and everything round them
+ATTN_REGION = "DistAttnRuntime.calc_attn"
+PASSES = ("fwd", "refwd", "bwd", "none")
+# what jax.checkpoint's re-run of the forward puts in the path (JAX 0.9:
+# ``transpose(jvp(..))/checkpoint/rematted_computation/<scopes>/<primitive>``;
+# the backward proper is under ``checkpoint`` without it)
+REMAT_MARKER = "rematted_computation"
+
+# the steps that ran under the flag, by name: models/llama.py:_StepJit puts
+# itself here with its last call
+_STEPS_SEEN: dict = {}
+
+
+def region_of(scopes) -> str | None:
+    """The innermost element of ``scopes`` that is one of
+    :data:`MODEL_REGIONS` or :data:`ATTN_REGION`; ``None`` outside all. A
+    path XLA cut short that still holds a ``group_cast*`` / ``group_reduce*``
+    span is :data:`ATTN_REGION`'s: only the runtime under it opens those."""
+    for scope in reversed(scopes or ()):
+        if scope in MODEL_REGIONS or scope == ATTN_REGION:
+            return scope
+    if any(s.startswith(("group_cast", "group_reduce")) for s in scopes or ()):
+        return ATTN_REGION
+    return None
+
+
+def abstract_signature(args, kwargs):
+    """``(args, kwargs)`` with every array replaced by its
+    ``jax.ShapeDtypeStruct`` (the sharding of a committed ``jax.Array``
+    kept) and everything else, the static arguments, as it is: what
+    ``lower`` needs to make the same program again."""
+
+    def abstract(x):
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        sharding = x.sharding if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    return jax.tree.map(abstract, (args, kwargs))
+
+
+def note_step_call(step, args, kwargs) -> None:
+    """Under the flag, keep ``step`` (a ``_StepJit``) and the arguments of
+    this call for :func:`compiled_step_texts`: two assignments, no lowering
+    and nothing computed (the arrays are donated or small, and what is read
+    of them later, shape, dtype and sharding, outlives their buffers).
+    Nothing with the flag off."""
+    if env_general.is_profile_mode_enable():
+        step.last_call = (args, kwargs)
+        _STEPS_SEEN[step.name] = step
+
+
+def compiled_step_texts() -> dict[str, str]:
+    """``{step name: optimized HLO text}`` of every ``_StepJit`` that ran
+    under the flag, lowered from the signature of its last call and
+    compiled: the executable that just ran, from the jit's own cache or the
+    persistent one. Call it after the steps, never inside a timed window.
+    ``{}`` with the flag off."""
+    if not env_general.is_profile_mode_enable():
+        return {}
+    texts = {}
+    for name, step in _STEPS_SEEN.items():
+        args, kwargs = step.signature
+        texts[name] = step.lower(*args, **kwargs).compile().as_text()
+    return texts
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?(\S+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?(\S+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLS = re.compile(r"\bcalls=%?([^\s,}]+)")
+_WRAPPED = re.compile(r"(\w+)\((.*)\)")
+_MATMULS = ("convolution", "dot")
+
+
+def _opcode(rest: str) -> str:
+    """The opcode of an instruction's text after ``<name> = ``: what stands
+    between the result type (a tuple's may hold spaces) and ``(``."""
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        rest = rest[i + 1:].lstrip()
+    else:
+        rest = rest.partition(" ")[2]
+    return rest.partition("(")[0]
+
+
+def _split_path(path: str) -> list[str]:
+    """``path`` cut at the ``/`` that no parenthesis encloses."""
+    parts, depth, start = [], 0, 0
+    for i, ch in enumerate(path):
+        depth += (ch == "(") - (ch == ")")
+        if ch == "/" and depth == 0:
+            parts.append(path[start:i])
+            start = i + 1
+    parts.append(path[start:])
+    return parts
+
+
+def scope_path(op_name: str) -> tuple[tuple[str, ...], str]:
+    """``(scopes, pass)`` of one ``op_name``. JAX prints a transform round
+    the path element that follows it (``jvp(mlp)/dot_general``,
+    ``transpose(jvp())/mul``), a jitted function as ``jit(<name>)``, and the
+    primitive last: the decorations are taken off, ``jit(..)`` and the
+    primitive left out, and the pass is ``refwd`` where the path crosses
+    :data:`REMAT_MARKER`, else ``bwd`` under a ``transpose``, ``fwd`` under
+    a ``jvp`` alone, ``none`` under no transform (the SGD update, what XLA
+    added)."""
+    scopes: list[str] = []
+    transforms: set[str] = set()
+
+    def walk(parts, primitive_last: bool):
+        for n, part in enumerate(parts):
+            m = _WRAPPED.fullmatch(part)
+            if m is None:
+                if not (primitive_last and n == len(parts) - 1):
+                    scopes.append(part)
+            elif m[1] != "jit":
+                transforms.add(m[1])
+                if m[2]:
+                    walk(_split_path(m[2]), False)
+
+    walk(_split_path(op_name), True)
+    if REMAT_MARKER in scopes:
+        which = "refwd"
+    elif "transpose" in transforms:
+        which = "bwd"
+    elif "jvp" in transforms:
+        which = "fwd"
+    else:
+        which = "none"
+    return tuple(scopes), which
+
+
+def instruction_scopes(text: str):
+    """From a compiled program's text (``compiled.as_text()``) to what a
+    device trace lacks: ``(table, on_boundary)``.
+
+    ``table`` is ``{instruction name: (scopes, pass)}`` for every
+    instruction of every computation of the module, fused ones too, the
+    name as a trace shows it (no ``%``). ``scopes`` is the scope path of the
+    instruction's ``op_name``, outermost first (:func:`scope_path`; the
+    names JAX's control flow and ``checkpoint`` add stay in it), ``pass``
+    one of :data:`PASSES`. An instruction without an ``op_name`` (a layout
+    ``copy``, a collective the partitioner inserted) has ``(None, "none")``.
+
+    XLA cuts the head off some paths (a gather it expands is left with
+    ``cond/branch_1_fun/moe_rows/gather``): such a path still names its
+    region but not its pass, and the instruction takes the pass most of its
+    computation's whole paths (those from ``jit(..)`` down) have, where the
+    computation is not the entry and has any.
+
+    A **fusion** takes the entry of the ``convolution`` / ``dot`` it holds
+    where those it holds name exactly one region (:func:`region_of`): a
+    weight-gradient matmul with the SGD update as its epilogue is the
+    matmul's region and pass, where its time goes. Otherwise it takes its
+    own or its root's, whichever names a region (a whole path first); a
+    fusion whose root is a bare ``tuple`` (several outputs) and one XLA left
+    without metadata takes the entry most of its instructions carry; last,
+    whatever ``op_name`` itself or its root has. ``on_boundary`` is
+    ``{fusion name: whether its instructions name more than one region}``,
+    so a reader can say how much time sits on a boundary."""
+    own: dict[str, tuple] = {}  # name -> (scopes, pass, path is whole)
+    inside: dict[str, list[tuple[str, str, bool]]] = {}  # computation -> rows
+    fusions: dict[str, str] = {}  # fusion instruction -> its computation
+    rows, entry = None, None
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                rows = inside.setdefault(c[1], [])
+                if line.startswith("ENTRY"):
+                    entry = c[1]
+            continue
+        root, name, rest = bool(m[1]), m[2], m[3]
+        opcode = _opcode(rest)
+        named = _OP_NAME.search(rest)
+        own[name] = (*scope_path(named[1]), named[1].startswith("jit(")) if (
+            named) else (None, "none", False)
+        if rows is not None:
+            rows.append((name, opcode, root))
+        if opcode == "fusion":
+            called = _CALLS.search(rest)
+            if called:
+                fusions[name] = called[1]
+    for computation, members in inside.items():
+        whole = [own[i][1] for i, _, _ in members if own[i][2]]
+        if computation == entry or not whole:
+            continue
+        most = max(PASSES, key=whole.count)
+        for i, _, _ in members:
+            scopes, _, is_whole = own[i]
+            if scopes is not None and not is_whole:
+                own[i] = (scopes, most, False)
+    table = {name: entry[:2] for name, entry in own.items()}
+    on_boundary = {}
+    for name, computation in fusions.items():
+        members = inside.get(computation, [])
+        regional = [own[i] for i, _, _ in members if region_of(own[i][0])]
+        on_boundary[name] = len({region_of(e[0]) for e in regional}) > 1
+        matmuls = [own[i] for i, opcode, _ in members
+                   if opcode in _MATMULS and region_of(own[i][0])]
+        # itself and its root, a whole path before one cut short
+        near = sorted(
+            [own[name]] + [own[i] for i, _, root in members if root],
+            key=lambda e: not e[2])
+        if len({region_of(e[0]) for e in matmuls}) == 1:
+            pick = matmuls[0]
+        elif any(region_of(e[0]) for e in near):
+            pick = next(e for e in near if region_of(e[0]))
+        elif regional:
+            pick = max(regional, key=regional.count)
+        else:
+            pick = next((e for e in near if e[0] is not None), own[name])
+        table[name] = pick[:2]
+    return table, on_boundary
