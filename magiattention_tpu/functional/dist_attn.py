@@ -347,11 +347,18 @@ def _stack_plans(args: list[AttnArg], sq: int, sk: int, bq: int, bk: int,
 
 
 class DeferredTilePolicy:
-    """Deferred auto-tile state shared by the CP runtimes.
+    """The CP runtimes' one lazy path from a call's data signature to its
+    plans' tiles.
 
-    Auto-tile must score the VMEM guard with the REAL head dims and dtype
-    (r3 advisor finding), which are only known at the first calc_attn —
-    so plan building defers when the policy is active. Subclasses provide
+    A key declares no heads, so the plans are built at once at the tiles
+    known then (an argument, an ``FFA_BLOCK_*`` key, else
+    ``default_blocks``), and the first ``calc_attn`` — the first place that
+    sees head dims, dtype and the GQA group — chooses again with them
+    (:meth:`_ensure_plans`): the auto-tile policy, which must score the
+    VMEM guard with the REAL head dims and dtype (r3 advisor finding) and
+    so builds nothing before, else the subclass's rule over the group
+    (:meth:`_group_tile`). Plans are rebuilt only when the tile chosen
+    differs from the one built. Subclasses provide
     ``_build_plans(blk_q, blk_k)`` and ``_tile_geoms() -> (geoms, sq, sk)``.
     """
 
@@ -381,37 +388,62 @@ class DeferredTilePolicy:
             block_q is not None or block_k is not None,
             self._auto_tile_pending,
         )
+        # the (blk_q, blk_k) and per-pass picks the plans were built for
+        self._plans_built: tuple | None = None
         if not self._auto_tile_pending:
-            self._build_plans(block_q, block_k)
+            self._rebuild_plans(block_q, block_k)
 
-    def _ensure_auto_plans(self, d: int, dv: int, itemsize: int) -> None:
-        """Choose tiles with the real data signature; rebuild on change."""
-        if not self._auto_tile_pending:
-            return
-        sig = (d, dv, itemsize)
+    def _rebuild_plans(self, blk_q, blk_k) -> None:
+        """``_build_plans`` unless the plans stand built for this choice."""
+        choice = (blk_q, blk_k, self._policy_bwd)
+        if self._plans_built != choice:
+            self._build_plans(blk_q, blk_k)
+            self._plans_built = choice
+
+    def _group_tile(self, d: int, dv: int, itemsize: int, group: int,
+                    emit_max_logits: bool) -> tuple[int | None, str]:
+        """``(block_q or None for the default, source)`` of an unpinned
+        call; a runtime without a rule over the group keeps the default."""
+        return None, "default"
+
+    def _ensure_plans(self, d: int, dv: int, itemsize: int, group: int,
+                      emit_max_logits: bool = False) -> None:
+        """Choose tiles with the real data signature; rebuild on change.
+
+        A key called at two signatures (two models of different group on
+        one mask) holds the plans of the LAST one: each change rebuilds
+        (tens of ms a plan group), and a program compiled before it keeps
+        the arrays it was traced with."""
+        if self._tile_source == "pin":
+            return  # an argument, an env key or a resilience rung: as built
+        sig = (d, dv, itemsize, group, emit_max_logits)
         if self._plan_sig == sig:
             return
-        from ..kernels.tile_policy import choose_blocks_per_pass_multi
+        blk_q = blk_k = None
+        if self._auto_tile_pending:
+            from ..kernels.tile_policy import choose_blocks_per_pass_multi
 
-        geoms, sq, sk = self._tile_geoms()
-        try:
-            (blk_q, blk_k), pol_dq, pol_dkv = choose_blocks_per_pass_multi(
-                geoms, sq, sk, d, dv, itemsize
-            )
-        except Exception as e:
-            # a failed VMEM scoring pass must not kill the step: the
-            # clamped defaults are always lowerable (docs/resilience.md)
-            if not env_resilience.is_fallback_enable():
-                raise
-            from ..resilience.fallback import record_resilience_event
+            geoms, sq, sk = self._tile_geoms()
+            pol_dq = pol_dkv = None
+            try:
+                (blk_q, blk_k), pol_dq, pol_dkv = choose_blocks_per_pass_multi(
+                    geoms, sq, sk, d, dv, itemsize
+                )
+            except Exception as e:
+                # a failed VMEM scoring pass must not kill the step: the
+                # clamped defaults are always lowerable (docs/resilience.md)
+                if not env_resilience.is_fallback_enable():
+                    raise
+                from ..resilience.fallback import record_resilience_event
 
-            record_resilience_event(
-                "recovered", "vmem_check",
-                action_detail="default_blocks", error=type(e).__name__,
-            )
-            (blk_q, blk_k), pol_dq, pol_dkv = (None, None), None, None
-        self._policy_bwd = (pol_dq, pol_dkv)
-        self._build_plans(blk_q, blk_k)
+                record_resilience_event(
+                    "recovered", "vmem_check",
+                    action_detail="default_blocks", error=type(e).__name__,
+                )
+            self._policy_bwd = (pol_dq, pol_dkv)
+        else:
+            blk_q, self._tile_source = self._group_tile(*sig)
+        self._rebuild_plans(blk_q, blk_k)
         self._plan_sig = sig
 
     # -- signatures (registry, quarantine and run-history keys) ---------
@@ -593,39 +625,28 @@ class DistAttnRuntime(DeferredTilePolicy):
         from ..kernels.ffa import default_blocks
 
         self._tel_plan_groups = None  # recomputed per plan build
-        km = self.calc_meta
-        shard = km.shard_len
-        kv_shard = km.kv_shard_len
-        total_recv = sum(km.recv_len_per_stage)
-        bq, bk = default_blocks(shard, kv_shard + total_recv, blk_q, blk_k)
+        _, sq, sk = self._tile_geoms()
+        bq, bk = default_blocks(sq, sk, blk_q, blk_k)
         self._bq, self._bk = bq, bk
         pol_dq, pol_dkv = getattr(self, "_policy_bwd", (None, None))
 
-        # merged (no-overlap) plan
-        self._merged_arrays, self._merged_dims = _stack_plans(
-            km.merged_args, shard, kv_shard + total_recv, bq, bk,
-            policy_dq=pol_dq, policy_dkv=pol_dkv, label=self.label,
-        )
-
-        if self.use_overlap:
-            # stage geometries clamp bk; policy picks that don't divide a
-            # stage's padded grid silently inherit (resolve gate)
-            self._host_arrays, self._host_dims = _stack_plans(
-                km.host_args, shard, kv_shard,
-                bq, min(bk, _ceil_to(kv_shard, 128)),
+        # the merged (no-overlap) plan, then the host's and the stages':
+        # stage geometries clamp bk; policy picks that don't divide a
+        # stage's padded grid silently inherit (resolve gate)
+        self._stage_arrays = []
+        self._stage_dims = []
+        for name, args, g_sq, g_sk, g_bk in self._plan_groups(bk):
+            arrays, dims = _stack_plans(
+                args, g_sq, g_sk, bq, g_bk,
                 policy_dq=pol_dq, policy_dkv=pol_dkv, label=self.label,
             )
-            self._stage_arrays = []
-            self._stage_dims = []
-            for st in range(self.num_stages):
-                rl = km.recv_len_per_stage[st]
-                sa, sdims = _stack_plans(
-                    km.remote_args_per_stage[st], shard, rl,
-                    bq, min(bk, _ceil_to(rl, 128)),
-                    policy_dq=pol_dq, policy_dkv=pol_dkv, label=self.label,
-                )
-                self._stage_arrays.append(sa)
-                self._stage_dims.append(sdims)
+            if name == "merged":
+                self._merged_arrays, self._merged_dims = arrays, dims
+            elif name == "host":
+                self._host_arrays, self._host_dims = arrays, dims
+            else:
+                self._stage_arrays.append(arrays)
+                self._stage_dims.append(dims)
         if telemetry.enabled():
             self._plan_group_stats()
 
@@ -731,6 +752,46 @@ class DistAttnRuntime(DeferredTilePolicy):
                 bwd_mode=bwd_mode,
             )
         return payload
+
+    def _plan_groups(self, bk: int):
+        """``(name, per-rank args, sq, sk, block_k)`` of every plan group
+        :meth:`_build_plans` stacks: the merged plan, and under overlap
+        the host's and each stage's, whose key buffers clamp ``block_k``."""
+        km = self.calc_meta
+        shard, kv_shard = km.shard_len, km.kv_shard_len
+        yield ("merged", km.merged_args, shard,
+               kv_shard + sum(km.recv_len_per_stage), bk)
+        if self.use_overlap:
+            yield ("host", km.host_args, shard, kv_shard,
+                   min(bk, _ceil_to(kv_shard, 128)))
+            for st, rl in enumerate(km.recv_len_per_stage):
+                yield (f"stage{st}", km.remote_args_per_stage[st], shard,
+                       rl, min(bk, _ceil_to(rl, 128)))
+
+    def _max_plan_work(self, bq: int, bk: int) -> int:
+        """The longest work list, q-major or k-major, of any rank of any
+        plan group this runtime builds at ``bq`` x ``bk``: what its largest
+        plan table has to hold."""
+        from ..kernels.tile_policy import max_ffa_work
+
+        return max(
+            max_ffa_work(a.q_ranges, a.k_ranges, a.d_lo, a.d_hi,
+                         sq, sk, bq, g_bk)
+            for _, args, sq, sk, g_bk in self._plan_groups(bk)
+            for a in args)
+
+    def _group_tile(self, d: int, dv: int, itemsize: int, group: int,
+                    emit_max_logits: bool) -> tuple[int | None, str]:
+        """``tile_policy.group_block_q`` over every plan this runtime
+        builds (:meth:`_max_plan_work`)."""
+        from ..kernels.ffa import default_blocks
+        from ..kernels.tile_policy import group_block_q
+
+        _, sq, sk = self._tile_geoms()
+        blk_q, source = group_block_q(
+            group, d, dv, itemsize, *default_blocks(sq, sk),
+            self._max_plan_work, emit_max_logits)
+        return (blk_q if source == "shape_rule" else None), source
 
     def _tile_geoms(self):
         # per-mask tile choice scored on the merged per-rank geometries
@@ -945,9 +1006,11 @@ class DistAttnRuntime(DeferredTilePolicy):
             )
             return fn(q, k, v, self._cast_ops, self._merged_slices)
 
-        # auto-tile runs HERE (not __post_init__) so the VMEM guard sees
-        # the real head dims and dtype (r3 advisor finding)
-        self._ensure_auto_plans(dh, dv, q.dtype.itemsize)
+        # the tiles are chosen HERE (not __post_init__): the auto-tile
+        # policy's VMEM guard needs the real head dims and dtype (r3 advisor
+        # finding), the rule for block_q the GQA group
+        self._ensure_plans(
+            dh, dv, q.dtype.itemsize, group, return_max_logits)
         # the merged plan's params, or the host stage's on the overlap path
         params = self._ffa_params(
             self._host_dims if self.use_overlap else self._merged_dims,

@@ -399,8 +399,9 @@ class DynamicDistAttnRuntime(DeferredTilePolicy):
         if self.backend in ("sdpa", "sdpa_online"):
             return self._calc_attn_sdpa(q, k, v, scale, return_max_logits)
 
-        # auto-tile with the real head dims/dtype (r3 advisor finding)
-        self._ensure_auto_plans(dh, dv, q.dtype.itemsize)
+        # auto-tile with the real head dims/dtype (r3 advisor finding);
+        # this runtime has no rule over the group: default_blocks otherwise
+        self._ensure_plans(dh, dv, q.dtype.itemsize, group)
         nqt, nkt, w, wt, overrides = self._dims
         params = FFAParams(
             num_work=w, num_work_t=wt, num_q_tiles=nqt, num_k_tiles=nkt,

@@ -730,6 +730,15 @@ def _ffa_fwd_pallas_gqa(
 # model counts two score-sized temporaries and Mosaic keeps more.
 Q_MAJOR_PACK_MAX_ROWS = 1024
 Q_MAJOR_PACK_MAX_BYTES = 9 * 1024 * 1024
+# What the v5e compiler takes of a plan's table: ``s32[W, 15]`` is a
+# scalar-prefetch operand whose rows pad to 512 bytes of the core's 1 MiB of
+# SMEM. Bisected between W = 1342 and 2664 by compiling for a described chip
+# (PERF.md, PR 36): the packed forward, the one-pass and the split backward
+# at g = 8 (128 x 512), g = 16 (64 x 512), g = 4 and g = 1 (256 x 512) and
+# g = 2 (128 x 128), d = 128 bf16, q-major and k-major lists of one length
+# — every one compiled at W = 2008 and was refused at 2009 ("Exceeded smem
+# capacity by 1.1K"). 24 rows of margin for what else a body may keep there.
+PLAN_TABLE_MAX_WORK = 1984
 
 
 def gqa_pack_fits(
@@ -3232,7 +3241,17 @@ def ffa_attn(
                     itemsize=q.dtype.itemsize,
                 )
             )
+    source = _registry_mod().tiles_source(explicit, auto_tile)
     bq, bk = default_blocks(sq, sk, block_q, block_k)
+    if source == "default":
+        # nothing chose the tile: block_q follows the group, so that a
+        # packed grid step is as many rows at g = 8 or 16 as at g = 4
+        from .tile_policy import group_block_q, max_ffa_work
+
+        bq, source = group_block_q(
+            hq // hk, d, dv, q.dtype.itemsize, bq, bk,
+            partial(max_ffa_work, qr, kr, d_lo, d_hi, sq, sk),
+            emit_max_logits=return_max_logits)
 
     plan = get_ffa_plan(qr, kr, d_lo, d_hi, sq, sk, bq, bk)
     arrays = plan_arrays(plan)
@@ -3256,8 +3275,7 @@ def ffa_attn(
         emit_max_logits=return_max_logits,
         **overrides,
     )
-    note_tiles(params, d, dv, q.dtype.itemsize,
-               _registry_mod().tiles_source(explicit, auto_tile))
+    note_tiles(params, d, dv, q.dtype.itemsize, source)
     return ffa_attn_with_plan(
         q, k, v, arrays, params, return_max_logits=return_max_logits
     )

@@ -20,8 +20,9 @@ history and no earlier run decides a kernel.
 A call made for a labelled runtime key (``DistAttnRuntimeKey.label``: a
 model that attends under two masks a step labels them, say ``window`` and
 ``full``) hands its label along: the choice is then also kept per label
-(``last_choice(decision, label=...)``, ``labelled_choices(decision)``) and
-the record carries it. The rule and the memo do not see the label: the same
+(``last_choice(decision, label=...)``; ``labelled_choices(decision)`` has
+every distinct choice a label saw with who made it) and the record carries
+it. The rule and the memo do not see the label: the same
 shapes get the same answer under any.
 
 Rank-ordered backend registrations double as the resilience ladders:
@@ -100,7 +101,10 @@ class BackendRegistry:
         self._lock = threading.Lock()
         self._memo: dict[tuple[str, Any], BackendChoice] = {}
         self._last: dict[str, tuple[Any, str]] = {}
-        self._last_by_label: dict[tuple[str, str], str] = {}
+        # the last choice of a (decision, label or None), and every distinct
+        # "choice (source)" made for a label, in order
+        self._last_choice: dict[tuple[str, str | None], BackendChoice] = {}
+        self._said_by_label: dict[tuple[str, str], list[str]] = {}
         self._announced: set[tuple[str, Any, str, str | None]] = set()
         self.stats: dict[str, int] = {
             "resolves": 0,
@@ -118,8 +122,12 @@ class BackendRegistry:
         provenance without per-step record spam."""
         with self._lock:
             self._last[decision] = (key, choice.name)
+            self._last_choice[decision, None] = choice
             if label is not None:
-                self._last_by_label[decision, label] = choice.name
+                self._last_choice[decision, label] = choice
+                said = self._said_by_label.setdefault((decision, label), [])
+                if f"{choice.name} ({choice.source})" not in said:
+                    said.append(f"{choice.name} ({choice.source})")
         if not (announce and telemetry.enabled()):
             return choice
         tag = (decision, _memo_key(key), choice.name, label)
@@ -182,10 +190,16 @@ class BackendRegistry:
         with self._lock:
             return self._last.get(decision)
 
+    def last_choice(
+        self, decision: str, label: str | None = None
+    ) -> BackendChoice | None:
+        with self._lock:
+            return self._last_choice.get((decision, label))
+
     def labelled(self, decision: str) -> dict[str, str]:
         with self._lock:
-            return {label: name for (d, label), name
-                    in self._last_by_label.items() if d == decision}
+            return {label: "; ".join(said) for (d, label), said
+                    in self._said_by_label.items() if d == decision}
 
 
 _registry: BackendRegistry | None = None
@@ -232,15 +246,24 @@ def stats() -> dict[str, int]:
 def last_choice(decision: str, label: str | None = None) -> str | None:
     """The decision's last choice in this process; with ``label`` the last
     one made for a runtime key of that label."""
-    if label is not None:
-        return get_registry().labelled(decision).get(label)
-    last = get_registry().last(decision)
-    return None if last is None else last[1]
+    last = get_registry().last_choice(decision, label)
+    return None if last is None else last.name
+
+
+def last_source(decision: str, label: str | None = None) -> str | None:
+    """Who made :func:`last_choice`'s choice: "pin", "heuristic", or what
+    the call site said of its own rule ("shape_rule", "guard", ...)."""
+    last = get_registry().last_choice(decision, label)
+    return None if last is None else last.source
 
 
 def labelled_choices(decision: str) -> dict[str, str]:
-    """``{label: last choice}`` of the calls made for labelled runtime
-    keys; empty where no key carries a label."""
+    """``{label: "choice (source)"}`` of the calls made for labelled runtime
+    keys, every distinct one a label saw, in order, joined by ``"; "`` — a
+    key called at two sizes (a model's step, then a smaller check program)
+    can choose twice: ``"fwd256x512 dq256x512 dkv256x512g8 (table_guard);
+    fwd128x512g8 dq128x512g8 dkv128x512g8 (shape_rule)"``. Empty where no
+    key carries a label."""
     return get_registry().labelled(decision)
 
 
@@ -275,7 +298,10 @@ def tiles_pinned() -> bool:
 def tiles_source(explicit: bool, auto_tile: bool) -> str:
     """Who chose a call's FFA tiles, for the ``ffa_tiles`` note: "pin"
     (tile arguments, or any FFA_BLOCK_* key, one pass's included),
-    "auto_tile" (the policy), else "default" (``ffa.default_blocks``)."""
+    "auto_tile" (the policy), else "default" (``ffa.default_blocks``) —
+    which the call then hands to ``tile_policy.group_block_q``, and that
+    answers "default", "shape_rule" (``block_q`` moved to fit the group) or
+    "table_guard" (it would have, and the plan's table does not fit)."""
     if explicit or tiles_pinned() or env_kernel.ffa_pass_blocks_pinned():
         return "pin"
     return "auto_tile" if auto_tile else "default"
@@ -392,7 +418,7 @@ PIN_KEYS: dict[str, tuple[str, ...]] = {
     "ffa_bwd_dkv": ("MAGI_ATTENTION_FFA_GQA_PACK_DKV",),
     "ffa_lowering": ("MAGI_ATTENTION_FFA_EXTENT_CLAMP",),
     # any FFA_BLOCK_* key is a "pin"; else the auto-tile policy, or
-    # ffa.default_blocks (tiles_source)
+    # ffa.default_blocks, block_q by tile_policy.group_block_q (tiles_source)
     "ffa_tiles": (
         "MAGI_ATTENTION_FFA_BLOCK_Q", "MAGI_ATTENTION_FFA_BLOCK_K",
         "MAGI_ATTENTION_FFA_BLOCK_Q_DQ", "MAGI_ATTENTION_FFA_BLOCK_K_DQ",

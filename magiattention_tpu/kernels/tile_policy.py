@@ -429,6 +429,97 @@ def _round_up(x: int, m: int) -> int:
 
 
 # ---------------------------------------------------------------------------
+# block_q follows the GQA group: a packed grid step carries group x block_q
+# rows, and the compiler takes at most ffa.Q_MAJOR_PACK_MAX_ROWS of them
+# ---------------------------------------------------------------------------
+
+# what the default block_q is divided by where the group is too large for it
+GROUP_TILE_DIVISORS = (2, 4)
+
+
+def group_block_q(
+    group: int,
+    d: int,
+    dv: int,
+    itemsize: int,
+    block_q: int,
+    block_k: int,
+    max_work,
+    emit_max_logits: bool = False,
+) -> tuple[int, str]:
+    """``(block_q, source)`` of a call of ``group`` q heads a kv head whose
+    default tile is ``block_q`` x ``block_k``: the rule that lets a pass buy
+    its rows a grid step by packing (PERF.md §6, PR 25) at every group, not
+    only where ``group x 256 <= 1024``.
+
+    It engages only where the ROW bound refuses the default tile (g >= 8 at
+    256 rows) and then takes the largest of ``block_q / 2, block_q / 4``
+    that the bound admits — 128 at g = 8, 64 at g = 16: a packed step of
+    1024 rows, the products and residency of g = 4 at 256 — if EVERY body
+    packs there (``ffa.gqa_pack_admitted``: forward, dq, dkv and one-pass; a
+    pass left plain would run fewer rows a step than it does today, and the
+    plain dq body does not lower at 64 rows) and the plan's table fits:
+    ``max_work(block_q, block_k)``, the largest work count of any plan the
+    caller would build at that tile, within ``ffa.PLAN_TABLE_MAX_WORK`` (a
+    smaller ``block_q`` is more work items, a 512-byte row of SMEM each).
+    ``source`` says what happened: ``"shape_rule"`` the tile moved;
+    ``"table_guard"`` it would have and the table does not fit, so the
+    default stays; ``"default"`` the rule had nothing to say — the default
+    tile packs or the group is 1, no tile of the set packs (g = 32), a pack
+    flag is off or the call wants max-logits (the packed forward emits
+    none), or the BYTE budget refuses a body (at the default, d =
+    256: nothing has measured a smaller tile there; at the candidate: d =
+    256 again, a wide head in float32)."""
+    from . import ffa
+
+    if (
+        group <= 1 or emit_max_logits
+        or group * block_q <= ffa.Q_MAJOR_PACK_MAX_ROWS
+    ):
+        return block_q, "default"
+    candidate = next(
+        (block_q // div for div in GROUP_TILE_DIVISORS
+         if block_q % (16 * div) == 0
+         and group * (block_q // div) <= ffa.Q_MAJOR_PACK_MAX_ROWS),
+        None)
+    if candidate is None or not all(
+        ffa.gqa_pack_admitted(
+            kind, group, candidate, block_k, d, dv, itemsize)
+        for kind in ("fwd", "dq", "dkv", "fused")
+    ):
+        return block_q, "default"
+    num_work = max_work(candidate, block_k)
+    fits = num_work <= ffa.PLAN_TABLE_MAX_WORK
+    if telemetry.enabled():
+        telemetry.record_event(
+            "tile_policy",
+            mode="group",
+            group=group, d=d, dv=dv, itemsize=itemsize,
+            default_blocks=[block_q, block_k],
+            candidate_blocks=[candidate, block_k],
+            num_work=num_work, table_capacity=ffa.PLAN_TABLE_MAX_WORK,
+            fwd_blocks=[candidate if fits else block_q, block_k],
+        )
+    return (candidate, "shape_rule") if fits else (block_q, "table_guard")
+
+
+def max_ffa_work(
+    qr: np.ndarray,
+    kr: np.ndarray,
+    d_lo: np.ndarray,
+    d_hi: np.ndarray,
+    sq: int,
+    sk: int,
+    bq: int,
+    bk: int,
+) -> int:
+    """The longer of a plan's two work lists (q-major and k-major) at a
+    tile: what its largest table holds."""
+    geom = (qr, kr, d_lo, d_hi, sq, sk, bq, bk)
+    return max(count_ffa_work(*geom), count_ffa_work_t(*geom))
+
+
+# ---------------------------------------------------------------------------
 # Mixed-granularity dispatch: per-slice fragmentation + two-pass plan split.
 #
 # A single (block_q, block_k) choice is a compromise: dense slices amortize

@@ -194,7 +194,7 @@ def test_cp_runtime_honors_the_tile_choice(monkeypatch, chooser):
     )
     if auto:
         # the choice ran with the REAL dims signature and is TPU-aligned
-        assert rt._plan_sig == (d, d, 4)
+        assert rt._plan_sig == (d, d, 4, hq // hk, False)
         assert rt._bq % 16 == 0 and rt._bk % 128 == 0
         return
     overrides = rt._merged_dims[4]
@@ -349,8 +349,54 @@ TILE_CASES = {
     # what the v5e compiler refuses of the packed q-major bodies runs
     # plain: over 1024 packed rows, or a modeled residency over 9 MiB
     # (tests/test_attn/test_pack_guard_compiles.py compiles both sides)
+    "g8_at_256_rows": (
+        (1024, 1024, 8, 1, 128, 128, "bfloat16", {"block_q": 256}, {}),
+        "fwd256x512 dq256x512 dkv256x512g8", "pin"),
+    # so block_q follows the group (tile_policy.group_block_q, PR 36): a
+    # packed step of 1024 rows at g = 8 and at g = 16 as at g = 4
     "g8": ((1024, 1024, 8, 1, 128, 128, "bfloat16", {}, {}),
-           "fwd256x512 dq256x512 dkv256x512g8", "default"),
+           "fwd128x512g8 dq128x512g8 dkv128x512g8", "shape_rule"),
+    "g16": ((1024, 1024, 16, 1, 128, 128, "bfloat16", {}, {}),
+            "fwd64x512g16 dq64x512g16 dkv64x512g16", "shape_rule"),
+    "g8_sq384": ((384, 384, 8, 1, 128, 128, "bfloat16", {}, {}),
+                 "fwd128x384g8 dq128x384g8 dkv128x384g8", "shape_rule"),
+    # no tile of the set packs 32 heads; a short sequence's clamped tile
+    # packs as it is
+    "g32": ((1024, 1024, 32, 1, 128, 128, "bfloat16", {}, {}),
+            "fwd256x512 dq256x512 dkv256x512", "default"),
+    "g8_sq128": ((128, 128, 8, 1, 128, 128, "bfloat16", {}, {}),
+                 "fwd128x128g8 dq128x128g8 dkv128x128g8", "default"),
+    # the BYTE budget refuses the packed dq at d = 256, at 128 rows too:
+    # the default tile stays
+    "g8_d256": ((1024, 1024, 8, 1, 256, 256, "bfloat16", {}, {}),
+                "fwd256x512 dq256x512 dkv256x512", "default"),
+    # the table guard: a 16384-token causal document is 2112 work items
+    # at 128 x 512, over ffa.PLAN_TABLE_MAX_WORK (1056 at 256 x 512)
+    "g8_table_guard": (
+        (16384, 16384, 8, 1, 128, 128, "bfloat16", {}, {}),
+        "fwd256x512 dq256x512 dkv256x512g8", "table_guard"),
+    # the packed forward emits no max-logits: nothing for block_q to follow
+    "g8_max_logits": (
+        (1024, 1024, 8, 1, 128, 128, "bfloat16",
+         {"return_max_logits": True}, {}),
+        "fwd256x512 dq256x512 dkv256x512g8", "default"),
+    # an argument, any FFA_BLOCK_* key and the auto-tile policy win over
+    # the group's rule as over the default
+    "g8_block_k_argument": (
+        (1024, 1024, 8, 1, 128, 128, "bfloat16", {"block_k": 256}, {}),
+        "fwd256x256 dq256x256 dkv256x256g8", "pin"),
+    "g8_env_pin": (
+        (1024, 1024, 8, 1, 128, 128, "bfloat16", {},
+         {"MAGI_ATTENTION_FFA_BLOCK_K": "512"}),
+        "fwd256x512 dq256x512 dkv256x512g8", "pin"),
+    "g8_env_pin_of_one_pass": (
+        (1024, 1024, 8, 1, 128, 128, "bfloat16", {},
+         {"MAGI_ATTENTION_FFA_BLOCK_Q_DKV": "128"}),
+        "fwd256x512 dq256x512 dkv128x512g8", "pin"),
+    "g8_auto_tile": (
+        (1024, 1024, 8, 1, 128, 128, "bfloat16", {},
+         {"MAGI_ATTENTION_FFA_AUTO_TILE": "1"}),
+        None, "auto_tile"),
     "d256_packed_dq_too_large": (
         (1024, 1024, 4, 1, 256, 256, "bfloat16", {}, {}),
         "fwd256x512g4 dq256x512 dkv256x512g4", "default"),
@@ -518,3 +564,219 @@ def test_bwd_mode_of_a_cell_follows_its_plans_revisit_distance(
     monkeypatch.setenv("MAGI_ATTENTION_BACKEND_FFA_BWD", "fused")
     assert mode(4) == "fused"
     assert mode(0) == "split"  # no pin lifts a feasibility guard
+
+
+# -- block_q follows the GQA group (tile_policy.group_block_q, PR 36) --------
+
+BF16_128 = (128, 128, 2)
+# name: (group, (d, dv, itemsize), default tile, largest work count at the
+# candidate tile) -> (block_q, source)
+GROUP_RULE = {
+    # up to g = 4 the default tile packs: nothing to follow
+    "g1": (1, BF16_128, (256, 512), 10, (256, "default")),
+    "g2": (2, BF16_128, (256, 512), 10, (256, "default")),
+    "g4": (4, BF16_128, (256, 512), 10, (256, "default")),
+    # 1024 packed rows a step at every larger group that has such a tile
+    "g8": (8, BF16_128, (256, 512), 10, (128, "shape_rule")),
+    "g16": (16, BF16_128, (256, 512), 10, (64, "shape_rule")),
+    "g32_no_tile_of_the_set_packs": (
+        32, BF16_128, (256, 512), 10, (256, "default")),
+    "g8_float32": (8, (128, 128, 4), (256, 512), 10, (128, "shape_rule")),
+    "g8_qk192_v128": (8, (192, 128, 2), (256, 512), 10, (128, "shape_rule")),
+    # refused by BYTES at the default, not by rows: not this rule's
+    "g4_d256": (4, (256, 256, 2), (256, 512), 10, (256, "default")),
+    # a body that does not fit at 128 rows either (the packed dq)
+    "g8_d256": (8, (256, 256, 2), (256, 512), 10, (256, "default")),
+    "g16_d256": (16, (256, 256, 2), (256, 512), 10, (256, "default")),
+    "g8_float32_qk192": (8, (192, 128, 4), (256, 512), 10, (256, "default")),
+    # the plan's table: at the capacity it moves, one item more it stays
+    "g8_table_full": (8, BF16_128, (256, 512), 1984, (128, "shape_rule")),
+    "g8_table_over": (8, BF16_128, (256, 512), 1985, (256, "table_guard")),
+    "g16_table_over": (16, BF16_128, (256, 512), 2809, (256, "table_guard")),
+    # a short sequence's clamped tile: 8 x 128 packs as it is; 16 x 128
+    # halves once; a tile whose half is no multiple of 16 rows stays
+    "g8_clamped_128": (8, BF16_128, (128, 128), 10, (128, "default")),
+    "g16_clamped_128": (16, BF16_128, (128, 128), 10, (64, "shape_rule")),
+    "g16_clamped_112": (16, BF16_128, (112, 128), 10, (112, "default")),
+    # a narrower key tile does not change the row bound
+    "g8_bk128": (8, BF16_128, (256, 128), 10, (128, "shape_rule")),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUP_RULE))
+def test_block_q_follows_the_group(monkeypatch, case):
+    from magiattention_tpu import telemetry
+    from magiattention_tpu.kernels import ffa
+    from magiattention_tpu.kernels.tile_policy import group_block_q
+
+    group, (d, dv, itemsize), (bq, bk), work, want = GROUP_RULE[case]
+    assert ffa.PLAN_TABLE_MAX_WORK == 1984  # the cases' table sizes
+    asked, said = [], []
+    monkeypatch.setattr(telemetry, "enabled", lambda: True)
+    monkeypatch.setattr(
+        telemetry, "record_event",
+        lambda kind, **rec: kind == "tile_policy" and said.append(rec))
+
+    def max_work(blk_q, blk_k):
+        asked.append((blk_q, blk_k))
+        return work
+
+    assert group_block_q(group, d, dv, itemsize, bq, bk, max_work) == want
+    if want[1] == "default":
+        # the plan is counted, and a record written, only for a tile the
+        # rule would move to
+        assert not asked and not said
+        return
+    [rec] = said
+    candidate = asked[0][0]
+    assert asked == [(candidate, bk)] and group * candidate == 1024
+    assert rec["mode"] == "group"
+    # the guard's reason: W and the capacity beside the tile kept
+    assert (rec["num_work"], rec["table_capacity"]) == (work, 1984)
+    assert rec["candidate_blocks"] == [candidate, bk]
+    assert rec["fwd_blocks"] == [want[0], bk]
+
+
+@pytest.mark.parametrize("flag", [
+    "MAGI_ATTENTION_FFA_GQA_PACK", "MAGI_ATTENTION_FFA_GQA_PACK_DQ",
+    "MAGI_ATTENTION_FFA_GQA_PACK_DKV"])
+def test_a_pass_unpacked_by_its_flag_keeps_the_default_tile(monkeypatch, flag):
+    """A plain body at the rule's tile would run fewer rows a step than at
+    the default (and the plain dq does not lower at 64): every pass packs,
+    or the tile stays."""
+    from magiattention_tpu.kernels.tile_policy import group_block_q
+
+    monkeypatch.setenv(flag, "0")
+    for group in (8, 16):
+        assert group_block_q(
+            group, 128, 128, 2, 256, 512, lambda bq, bk: 10
+        ) == (256, "default")
+
+
+# cell -> {key label: (hq, hk, W at 256 x 512, the rule's (block_q, source),
+# W at the tile the rule would move to)}: ISSUE 36's table, the largest of
+# the q-major and k-major lists of the merged plan (the largest rank's at cp
+# 4). Only the Trinity window key moves; the full layer and the hybrid
+# cell's g = 16 call wait for a table laid lane-major (ROADMAP A3).
+CELL_TILES = {
+    "nemo12b.longdoc.cp1": {"": (32, 8, 1056, (None, "default"), None)},
+    "nemo12b.packed.cp1": {"": (32, 8, 315, (None, "default"), None)},
+    "mistral7b.swa32k.cp1": {"": (32, 8, 1096, (None, "default"), None)},
+    "nemo12b.longdoc.cp4": {"": (32, 8, 1043, (None, "default"), None)},
+    "nemotron3nano.packed32k.cp1": {
+        "": (32, 2, 732, (None, "table_guard"), 2809)},
+    "trinitymini.longdocs32k.cp1": {
+        "full": (32, 4, 1342, (None, "table_guard"), 2664),
+        "window": (32, 4, 617, (128, "shape_rule"), 1189)},
+}
+
+
+@pytest.mark.parametrize("cell_name", sorted(CELL_TILES))
+def test_the_rule_on_the_cells_real_masks(cell_name):
+    """Each cell's own mask at its own tokens and head layout through its
+    runtime's plan groups (numpy only: no kernel is traced)."""
+    import jax
+    from jax.sharding import Mesh
+
+    from cellbench import manifest, run, traffic_gen
+    from magiattention_tpu.api.magi_attn_interface import _mgr
+    from magiattention_tpu.kernels import ffa
+
+    cell = manifest.load_cell(manifest.ROOT, cell_name)
+    family = manifest.load_family(manifest.ROOT, cell.config["family"])
+    cfg, tokens, window, _ = run.cell_sizes(cell, family, 0)
+    family.model_config(cfg)  # the afmoe family's window is its layers'
+    spec = traffic_gen.make_mask(
+        cell.traffic, tokens, window, 0,
+        manifest.load_generator(manifest.ROOT, cell.traffic["generator"]))
+    mesh = Mesh(np.array(jax.devices("cpu")[:cell.chips]), ("cp",))
+    key = family.make_key(spec, mesh)
+    keys = key._asdict() if hasattr(key, "_asdict") else {"": key}
+    layouts = {(g["hq"], g["hk"], g["d_qk"], g["d_v"])
+               for g in family.ffa_calls(cfg) if g["layers"]}
+    assert set(keys) == set(CELL_TILES[cell_name])
+    for label, (hq, hk, w_default, want, w_moved) in CELL_TILES[
+            cell_name].items():
+        assert layouts == {(hq, hk, 128, 128)}
+        rt = _mgr(keys[label]).runtime
+        assert (rt._bq, rt._bk, rt._tile_source) == (256, 512, "default")
+        assert rt._max_plan_work(256, 512) == w_default
+        assert max(rt._merged_dims[2:4]) == w_default  # the plans built
+        assert rt._group_tile(128, 128, 2, hq // hk, False) == want
+        assert rt._group_tile(128, 128, 2, hq // hk, True) == (
+            None, "default")  # the packed forward emits no max-logits
+        if w_moved is not None:
+            assert rt._max_plan_work(1024 * hk // hq, 512) == w_moved
+            assert (w_moved <= ffa.PLAN_TABLE_MAX_WORK) == (
+                want[1] == "shape_rule")
+
+
+def test_one_key_at_two_groups_gets_each_its_own_tile(monkeypatch):
+    """A key declares no heads: a runtime called at g = 4, at g = 8 and at
+    g = 4 again REBUILDS its plans on each change (it keeps the last
+    signature's, not one per signature), each call at its own tile and
+    right, forward and gradients, against the dense reference at cp = 2."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from magiattention_tpu.api import (
+        calc_attn, dispatch, magi_attn_flex_key, undispatch,
+    )
+    from magiattention_tpu.api.magi_attn_interface import _mgr
+    from magiattention_tpu.common.enum import AttnMaskType
+    from magiattention_tpu.common.mask import AttnMask
+    from magiattention_tpu.common.ranges import AttnRanges
+    from magiattention_tpu.kernels import registry
+    from magiattention_tpu.testing.ref_attn import ref_attn
+
+    for key in ("MAGI_ATTENTION_FFA_BLOCK_Q", "MAGI_ATTENTION_FFA_BLOCK_K",
+                "MAGI_ATTENTION_FFA_AUTO_TILE"):
+        monkeypatch.delenv(key, raising=False)
+    s, d = 1024, 64
+    docs = [[0, 600], [600, 1024]]
+    mesh = Mesh(np.array(jax.devices("cpu")[:2]), axis_names=("cp",))
+    key = magi_attn_flex_key(docs, docs, [1, 1], s, s, mesh=mesh,
+                             chunk_size=256)
+    rt = _mgr(key).runtime
+    builds = []
+    build = rt._build_plans
+    monkeypatch.setattr(
+        rt, "_build_plans", lambda bq, bk: (builds.append(bq), build(bq, bk)))
+    mask = AttnMask.from_ranges(
+        AttnRanges.from_ranges(docs), AttnRanges.from_ranges(docs),
+        [AttnMaskType.CAUSAL] * 2, total_seqlen_q=s, total_seqlen_k=s,
+    ).mask_array
+
+    def fwd(q, k, v):
+        out_d, _ = calc_attn(
+            dispatch(q, key), dispatch(k, key, role="kv"),
+            dispatch(v, key, role="kv"), key)
+        return undispatch(out_d, key)
+
+    rng = np.random.default_rng(0)
+    for hq, tiles, source in (
+            (4, "fwd256x512g4 dq256x512g4 dkv256x512g4", "default"),
+            (8, "fwd128x512g8 dq128x512g8 dkv128x512g8", "shape_rule"),
+            (8, "fwd128x512g8 dq128x512g8 dkv128x512g8", "shape_rule"),
+            (4, "fwd256x512g4 dq256x512g4 dkv256x512g4", "default")):
+        q, k, v, w = (
+            jnp.asarray(rng.standard_normal((s, h, d)), jnp.float32)
+            for h in (hq, 1, 1, hq))
+        loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) * w)  # noqa: E731
+        got = jax.value_and_grad(loss(fwd), (0, 1, 2))(q, k, v)
+        ref = jax.value_and_grad(loss(
+            lambda q, k, v: ref_attn(
+                q, k, v, mask, compute_dtype=jnp.float32)[0]), (0, 1, 2))(
+            q, k, v)
+        assert registry.last_choice("ffa_tiles") == tiles
+        assert registry.last_source("ffa_tiles") == source
+        assert rt._tile_source == source
+        assert rt._plan_sig == (d, d, 4, hq, False)
+        for name, a, b in zip(("loss", "dq", "dk", "dv"),
+                              jax.tree.leaves(got), jax.tree.leaves(ref)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), atol=5e-4, rtol=5e-4,
+                err_msg=f"hq {hq}: {name}")
+    # built at the key (before this test listened), then once a change
+    assert builds == [128, None]

@@ -456,3 +456,89 @@ def test_a_labelled_keys_kernel_names_still_match_the_bodies():
     assert set(registry.labelled_choices("ffa_tiles")) >= {"full", "window"}
     assert registry.last_choice("ffa_bwd", label="window") in (
         "fused", "split")
+
+
+# -- block_q follows the group (PR 36): the packed tile against 256 rows -----
+
+def _program_and_reference(cell_name, lens, monkeypatch, block_q=None, seed=3):
+    """``(program's values, reference's, targets, what ran)`` of a cell's
+    family at rehearsal widths on documents of ``lens`` tokens; ``block_q``
+    pins the q tile of every key by ``MAGI_ATTENTION_FFA_BLOCK_Q`` (the
+    keys carry the environment's snapshot: a pin makes keys of its own)."""
+    if block_q is None:
+        monkeypatch.delenv("MAGI_ATTENTION_FFA_BLOCK_Q", raising=False)
+    else:
+        monkeypatch.setenv("MAGI_ATTENTION_FFA_BLOCK_Q", str(block_q))
+    cell = manifest.load_cell(manifest.ROOT, cell_name)
+    family = manifest.load_family(manifest.ROOT, cell.config["family"])
+    cfg, *_ = run.cell_sizes(cell, family, 1)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("cp",))
+    spec = _spec(lens)
+    mcfg = family.model_config(cfg)
+    params = family.init_params(mcfg, mesh, seed)
+    toks, labels = (jnp.asarray(x) for x in traffic_gen.token_batches(
+        spec, cfg["vocab_size"], seed, 1)[0])
+    registry.reset_registry()
+    got = family.check_program(mcfg, family.make_key(spec, mesh))(
+        params, toks, labels)
+    ref = family.reference(params, cfg, toks, labels, spec)
+    ran = {
+        "tiles": registry.labelled_choices("ffa_tiles")
+        or {"": registry.last_choice("ffa_tiles")},
+        "bwd": registry.labelled_choices("ffa_bwd")
+        or {"": registry.last_choice("ffa_bwd")},
+        "tiles_source": registry.last_source("ffa_tiles"),
+        "bwd_source": registry.last_source("ffa_bwd"),
+    }
+    return (jax.device_get(got), jax.device_get(ref),
+            int((np.asarray(labels) >= 0).sum()), family.CHECKS, ran)
+
+
+# 2 x 256 rows and a tail: the 256-row default is unclamped, so g = 8 has a
+# tile to move from; the toy window (64) cuts every document
+RULE_LENS = [300, 129, 211]
+
+
+@pytest.mark.parametrize("cell_name,group,moved", [
+    (CELL, 8, True), ("nemo12b.longdoc.cp1", 4, False)])
+def test_the_groups_tile_agrees_with_256_rows_and_the_reference(
+    monkeypatch, cell_name, group, moved
+):
+    """The family's check program (forward, loss, every ``CHECKS``
+    gradient) through the runtime at the tile the rule chooses and at
+    ``block_q`` 256 pinned: at g = 8 both keys — window and full layers in
+    one step — move to 128 rows with every pass packed and the one-pass
+    backward (no guard's outcome), and agree with the pinned run and with
+    the plain reference at the family's limits; the ``llama`` toy at g = 4
+    is the control: the same tile, and the same numbers to the bit."""
+    got, ref, targets, limits, ran = _program_and_reference(
+        cell_name, RULE_LENS, monkeypatch)
+    pinned, _, _, _, ran_pinned = _program_and_reference(
+        cell_name, RULE_LENS, monkeypatch, block_q=256)
+    packed = "fwd{0}x512g{1} dq{0}x512g{1} dkv{0}x512g{1}"
+    if moved:
+        # per label: every distinct choice, with who made it
+        assert ran["tiles"] == {
+            label: packed.format(128, 8) + " (shape_rule)"
+            for label in ("full", "window")}
+        assert ran_pinned["tiles"] == {
+            label: "fwd256x512 dq256x512 dkv256x512g8 (pin)"
+            for label in ("full", "window")}
+        assert set(ran["bwd"].values()) == set(
+            ran_pinned["bwd"].values()) == {"fused (heuristic)"}
+    else:
+        assert ran["tiles"] == ran_pinned["tiles"] == {
+            "": packed.format(256, 4)}
+        assert ran["tiles_source"] == "default"
+        assert ran_pinned["tiles_source"] == "pin"
+        assert ran["bwd"] == ran_pinned["bwd"] == {"": "fused"}
+        assert ran["bwd_source"] == ran_pinned["bwd_source"] == "heuristic"
+    for name, sides in (("the rule's tile against the reference", (got, ref)),
+                        ("256 rows against the reference", (pinned, ref)),
+                        ("the rule's tile against 256 rows", (got, pinned))):
+        checks = reference.compare(*sides, limits, targets=targets)
+        assert not _failed(checks), (name, checks)
+    if not moved:
+        for name in got:
+            np.testing.assert_array_equal(
+                np.asarray(got[name]), np.asarray(pinned[name]), err_msg=name)

@@ -361,3 +361,29 @@ def test_resolution_announces_backend_select(tmp_path, monkeypatch):
     assert selects[0]["choice"] == "fused"
     assert selects[0]["source"] == "heuristic"
     assert selects[0]["key"] == [1, 2]
+
+
+def test_a_label_keeps_every_distinct_choice_with_who_made_it():
+    """A labelled key called at two sizes (a model's step, then a smaller
+    check program) can choose twice: ``labelled_choices`` — what a result
+    line prints — keeps both in order with their sources, ``last_choice``
+    and ``last_source`` the last."""
+    step = "fwd256x512 dq256x512 dkv256x512g8"
+    check = "fwd128x512g8 dq128x512g8 dkv128x512g8"
+    for _ in range(2):  # a step repeats: one entry
+        kreg.note_choice("ffa_tiles", (32768,), step, "table_guard",
+                             label="full")
+    kreg.note_choice("ffa_tiles", (8192,), check, "shape_rule",
+                         label="full")
+    kreg.note_choice("ffa_tiles", (8192,), check, "shape_rule",
+                         label="window")
+    kreg.note_choice("ffa_tiles", (1024,), "fwd256x512", "default")
+    assert kreg.labelled_choices("ffa_tiles") == {
+        "full": f"{step} (table_guard); {check} (shape_rule)",
+        "window": f"{check} (shape_rule)"}
+    assert kreg.last_choice("ffa_tiles", label="full") == check
+    assert kreg.last_source("ffa_tiles", "full") == "shape_rule"
+    assert kreg.last_choice("ffa_tiles") == "fwd256x512"
+    assert kreg.last_source("ffa_tiles") == "default"
+    assert kreg.labelled_choices("ffa_bwd") == {}
+    assert kreg.last_source("ffa_bwd") is None
