@@ -28,8 +28,13 @@ An attention block of kind ``k`` rotates q and k where ``rope_theta`` is set
 and ``k`` is in ``rope_in`` (a family with a rotary embedding in its window
 layers only says ``rope_in="W"``); ``qk_norm`` and ``attn_gate`` give it
 the per-head q/k norms and the sigmoid output gate that ``attn_block``
-reads from the layer's leaves. A model layer of attention and then an MLP
-is two blocks (``W`` then ``D`` or ``E``).
+reads from the layer's leaves; ``latent`` (``llama.LatentAttention``) gives
+every attention block the latent leaves in place of ``wq``, ``wk``, ``wv``
+(low-rank q and kv chains, one rotary key a token, ``n_heads`` key-value
+heads through ``calc_attn``), and the positions handed to the blocks are
+then each token's place in its document. ``route`` names an ``E`` block's
+routing (``moe.ROUTES``). A model layer of attention and then an MLP is two
+blocks (``W`` then ``D`` or ``E``).
 
 Everything else is the Llama family's, used and not copied:
 ``embed_dispatched`` (times ``embed_scale``), ``_rms_norm``, ``masked_ce``,
@@ -60,6 +65,7 @@ from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
 from ..kernels import ssd
 from ..utils.profiling import REGION, profile_scope
 from .llama import (
+    LatentAttention,
     _rms_norm,
     _StepJit,
     attn_block,
@@ -67,7 +73,7 @@ from .llama import (
     masked_ce,
     swiglu_mlp,
 )
-from .moe import EXPERT_ACTS, ROUTES_SAVED, dropless_moe_ffn
+from .moe import EXPERT_ACTS, ROUTES, ROUTES_SAVED, dropless_moe_ffn
 
 MIXERS = ("M", "E", "D", "*", "W")
 ATTENTION = ("*", "W")
@@ -89,6 +95,7 @@ class HybridConfig:
     rope_in: str = "*W"  # the attention kinds that rotate, given a theta
     qk_norm: bool = False  # RMSNorm over each head's channels of q and k
     attn_gate: bool = False  # the output times sigmoid(h w_attn_gate)
+    latent: LatentAttention | None = None  # latent attention, its widths
     # 'D': dense SwiGLU MLP
     dense_ffn: int = 512
     # 'M': Mamba-2
@@ -111,6 +118,7 @@ class HybridConfig:
     shared_ffn: int = 256
     routed_scale: float = 1.0
     expert_act: str = "relu2"  # or "swiglu": gate and up side by side
+    route: str = "sigmoid_topk"  # or "softmax_topk" (moe.ROUTES)
     moe_token_block: int = 8192
     dtype: str = "bfloat16"
     remat: bool = False
@@ -126,6 +134,23 @@ class HybridConfig:
         if self.expert_act not in EXPERT_ACTS:
             raise ValueError(
                 f"expert_act {self.expert_act!r}: one of {EXPERT_ACTS}")
+        if self.route not in ROUTES:
+            raise ValueError(f"route {self.route!r}: one of {ROUTES}")
+        if self.latent is not None and (
+                self.rope_theta is None
+                or not 0 < self.latent.rope_dim < self.head_dim
+                or self.latent.rope_dim % 2):
+            raise ValueError(
+                f"latent attention rotates an even rope_dim "
+                f"({self.latent.rope_dim}) of head_dim {self.head_dim} "
+                f"channels by rope_theta ({self.rope_theta})")
+        if self.latent is not None and (
+                self.n_kv_heads != self.n_heads or self.rope_in != "*W"):
+            raise ValueError(
+                f"latent attention runs expanded, n_heads ({self.n_heads}) "
+                f"key-value heads, and rotates in every attention block: "
+                f"n_kv_heads {self.n_kv_heads} and rope_in "
+                f"{self.rope_in!r} would not be read")
         if self.chunk_size != ssd.CHUNK:
             raise ValueError(
                 f"chunk_size {self.chunk_size}: the scan kernel's chunk is "
@@ -205,14 +230,26 @@ def _init_dense(cfg: HybridConfig, key) -> dict:
 
 def _init_attention(cfg: HybridConfig, key) -> dict:
     k = jax.random.split(key, 4)
-    dim, dh = cfg.dim, cfg.head_dim
+    dim, dh, lat = cfg.dim, cfg.head_dim, cfg.latent
     lyr = {
         "attn_norm": jnp.ones((dim,), jnp.float32),
-        "wq": _dense(k[0], (dim, cfg.n_heads * dh), dim),
-        "wk": _dense(k[1], (dim, cfg.n_kv_heads * dh), dim),
-        "wv": _dense(k[2], (dim, cfg.n_kv_heads * dh), dim),
         "wo": _dense(k[3], (cfg.n_heads * dh, dim), cfg.n_heads * dh),
     }
+    if lat is None:
+        lyr["wq"] = _dense(k[0], (dim, cfg.n_heads * dh), dim)
+        lyr["wk"] = _dense(k[1], (dim, cfg.n_kv_heads * dh), dim)
+        lyr["wv"] = _dense(k[2], (dim, cfg.n_kv_heads * dh), dim)
+    else:  # the q chain from k[0], the kv chain from k[1]
+        qa, qb = jax.random.split(k[0])
+        kva, kvb = jax.random.split(k[1])
+        lyr["w_q_a"] = _dense(qa, (dim, lat.q_rank), dim)
+        lyr["q_a_norm"] = jnp.ones((lat.q_rank,), jnp.float32)
+        lyr["w_q_b"] = _dense(qb, (lat.q_rank, cfg.n_heads * dh), lat.q_rank)
+        lyr["w_kv_a"] = _dense(kva, (dim, lat.kv_rank + lat.rope_dim), dim)
+        lyr["kv_a_norm"] = jnp.ones((lat.kv_rank,), jnp.float32)
+        lyr["w_kv_b"] = _dense(
+            kvb, (lat.kv_rank, cfg.n_heads * (2 * dh - lat.rope_dim)),
+            lat.kv_rank)
     if cfg.qk_norm:
         lyr["q_norm"] = jnp.ones((dh,), jnp.float32)
         lyr["k_norm"] = jnp.ones((dh,), jnp.float32)
@@ -327,12 +364,15 @@ def _check_window_key(cfg, attn_key, window_key) -> None:
 def forward(
     params: dict, cfg: HybridConfig, tokens: jax.Array,
     attn_key: DistAttnRuntimeKey, with_routes: bool = False,
-    window_key: DistAttnRuntimeKey | None = None,
+    window_key: DistAttnRuntimeKey | None = None, with_stream: bool = False,
 ):
     """Logits ``(total_seqlen, vocab)`` float32 in dispatched order (at cp 1
     natural order); with ``with_routes`` also each ``E`` block's routing,
     ``[{"topi", "scores", "group_rows", "block_rows", "blocks_fitted"}]``
-    (:func:`~.moe.dropless_moe_ffn`). ``attn_key`` owns the dispatch, the
+    (:func:`~.moe.dropless_moe_ffn`); with ``with_stream`` then the residual
+    stream, the input of every block and last that of the final norm
+    ``[(total_seqlen, dim)] * (blocks + 1)`` in dispatched order (what a
+    comparison block by block reads). ``attn_key`` owns the dispatch, the
     positions and the documents' starts and is the ``*`` blocks' mask;
     ``window_key`` is the ``W`` blocks'."""
     _check_window_key(cfg, attn_key, window_key)
@@ -345,6 +385,8 @@ def forward(
         with profile_scope(REGION.ssm):
             starts = get_document_starts(attn_key)
             pos_in_doc, seg_rows = pos - starts, ssd.segment_rows(starts)
+    if cfg.latent is not None:  # attn_block: a token's place in its document
+        pos = pos - get_document_starts(attn_key)
 
     def joined(x, y, lyr):
         if "post_norm" in lyr:
@@ -365,7 +407,7 @@ def forward(
         y, routes = dropless_moe_ffn(
             h, lyr, top_k=cfg.top_k, scale=cfg.routed_scale,
             expert_offset=cfg.expert_offset, token_block=cfg.moe_token_block,
-            act=cfg.expert_act)
+            act=cfg.expert_act, route=cfg.route)
         with profile_scope(REGION.moe_shared):
             return joined(x, y, lyr), routes
 
@@ -384,15 +426,18 @@ def forward(
     if cfg.remat:  # an E block's chosen experts are saved, never recomputed
         blocks = {kind: jax.checkpoint(fn, policy=ROUTES_SAVED)
                   for kind, fn in blocks.items()}
-    routes = []
+    routes, stream = [], [x]
     for kind, lyr in zip(cfg.pattern, params["layers"]):
         x, routed = blocks[kind](x, lyr)
+        stream.append(x)
         if routed is not None:
             routes.append(routed)
     with profile_scope(REGION.head_loss):
         x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
-    return (logits, routes) if with_routes else logits
+    out = (logits, *([routes] if with_routes else []),
+           *([stream] if with_stream else []))
+    return out if len(out) > 1 else logits
 
 
 def loss_fn(params, cfg, tokens, labels, attn_key, window_key=None):

@@ -51,6 +51,38 @@ class LlamaConfig:
         return jnp.dtype(self.dtype)
 
 
+@dataclass(frozen=True)
+class LatentAttention:
+    """What a latent-attention block has beside ``n_heads`` and ``head_dim``
+    (its configuration's ``latent``; :func:`attn_block` says what the block
+    computes). A head's ``head_dim`` query-key channels are ``head_dim -
+    rope_dim`` without a position and then ``rope_dim`` that rotate; values
+    are ``head_dim`` wide.
+
+    The rotary frequencies are YaRN's (:func:`yarn_inv_freq`): ``theta`` is
+    the configuration's ``rope_theta``, ``yarn_factor`` 1 leaves them plain.
+    The pairs that rotate are channels (0, 1), (2, 3), ... (interleaved).
+    ``mscale_all_dim`` (the DeepSeek-V2 convention): the softmax scale is
+    ``head_dim ** -0.5`` times ``(0.1 mscale_all_dim ln(yarn_factor) + 1)
+    ** 2``. ``pos_scale_beta``: q times ``1 + beta ln(1 + floor(pos /
+    yarn_original_len))``, ``pos`` the token's position in its document."""
+
+    q_rank: int
+    kv_rank: int
+    rope_dim: int
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    mscale_all_dim: float = 0.0
+    pos_scale_beta: float = 0.0
+
+    @property
+    def softmax_mscale(self) -> float:
+        """What the softmax scale is multiplied by (1 at ``yarn_factor`` 1)."""
+        return (0.1 * self.mscale_all_dim * np.log(self.yarn_factor) + 1.0) ** 2
+
+
 def init_params(cfg: LlamaConfig, key: jax.Array) -> dict:
     """Random-init parameter pytree (fp32 master weights)."""
     ks = jax.random.split(key, 2 + cfg.n_layers)
@@ -135,6 +167,66 @@ def _rope(x, pos, theta):
     ).astype(x.dtype)
 
 
+def yarn_inv_freq(dim: int, theta: float, lat: LatentAttention) -> np.ndarray:
+    """The ``dim / 2`` rotary frequencies of YaRN, float32: a frequency that
+    turns more than ``yarn_beta_fast`` times over ``yarn_original_len``
+    positions is kept, one that turns fewer than ``yarn_beta_slow`` times is
+    divided by ``yarn_factor``, and a linear ramp over the frequency's index
+    (its ends rounded outward to whole indices) blends the two between."""
+    kept = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def index_of(turns):  # the index whose frequency turns that often
+        return dim * np.log(lat.yarn_original_len / (turns * 2 * np.pi)) / (
+            2 * np.log(theta))
+
+    low = max(np.floor(index_of(lat.yarn_beta_fast)), 0)
+    high = min(np.ceil(index_of(lat.yarn_beta_slow)), dim - 1)
+    ramp = np.clip(
+        (np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (kept / lat.yarn_factor * ramp + kept * (1 - ramp)).astype(
+        np.float32)
+
+
+def _rotate_pairs(x, cos, sin):
+    """Rotate the channel pairs (0, 1), (2, 3), ... of float32 ``x`` ``(S,
+    h, d)`` by the angles of ``cos``, ``sin`` ``(S, 1, d / 2)``. The result
+    holds every pair's first channel and then every pair's second: q and k
+    come out in one order, and a score is a sum over the channels."""
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+
+def _latent_qkv(h, lyr, cfg, pos):
+    """q, k, v ``(S, n_heads, head_dim)`` of a latent-attention block from
+    its normed input ``h``, expanded to ``n_heads`` key-value heads (the
+    training form: nothing absorbed). ``calc_attn`` scales the scores by
+    ``head_dim ** -0.5``; what the block's softmax scale has beyond that
+    and the position scale are folded into q before its one rounding."""
+    lat, dt, eps = cfg.latent, h.dtype, cfg.norm_eps
+    hq, dh, nope = cfg.n_heads, cfg.head_dim, cfg.head_dim - lat.rope_dim
+    c_q = _rms_norm(h @ lyr["w_q_a"].astype(dt), lyr["q_a_norm"], eps)
+    q = (c_q @ lyr["w_q_b"].astype(dt)).reshape(-1, hq, dh)
+    kv_a = h @ lyr["w_kv_a"].astype(dt)  # [c_kv | the one rotary key]
+    c_kv = _rms_norm(kv_a[:, :lat.kv_rank], lyr["kv_a_norm"], eps)
+    kv = (c_kv @ lyr["w_kv_b"].astype(dt)).reshape(-1, hq, nope + dh)
+    with profile_scope(REGION.mla_assemble):
+        pos32 = pos.astype(jnp.float32)
+        ang = pos32[:, None, None] * yarn_inv_freq(
+            lat.rope_dim, cfg.rope_theta, lat)
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        scale = lat.softmax_mscale * (1.0 + lat.pos_scale_beta * jnp.log1p(
+            jnp.floor(pos32 / lat.yarn_original_len)))
+        q = q.astype(jnp.float32) * scale[:, None, None]
+        q = jnp.concatenate([q[..., :nope], _rotate_pairs(
+            q[..., nope:], cos, sin)], -1).astype(dt)
+        k_rope = _rotate_pairs(
+            kv_a[:, None, lat.kv_rank:].astype(jnp.float32), cos,
+            sin).astype(dt)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+            k_rope, (k_rope.shape[0], hq, lat.rope_dim))], -1)
+        return q, k, kv[..., nope:]
+
+
 def attn_block(x, lyr, cfg, pos, attn_key, rope: bool = True):
     """Pre-norm attention sub-block on the dispatched layout (shared by the
     Llama, MoE and hybrid families — ONE source of truth for
@@ -153,22 +245,43 @@ def attn_block(x, lyr, cfg, pos, attn_key, rope: bool = True):
       before ``wo`` (one gate a head channel; the product in float32,
       rounded once);
     * ``attn_post_norm`` ``(dim,)`` — RMSNorm of the sub-block's output
-      before it joins the residual stream.
+      before it joins the residual stream;
+    * ``w_q_a`` ``(dim, q_rank)``, ``q_a_norm`` ``(q_rank,)``, ``w_q_b``
+      ``(q_rank, n_heads * head_dim)``, ``w_kv_a`` ``(dim, kv_rank +
+      rope_dim)``, ``kv_a_norm`` ``(kv_rank,)``, ``w_kv_b`` ``(kv_rank,
+      n_heads * (head_dim - rope_dim + head_dim))`` IN PLACE OF ``wq``,
+      ``wk``, ``wv`` — latent attention, its widths and rotary parameters
+      ``cfg.latent`` (:class:`LatentAttention`): ``q = RMSNorm(h w_q_a)
+      w_q_b``; ``h w_kv_a`` is a latent ``kv_rank`` wide and ONE rotary key
+      of ``rope_dim`` a token; ``RMSNorm(latent) w_kv_b`` is each head's
+      ``head_dim - rope_dim`` key channels without a position and its
+      ``head_dim`` value channels. The last ``rope_dim`` channels of every
+      q head and the one rotary key rotate, and every head's key is its own
+      channels followed by that key. ``pos`` is then the token's position
+      IN ITS DOCUMENT (the position scale reads it whole; a rotation reads
+      differences only). Runs expanded, ``n_heads`` key-value heads through
+      ``calc_attn`` (g = 1): the configuration refuses a ``latent`` with
+      ``n_kv_heads != n_heads`` or a ``rope_in`` of its own, and ``rope``
+      is not read (the rotary channels always rotate).
     """
     dt = x.dtype
     with profile_scope(REGION.attn_qkv):
         h = _rms_norm(x, lyr["attn_norm"], cfg.norm_eps)
-        q = (h @ lyr["wq"].astype(dt)).reshape(-1, cfg.n_heads, cfg.head_dim)
-        k = (h @ lyr["wk"].astype(dt)).reshape(
-            -1, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lyr["wv"].astype(dt)).reshape(
-            -1, cfg.n_kv_heads, cfg.head_dim)
-        if "q_norm" in lyr:
-            q = _rms_norm(q, lyr["q_norm"], cfg.norm_eps)
-            k = _rms_norm(k, lyr["k_norm"], cfg.norm_eps)
-        if rope and cfg.rope_theta is not None:
-            q = _rope(q, pos, cfg.rope_theta)
-            k = _rope(k, pos, cfg.rope_theta)
+        if "w_q_a" in lyr:
+            q, k, v = _latent_qkv(h, lyr, cfg, pos)
+        else:
+            q = (h @ lyr["wq"].astype(dt)).reshape(
+                -1, cfg.n_heads, cfg.head_dim)
+            k = (h @ lyr["wk"].astype(dt)).reshape(
+                -1, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ lyr["wv"].astype(dt)).reshape(
+                -1, cfg.n_kv_heads, cfg.head_dim)
+            if "q_norm" in lyr:
+                q = _rms_norm(q, lyr["q_norm"], cfg.norm_eps)
+                k = _rms_norm(k, lyr["k_norm"], cfg.norm_eps)
+            if rope and cfg.rope_theta is not None:
+                q = _rope(q, pos, cfg.rope_theta)
+                k = _rope(k, pos, cfg.rope_theta)
     attn_out, _ = calc_attn(q, k, v, attn_key)
     with profile_scope(REGION.attn_out):
         attn_out = attn_out.reshape(-1, cfg.n_heads * cfg.head_dim)

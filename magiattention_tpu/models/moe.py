@@ -290,11 +290,30 @@ def route_sigmoid_topk(h, router, bias, top_k: int, scale: float):
     int32, weights (S, K) float32, s (S, n_experts) float32)``."""
     s = jax.nn.sigmoid(jnp.dot(
         h.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST))
+    return _top_k_of_scores(s, bias, top_k, scale)
+
+
+def route_softmax_topk(h, router, bias, top_k: int, scale: float):
+    """Softmax routing (the Mixtral and DeepSeek-V2 lineage) over ALL the
+    experts ``router`` is wide: ``s = softmax(h W_r)`` in float32, and then
+    as :func:`route_sigmoid_topk` — the ``top_k`` of ``s + bias`` chosen,
+    weights ``s[chosen] / sum(s[chosen]) * scale``, which is the softmax
+    over the chosen logits alone. Same returns."""
+    s = jax.nn.softmax(jnp.dot(
+        h.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST),
+        axis=-1)
+    return _top_k_of_scores(s, bias, top_k, scale)
+
+
+def _top_k_of_scores(s, bias, top_k: int, scale: float):
     _, topi = jax.lax.top_k(jax.lax.stop_gradient(s + bias), top_k)
     topi = checkpoint_name(topi, ROUTES_NAME)  # saved under remat, see below
     chosen = jnp.take_along_axis(s, topi, axis=-1)
     weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scale
     return topi, weights, s
+
+
+ROUTES = ("sigmoid_topk", "softmax_topk")
 
 
 @jax.custom_vjp
@@ -460,15 +479,17 @@ def _tiered_experts_block(capacity: int, worst: int, **static):
 
 def dropless_moe_ffn(
     h, lyr, *, top_k: int, scale: float, expert_offset: int = 0,
-    token_block: int = 8192, act: str = "relu2",
+    token_block: int = 8192, act: str = "relu2", route: str = "sigmoid_topk",
 ):
     """One chip's share of an expert layer that drops no token.
 
     The layer is told which experts it holds: ``lyr["w_up"]`` ``(held, dim,
     ffn)`` and ``lyr["w_down"]`` ``(held, ffn, dim)`` are experts
     ``expert_offset .. expert_offset + held`` of the ``lyr["router"]``'s
-    ``n_experts`` columns. It routes over all of them
-    (:func:`route_sigmoid_topk`), computes ``w_down relu(w_up h)^2`` of its
+    ``n_experts`` columns. It routes over all of them (``route``, one of
+    :data:`ROUTES`: :func:`route_sigmoid_topk`, :func:`route_softmax_topk`;
+    ``lyr["e_bias"]`` takes part in the choice either way), computes
+    ``w_down relu(w_up h)^2`` of its
     own experts for the rows routed to them, adds the shared expert
     (``ws_up``, ``ws_down``, every token) and leaves out what the experts
     held elsewhere would have added: on one chip the layer runs without its
@@ -506,10 +527,14 @@ def dropless_moe_ffn(
     """
     if act not in EXPERT_ACTS:
         raise ValueError(f"act {act!r}: one of {EXPERT_ACTS}")
+    if route not in ROUTES:
+        raise ValueError(f"route {route!r}: one of {ROUTES}")
     dt = h.dtype
     s, dim = h.shape
     with profile_scope(REGION.moe_route):
-        topi, weights, scores = route_sigmoid_topk(
+        route_topk = (route_softmax_topk if route == "softmax_topk"
+                      else route_sigmoid_topk)
+        topi, weights, scores = route_topk(
             h, lyr["router"], lyr["e_bias"], top_k, scale)
     with profile_scope(REGION.moe_experts):
         w_up, w_down = lyr["w_up"].astype(dt), lyr["w_down"].astype(dt)
@@ -523,6 +548,7 @@ def dropless_moe_ffn(
     capacity = tile_policy.grouped_row_capacity(
         worst * held / n_experts, worst, tile_rows)
     key = (s, dim, *w_up.shape, top_k)
+    registry.note_choice("moe_route", key, route, "config")
     registry.note_choice("moe_grouped", key, "pallas_grouped", "default")
     registry.note_choice(
         "moe_grouped_tiles", key, f"rows{tile_rows}", "shape_rule")

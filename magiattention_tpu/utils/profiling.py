@@ -161,12 +161,22 @@ MODEL_REGIONS = (
     "embed", "attn_qkv", "attn_out", "mlp", "ssm", "moe_route", "moe_rows",
     "moe_experts", "moe_shared", "head_loss", "update",
 )
+# Regions INSIDE one of those, opened only where a family's leaves bring the
+# work (a step without such leaves names none of them): ``mla_assemble``, in
+# ``attn_qkv``, is what a latent-attention block does between its projections
+# and ``calc_attn`` — the rotation of the rotary channels, the one rotary key
+# laid out for every head, the concatenations into q and k, q's scales.
+# :func:`region_of` gives an instruction to the innermost, so a sum over
+# ``attn_qkv`` holds the projections and not this.
+NESTED_REGIONS = ("mla_assemble",)
 # ``with profile_scope(REGION.mlp)``: the names as attributes, so that a model
 # file spells none and a misspelt one fails where it is written
-REGION = SimpleNamespace(**{name: name for name in MODEL_REGIONS})
+REGION = SimpleNamespace(
+    **{name: name for name in MODEL_REGIONS + NESTED_REGIONS})
 # the span DistAttnRuntime.calc_attn has always had: between attn_qkv and
 # attn_out, the kernels and everything round them
 ATTN_REGION = "DistAttnRuntime.calc_attn"
+_REGIONS = frozenset((*MODEL_REGIONS, *NESTED_REGIONS, ATTN_REGION))
 PASSES = ("fwd", "refwd", "bwd", "none")
 # what jax.checkpoint's re-run of the forward puts in the path (JAX 0.9:
 # ``transpose(jvp(..))/checkpoint/rematted_computation/<scopes>/<primitive>``;
@@ -180,11 +190,12 @@ _STEPS_SEEN: dict = {}
 
 def region_of(scopes) -> str | None:
     """The innermost element of ``scopes`` that is one of
-    :data:`MODEL_REGIONS` or :data:`ATTN_REGION`; ``None`` outside all. A
-    path XLA cut short that still holds a ``group_cast*`` / ``group_reduce*``
-    span is :data:`ATTN_REGION`'s: only the runtime under it opens those."""
+    :data:`MODEL_REGIONS`, :data:`NESTED_REGIONS` or :data:`ATTN_REGION`;
+    ``None`` outside all. A path XLA cut short that still holds a
+    ``group_cast*`` / ``group_reduce*`` span is :data:`ATTN_REGION`'s: only
+    the runtime under it opens those."""
     for scope in reversed(scopes or ()):
-        if scope in MODEL_REGIONS or scope == ATTN_REGION:
+        if scope in _REGIONS:
             return scope
     if any(s.startswith(("group_cast", "group_reduce")) for s in scopes or ()):
         return ATTN_REGION

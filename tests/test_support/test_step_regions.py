@@ -21,6 +21,7 @@ from magiattention_tpu.utils import profiling
 from magiattention_tpu.utils.profiling import (
     ATTN_REGION,
     MODEL_REGIONS,
+    NESTED_REGIONS,
     REGION,
     instruction_scopes,
     region_of,
@@ -143,8 +144,9 @@ def test_no_region_is_taken_for_a_kernel_or_a_collective():
     taken = re.compile(
         r"magi_|ragged[-_]dot|all[-_]to[-_]all|all[-_]gather|all[-_]reduce|"
         r"reduce[-_]scatter|ppermute|collective|psum|pmax|pmin")
-    assert not [name for name in MODEL_REGIONS if taken.search(name)]
-    assert vars(REGION) == {name: name for name in MODEL_REGIONS}
+    names = MODEL_REGIONS + NESTED_REGIONS
+    assert not [name for name in names if taken.search(name)]
+    assert vars(REGION) == {name: name for name in names}
 
 
 LAYER_REGIONS = [REGION.attn_qkv, ATTN_REGION, REGION.attn_out, REGION.mlp]
@@ -196,9 +198,33 @@ def test_every_block_of_the_hybrid_pattern_has_its_regions(tables, region):
 
 
 def test_the_hybrid_step_uses_every_region(tables):
+    """Every model region, and no nested one: those are opened only where a
+    family's leaves bring the work."""
     found = _entries(tables[HYBRID_STEP][0])
     assert {r for r, _ in found} - {None} == {*MODEL_REGIONS, ATTN_REGION}
     assert {w for r, w in found if r == REGION.update} == {"none"}
+
+
+def test_a_latent_attention_block_opens_its_nested_region(flag_on):
+    """``mla_assemble`` lies inside ``attn_qkv`` in every pass, an
+    instruction in it is the nested region's and not ``attn_qkv``'s, and the
+    projections stay ``attn_qkv``'s."""
+    cfg = dataclasses.replace(
+        HYBRID, vocab_size=138, pattern="*E", n_heads=2, n_kv_heads=2,
+        head_dim=64, rope_theta=1e4, latent=llama.LatentAttention(
+            q_rank=32, kv_rank=16, rope_dim=32, yarn_factor=8.0,
+            yarn_original_len=64, mscale_all_dim=1.0, pos_scale_beta=0.1))
+    args, _ = _hybrid_args(cfg)
+    table, _ = instruction_scopes(
+        hybrid.train_step.lower(*args).compile().as_text())
+    inside = [scopes for scopes, _ in table.values()
+              if scopes and REGION.mla_assemble in scopes]
+    assert inside and all(
+        REGION.attn_qkv in scopes[:scopes.index(REGION.mla_assemble)]
+        and region_of(scopes) == REGION.mla_assemble for scopes in inside)
+    found = _entries(table)
+    for region in (REGION.mla_assemble, REGION.attn_qkv):
+        assert {(region, "fwd"), (region, "refwd"), (region, "bwd")} <= found
 
 
 FUSIONS = """HloModule toy
@@ -293,7 +319,8 @@ def test_with_the_flag_off_the_program_is_the_parents(monkeypatch, family):
              if not n.startswith("params[")]  # an argument's own name
     assert any("jvp" in n for n in names)  # the paths are there to read
     regions = re.compile(
-        r"(?<![\w.])(" + "|".join(map(re.escape, MODEL_REGIONS)) + r")(?![\w.])")
+        r"(?<![\w.])(" + "|".join(map(
+            re.escape, MODEL_REGIONS + NESTED_REGIONS)) + r")(?![\w.])")
     assert not [n for n in names if regions.search(n)]
     _, loss = step(*args, **kwargs)
     assert np.isfinite(float(loss))
