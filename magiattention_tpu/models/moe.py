@@ -316,25 +316,6 @@ def _top_k_of_scores(s, bias, top_k: int, scale: float):
 ROUTES = ("sigmoid_topk", "softmax_topk")
 
 
-@jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation whose inverse is known: the backward
-    is the gather ``g[inverse]``, not a scatter-add."""
-    return x[perm]
-
-
-def _permute_rows_fwd(x, perm, inverse):
-    return x[perm], (perm, inverse)
-
-
-def _permute_rows_bwd(res, g):
-    perm, inverse = res
-    return g[inverse], None, None
-
-
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
-
-
 def _local_expert_ids(topi, held: int, offset: int):
     """``(mine, ids)``: whether each chosen expert is one of the ``held``
     from ``offset``, and its number among them (``held`` for one that is
@@ -365,34 +346,71 @@ def _expert_act(up, act: str):
     return jax.nn.silu(gate) * up
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _token_rows(h, pairs, slot, k: int):
+def _sum_choices(x, slot, gate):
+    """``sum_c gate[:, c] x[slot[:, c]]`` for ``slot`` and ``gate`` ``(tokens,
+    k)``: a choice at a time, each gathered straight into one float32 sum,
+    rounded once to ``x``'s dtype. No (token, choice)-shaped array is made:
+    a ``k`` axis second-minor is no tile at k = 4 or 6, and XLA would relay
+    out every pair in float32. A slot past ``x``'s rows adds nothing."""
+    rows = x.shape[0]
+    gate = jnp.where(slot < rows, gate, 0.0)
+    slot = jnp.minimum(slot, rows - 1)
+    total = jnp.zeros((slot.shape[0], x.shape[-1]), jnp.float32)
+    for c in range(slot.shape[1]):
+        total = total + gate[:, c, None] * x[slot[:, c]].astype(jnp.float32)
+    return total.astype(x.dtype)
+
+
+@jax.custom_vjp
+def _token_rows(h, pairs, slot):
     """``h[pairs // k]``: the tokens' rows of the (token, choice) pairs
     ``pairs`` (the head of the sorted order), without a copy of ``h`` a
-    choice. ``slot`` is the place of every pair in the sorted order, so the
-    backward is a gather too: a pair takes ``g[slot]`` where its slot is one
-    of ``g``'s rows, and a token sums its ``k`` pairs."""
-    return h[pairs // k]
+    choice. ``slot`` ``(tokens, k)`` is the place of every pair in the
+    sorted order, so the backward is a gather too: a token sums ``g[slot]``
+    over its ``k`` choices (:func:`_sum_choices`), a slot past ``g``'s rows
+    adding nothing."""
+    return h[pairs // slot.shape[1]]
 
 
-def _token_rows_fwd(h, pairs, slot, k):
-    return h[pairs // k], slot
+def _token_rows_fwd(h, pairs, slot):
+    return _token_rows(h, pairs, slot), slot
 
 
-def _token_rows_bwd(k, slot, g):
-    rows = g.shape[0]
-    by_pair = g[jnp.minimum(slot, rows - 1)]
-    if rows < slot.shape[0]:  # a buffer below the worst case: pairs past it
-        by_pair = jnp.where((slot < rows)[:, None], by_pair, 0)
-    # what a copy of ``h`` a choice would have had for its transpose
-    (dh,) = jax.linear_transpose(
-        partial(jnp.repeat, repeats=k, axis=0),
-        jax.ShapeDtypeStruct((slot.shape[0] // k, g.shape[-1]), g.dtype),
-    )(by_pair)
-    return dh, None, None
+def _token_rows_bwd(slot, g):
+    return _sum_choices(g, slot, jnp.ones(slot.shape, jnp.float32)), None, None
 
 
 _token_rows.defvjp(_token_rows_fwd, _token_rows_bwd)
+
+
+@jax.custom_vjp
+def _weighed_rows(out, gate, slot, pairs):
+    """``y[t] = sum_c gate[t, c] out[slot[t, c]]``, the way back from the
+    buffer in expert order to the tokens (:func:`_sum_choices`); a pair
+    whose slot is past ``out``'s rows adds nothing. ``pairs`` is the pair
+    of each of ``out``'s rows (``slot[pairs[j]] == j``), so the backward
+    runs in the buffer's order: row ``j`` of token ``t = pairs[j] // k``
+    takes ``d out[j] = gate_j dy[t]`` and gives its pair ``d gate =
+    <out[j], dy[t]>``, both in float32; ``capacity`` rows of ``dy`` are
+    gathered, not one a pair."""
+    return _sum_choices(out, slot, gate)
+
+
+def _weighed_rows_fwd(out, gate, slot, pairs):
+    return _weighed_rows(out, gate, slot, pairs), (out, gate, slot, pairs)
+
+
+def _weighed_rows_bwd(res, dy):
+    out, gate, slot, pairs = res
+    rows = out.shape[0]
+    dy_rows = dy[pairs // slot.shape[1]].astype(jnp.float32)
+    d_out = gate.reshape(-1)[pairs][:, None] * dy_rows
+    dots = jnp.sum(out.astype(jnp.float32) * dy_rows, axis=-1)
+    d_gate = jnp.where(slot < rows, dots[jnp.minimum(slot, rows - 1)], 0.0)
+    return d_out.astype(out.dtype), d_gate.astype(gate.dtype), None, None
+
+
+_weighed_rows.defvjp(_weighed_rows_fwd, _weighed_rows_bwd)
 
 
 def _held_experts_block(h, topi, weights, w_up, w_down, sizes, *,
@@ -404,20 +422,24 @@ def _held_experts_block(h, topi, weights, w_up, w_down, sizes, *,
     ``capacity`` rows of that order: the caller gives ``tokens x top_k``,
     the worst case, or a smaller size to a block whose ``sizes`` (the group
     sizes the grouped product is given, :func:`held_expert_rows`) sum to no
-    more, so no row is ever dropped. The way back is indexed by (token,
-    choice): a pair past the buffer reads its last row and weighs it 0."""
+    more, so no row is ever dropped. The way back gathers each choice's rows
+    of the buffer into one float32 sum a token, weighed by the gate (0 for
+    an expert held elsewhere, nothing for a pair past the buffer); its
+    transpose, and that of the way in, run in the buffer's order
+    (:func:`_weighed_rows`, :func:`_token_rows`): no array of the block's
+    (token, choice) pairs is made either way."""
     sb, k = topi.shape
     with profile_scope(REGION.moe_route):
         mine, gid = _local_expert_ids(topi, w_up.shape[0], offset)
         order = jnp.argsort(gid.reshape(-1), stable=True)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(sb * k, dtype=order.dtype))
+        slot = jnp.zeros_like(order).at[order].set(
+            jnp.arange(sb * k, dtype=order.dtype)).reshape(sb, k)
         head = order[:capacity]
         live = (jnp.arange(capacity) < jnp.sum(sizes))[:, None]
     # past the groups the grouped product writes nothing, forward or
     # backward: what it leaves there is masked on the way in and out
     with profile_scope(REGION.moe_rows):
-        rows = jnp.where(live, _token_rows(h, head, inverse, k), 0)
+        rows = jnp.where(live, _token_rows(h, head, slot), 0)
     grouped = partial(grouped_matmul, group_sizes=sizes, tile_rows=tile_rows)
     with profile_scope(REGION.moe_experts):
         up = grouped(rows, w_up)  # float32: the activation before rounding
@@ -425,12 +447,7 @@ def _held_experts_block(h, topi, weights, w_up, w_down, sizes, *,
         out = grouped(inner, w_down, out_dtype=h.dtype)
     with profile_scope(REGION.moe_rows):
         out = jnp.where(live, out, 0)
-        back = _permute_rows(
-            out, jnp.minimum(inverse, capacity - 1), head).reshape(sb, k, -1)
-        gate = jnp.where(mine, weights, 0.0).astype(h.dtype)
-        return jnp.einsum(
-            "sk,skd->sd", gate, back, preferred_element_type=jnp.float32
-        ).astype(h.dtype)
+        return _weighed_rows(out, jnp.where(mine, weights, 0.0), slot, head)
 
 
 def _tiered_experts_block(capacity: int, worst: int, **static):
@@ -564,8 +581,8 @@ def dropless_moe_ffn(
         return block(*args, w_up, w_down, sizes), sizes
 
     # what the loop over the blocks adds round them (a block's rows cut out
-    # and its result put back, the relayouts XLA makes for the weighted
-    # sum) carries the loop's scope: row movement
+    # and its result put back, the weights' gradients summed over the
+    # blocks) carries the loop's scope: row movement
     with profile_scope(REGION.moe_rows):
         routed, sizes = jax.lax.map(one_block, tuple(
             v.reshape(s // sb, sb, -1) for v in (h, topi, weights)))

@@ -200,13 +200,12 @@ def _relu2_layer_as_it_was(h, lyr, *, top_k, scale, token_block):
             jnp.arange(sb * k, dtype=order.dtype))
         sizes = moe.held_expert_rows(topi, held, 0)
         live = (jnp.arange(sb * k) < jnp.sum(sizes))[:, None]
-        rows = jnp.where(live, moe._permute_rows(
-            jnp.repeat(h, k, axis=0), order, inverse), 0)
+        rows = jnp.where(live, jnp.repeat(h, k, axis=0)[order], 0)
         up = grouped_matmul(rows, w_up, sizes, tile_rows=tile_rows)
         act = jnp.where(live, jnp.square(jax.nn.relu(up)), 0).astype(dt)
         out = jnp.where(live, grouped_matmul(
             act, w_down, sizes, tile_rows=tile_rows, out_dtype=dt), 0)
-        back = moe._permute_rows(out, inverse, order).reshape(sb, k, -1)
+        back = out[inverse].reshape(sb, k, -1)
         gate = jnp.where(mine, weights, 0.0).astype(dt)
         return jnp.einsum("sk,skd->sd", gate, back,
                           preferred_element_type=jnp.float32).astype(dt)
@@ -219,8 +218,13 @@ def _relu2_layer_as_it_was(h, lyr, *, top_k, scale, token_block):
     return routed.reshape(s, dim) + shared
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_relu2_through_the_changed_layer_is_bit_equal_to_before(dtype):
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 2 ** -6)])
+def test_relu2_through_the_changed_layer_equals_before(dtype, tol):
+    """The layer and its gradients as they were before the layer knew an
+    ``act``, to ``dtype``'s rounding: the way back now sums a token's choices
+    in another order, its weights and the sum's transpose in float32 where
+    they were rounded to ``dtype``."""
     lyr = _expert_layer(4, "relu2", n_experts=32)
     h = jax.random.normal(jax.random.PRNGKey(5), (256, 64)).astype(dtype)
     kw = dict(top_k=6, scale=2.5, token_block=128)
@@ -231,12 +235,14 @@ def test_relu2_through_the_changed_layer_is_bit_equal_to_before(dtype):
     def before(h, lyr):
         return _relu2_layer_as_it_was(h, lyr, **kw).astype(jnp.float32).sum()
 
-    np.testing.assert_array_equal(
-        moe.dropless_moe_ffn(h, lyr, **kw)[0],
-        _relu2_layer_as_it_was(h, lyr, **kw))
-    got, want = (jax.grad(f, argnums=(0, 1))(h, lyr) for f in (now, before))
+    got = (moe.dropless_moe_ffn(h, lyr, **kw)[0],
+           jax.grad(now, argnums=(0, 1))(h, lyr))
+    want = (_relu2_layer_as_it_was(h, lyr, **kw),
+            jax.grad(before, argnums=(0, 1))(h, lyr))
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_array_equal(a, b)
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        np.testing.assert_allclose(a, b, rtol=0, atol=tol * (
+            np.abs(b).max() or 1.0))
 
 
 def test_an_unknown_expert_activation_is_refused():

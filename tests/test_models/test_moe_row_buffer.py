@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from magiattention_tpu.kernels import registry, tile_policy
+from magiattention_tpu.kernels.grouped_matmul import grouped_matmul
 from magiattention_tpu.models import hybrid, moe
 
 # 64 tokens a block x top 4 of 16 experts, 4 held: 256 pairs at worst, 64
@@ -49,23 +50,23 @@ def _dense_block(h, topi, weights, w_up, w_down, offset, act):
             "ske,sk,sed->sd", chosen.astype(jnp.float32), weights, outs)
 
 
-def _pairs(rows_held: int, offset=4):
-    """Chosen ids ``(SB, K)`` of which exactly ``rows_held`` pairs fall on
+def _pairs(rows_held: int, offset=4, k=K):
+    """Chosen ids ``(SB, k)`` of which exactly ``rows_held`` pairs fall on
     the experts held, spread over them; the others on experts held
     elsewhere."""
-    flat = np.arange(SB * K)
+    flat = np.arange(SB * k)
     ids = np.where(flat < rows_held, offset + flat % HELD,
                    (offset + HELD + flat % (N_EXPERTS - HELD)) % N_EXPERTS)
-    return jnp.asarray(ids.reshape(SB, K), jnp.int32)
+    return jnp.asarray(ids.reshape(SB, k), jnp.int32)
 
 
-def _block_inputs(seed, act, dtype, rows_held):
+def _block_inputs(seed, act, dtype, rows_held, k=K):
     lyr = _share(seed, act, dtype)
     ks = jax.random.split(jax.random.PRNGKey(seed + 100), 3)
     h = jax.random.normal(ks[0], (SB, DIM)).astype(dtype)
-    weights = jax.random.uniform(ks[1], (SB, K), jnp.float32, 0.1, 1.0)
+    weights = jax.random.uniform(ks[1], (SB, k), jnp.float32, 0.1, 1.0)
     dy = jax.random.normal(ks[2], (SB, DIM)).astype(dtype)
-    return h, _pairs(rows_held), weights, lyr["w_up"], lyr["w_down"], dy
+    return h, _pairs(rows_held, k=k), weights, lyr["w_up"], lyr["w_down"], dy
 
 
 @pytest.mark.parametrize("act", ACTS)
@@ -92,6 +93,71 @@ def test_a_block_at_the_expected_buffer_equals_itself_at_the_worst_case(act):
     for name, a, b in zip(("y", "dh", "dweights", "dw_up", "dw_down"),
                           tight, worst):
         np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def _block_token_major(h, topi, weights, w_up, w_down, sizes, *, offset,
+                       tile_rows, act, capacity):
+    """The block with its old, token-major way back: every pair's row of
+    the buffer gathered back to ``[tokens x k, dim]``, viewed as ``[tokens,
+    k, dim]`` and weighed by one ``einsum``; plain gathers, whose transposes
+    autodiff takes."""
+    sb, k = topi.shape
+    mine, gid = moe._local_expert_ids(topi, w_up.shape[0], offset)
+    order = jnp.argsort(gid.reshape(-1), stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(sb * k, dtype=order.dtype))
+    live = (jnp.arange(capacity) < jnp.sum(sizes))[:, None]
+    rows = jnp.where(live, h[order[:capacity] // k], 0)
+    up = grouped_matmul(rows, w_up, sizes, tile_rows=tile_rows)
+    inner = jnp.where(live, moe._expert_act(up, act), 0).astype(h.dtype)
+    out = jnp.where(live, grouped_matmul(
+        inner, w_down, sizes, tile_rows=tile_rows, out_dtype=h.dtype), 0)
+    back = out[jnp.minimum(inverse, capacity - 1)].reshape(sb, k, -1)
+    gate = jnp.where(mine, weights, 0.0).astype(h.dtype)
+    return jnp.einsum("sk,skd->sd", gate, back,
+                      preferred_element_type=jnp.float32).astype(h.dtype)
+
+
+@pytest.mark.parametrize("k", [4, 6, 8])
+@pytest.mark.parametrize("act", ACTS)
+@pytest.mark.parametrize("buffer, rows_held", [
+    pytest.param("expected", "fit", id="expected-pairs_past_it"),
+    pytest.param("worst", "fit", id="worst-held_elsewhere"),
+    pytest.param("worst", "worst", id="worst-every_pair_held"),
+])
+def test_the_way_back_in_the_buffers_order_equals_the_token_major_einsum(
+        k, act, buffer, rows_held):
+    """Output, ``d h``, ``d weights``, ``d w_up``, ``d w_down`` of the block
+    equal those of the token-major form to float32 rounding, at k = 4, 6, 8:
+    at the expected buffer with pairs past it, at the worst case with the
+    same pairs (on experts held elsewhere at its tail), and at the worst case
+    with every pair on the experts held."""
+    worst, tile = SB * k, tile_policy.grouped_row_tile(SB * k // N_EXPERTS)
+    expected = tile_policy.grouped_row_capacity(
+        worst * HELD / N_EXPERTS, worst, tile)
+    assert expected < worst
+    capacity = expected if buffer == "expected" else worst
+    h, topi, weights, w_up, w_down, dy = _block_inputs(
+        4, act, jnp.float32, expected - 3 if rows_held == "fit" else worst, k)
+    sizes = moe.held_expert_rows(topi, HELD, 4)
+
+    @jax.jit
+    def both(h, weights, w_up, w_down, dy):
+        def at(fn):
+            y, vjp = jax.vjp(lambda h, weights, w_up, w_down: fn(
+                h, topi, weights, w_up, w_down, sizes, offset=4,
+                tile_rows=tile, act=act, capacity=capacity),
+                h, weights, w_up, w_down)
+            return (y, *vjp(dy))
+        return at(moe._held_experts_block), at(_block_token_major)
+
+    got, want = both(h, weights, w_up, w_down, dy)
+    for name, a, b in zip(("y", "dh", "dweights", "dw_up", "dw_down"),
+                          got, want):
+        scale = float(jnp.abs(b).max())
+        assert scale > 0, name
+        np.testing.assert_allclose(a, b, atol=1e-6 * scale, rtol=0,
+                                   err_msg=name)
 
 
 @pytest.fixture()
