@@ -730,6 +730,42 @@ def capture_ssd_contracts(
     return contracts
 
 
+def capture_kda_contracts(
+    tokens: int = 256, heads: int = 64, d: int = 128,
+) -> list[KernelContract]:
+    """Drive both wrappers of ``kernels/kda.py`` (the forward that saves
+    its states, and the backward) under capture, at the head layout of the
+    benchmark's Solar cell."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels import kda
+
+    n = tokens // kda.CHUNK
+    wide = jnp.zeros((tokens, heads * d), jnp.bfloat16)
+    g2 = jnp.zeros((tokens, heads * d), jnp.float32)
+    b4 = jnp.zeros((heads, n, kda.CHUNK, 1), jnp.float32)
+    sr = jnp.zeros((n, kda.SEG_ROWS, kda.CHUNK), jnp.float32)
+    sc = jnp.zeros((n, kda.CHUNK, kda.SEG_ROWS), jnp.float32)
+    hin = jnp.zeros((n, heads, d, d), jnp.float32)
+    res = (wide, wide, wide, g2, b4, sc, sr, hin)
+    contracts: list[KernelContract] = []
+    with jax.default_device(jax.devices("cpu")[0]):
+        for drive in (
+            lambda: kda._fwd_call(
+                wide, wide, wide, g2, b4, sc, sr, d, save=True),
+            lambda: kda._kda_core_bwd(d, res, wide),
+        ):
+            cap = _capture_pallas()
+            with cap:
+                try:
+                    drive()
+                except _Captured:
+                    pass
+            contracts.extend(cap.contracts)
+    return contracts
+
+
 def check_scan_contract(
     report: VerifyReport, contract: KernelContract, site: str
 ) -> None:
@@ -1278,11 +1314,12 @@ def _pallas_contracts() -> dict:
     from ..kernels.block_sparse import PALLAS_CONTRACTS as bsp_contracts
     from ..kernels.ffa import PALLAS_CONTRACTS as ffa_contracts
     from ..kernels.grouped_matmul import PALLAS_CONTRACTS as grouped_contracts
+    from ..kernels.kda import PALLAS_CONTRACTS as kda_contracts
     from ..kernels.paged_decode import PALLAS_CONTRACTS as decode_contracts
     from ..kernels.ssd import PALLAS_CONTRACTS as ssd_contracts
 
     return {**ffa_contracts, **decode_contracts, **bsp_contracts,
-            **ssd_contracts, **grouped_contracts}
+            **ssd_contracts, **grouped_contracts, **kda_contracts}
 
 
 def _contract_sources() -> list[tuple[str, str, dict]]:
@@ -1291,6 +1328,7 @@ def _contract_sources() -> list[tuple[str, str, dict]]:
     from ..kernels.block_sparse import PALLAS_CONTRACTS as bsp_contracts
     from ..kernels.ffa import PALLAS_CONTRACTS as ffa_contracts
     from ..kernels.grouped_matmul import PALLAS_CONTRACTS as grouped_contracts
+    from ..kernels.kda import PALLAS_CONTRACTS as kda_contracts
     from ..kernels.paged_decode import PALLAS_CONTRACTS as decode_contracts
     from ..kernels.ssd import PALLAS_CONTRACTS as ssd_contracts
 
@@ -1313,6 +1351,7 @@ def _contract_sources() -> list[tuple[str, str, dict]]:
             (kdir / "grouped_matmul.py").read_text(),
             grouped_contracts,
         ),
+        ("kernels/kda.py", (kdir / "kda.py").read_text(), kda_contracts),
     ]
 
 
@@ -2043,6 +2082,22 @@ def run_kernel_audit(
         rows.append(
             {
                 "config": "ssd",
+                "kernel": contract.kernel_name,
+                "grid": list(contract.grid),
+                "vmem_bytes": _declared_bytes(contract),
+                # no model of the body's intermediates: K1's own margin
+                "vmem_total_bytes": 2 * _declared_bytes(contract),
+                "vmem_allowed_bytes": VMEM_ALLOWED_BYTES,
+            }
+        )
+
+    # the gated delta rule's two calls: a dense (heads, chunks) grid
+    for contract in capture_kda_contracts():
+        captured_kernels.add(contract.kernel_name)
+        check_scan_contract(report, contract, f"kda:{contract.kernel_name}")
+        rows.append(
+            {
+                "config": "kda",
                 "kernel": contract.kernel_name,
                 "grid": list(contract.grid),
                 "vmem_bytes": _declared_bytes(contract),

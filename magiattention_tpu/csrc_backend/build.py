@@ -30,7 +30,10 @@ def _cache_dir() -> Path:
 
 def _build(src: Path, out: Path) -> None:
     out.parent.mkdir(parents=True, exist_ok=True)
-    tmp = out.with_suffix(".so.tmp")
+    # a name of this process's own: processes that build at once (test
+    # workers sharing the cache) each rename a whole file into place, and
+    # none loads one that another compiler is still writing
+    tmp = out.with_name(f"{out.name}.{os.getpid()}.tmp")
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
         "-o", str(tmp), str(src),

@@ -1,7 +1,8 @@
-"""A decoder built from a layer pattern: Mamba-2, expert, dense and attention
-mixers.
+"""A decoder built from a layer pattern: Mamba-2, gated delta-rule, expert,
+dense and attention mixers.
 
-The block builder of the hybrid families (``nemotron_h``, ``afmoe``):
+The block builder of the hybrid families (``nemotron_h``, ``afmoe``,
+``mistral4``, ``solar_open2``):
 ``pattern`` is a string with one letter a block, and every block is ``x +
 mixer(RMSNorm(x))`` with ONE mixer (``x + RMSNorm(mixer(RMSNorm(x)))`` where
 ``post_norm`` is set: a norm of its own on the mixer's output):
@@ -10,6 +11,15 @@ mixer(RMSNorm(x))`` with ONE mixer (``x + RMSNorm(mixer(RMSNorm(x)))`` where
   depthwise convolution and SiLU over ``x B C``, the chunked state-space scan
   (``kernels/ssd.py``), ``D`` skip, a gated group RMSNorm, ``out_proj``. The
   scan's state and the convolution's taps stop at a document's first token;
+* ``K`` — a gated delta-rule mixer with a per-channel decay (Kimi Delta
+  Attention): q, k, v each projected, a causal depthwise convolution and
+  SiLU, q and k L2-normalised per head; a log-decay per key channel ``g =
+  -exp(A_log) softplus(h W_fa W_fb + dt_bias)`` (a low-rank projection) and
+  ``beta = sigmoid(h W_b)``, doubled where ``kda_neg_eigval`` (the state's
+  update ``I - beta k k^T`` may then turn a direction over); the chunked
+  recurrence (``kernels/kda.py``); an RMSNorm over each head's channels
+  times ``sigmoid(h W_ga W_gb)`` (a low-rank gate), ``W_o``. The state and
+  the convolution's taps stop at a document's first token;
 * ``E`` — this chip's share of a dropless expert layer with a shared expert
   (``models/moe.py:dropless_moe_ffn``), the experts ``relu2`` (two matrices)
   or ``swiglu`` (three) by ``expert_act``;
@@ -42,8 +52,8 @@ Everything else is the Llama family's, used and not copied:
 activations, ``remat`` of a block, plain SGD. The documents' boundaries come
 from the runtime key (``api.get_document_starts``). At cp > 1 the scan's
 state would have to cross ``dispatch``'s chunk permutation, which nothing
-here does yet: the forward of a pattern with an ``M`` refuses such a key by
-name.
+here does yet: the forward of a pattern with an ``M`` or a ``K`` refuses such
+a key by name.
 """
 
 from __future__ import annotations
@@ -62,7 +72,7 @@ from ..api import (
     same_dispatch,
 )
 from ..dist_attn_runtime_mgr import DistAttnRuntimeKey
-from ..kernels import ssd
+from ..kernels import kda, ssd
 from ..utils.profiling import REGION, profile_scope
 from .llama import (
     LatentAttention,
@@ -75,7 +85,8 @@ from .llama import (
 )
 from .moe import EXPERT_ACTS, ROUTES, ROUTES_SAVED, dropless_moe_ffn
 
-MIXERS = ("M", "E", "D", "*", "W")
+MIXERS = ("M", "K", "E", "D", "*", "W")
+RECURRENT = {"M": "the Mamba-2 scan", "K": "the gated delta rule"}
 ATTENTION = ("*", "W")
 
 
@@ -108,6 +119,11 @@ class HybridConfig:
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
+    # 'K': gated delta rule (conv_kernel and time_step_* shared with 'M')
+    kda_heads: int = 4
+    kda_head_dim: int = 128
+    kda_gate_rank: int = 32  # of the decay's and the output gate's products
+    kda_neg_eigval: bool = True  # beta = 2 sigmoid(h W_b), else sigmoid
     # 'E': experts; the router is n_experts wide, this chip holds
     # experts expert_offset .. expert_offset + experts_held
     n_experts: int = 8
@@ -177,14 +193,19 @@ def _dense(key, shape, fan_in):
     return jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
 
 
-def _init_mamba(cfg: HybridConfig, key) -> dict:
-    k = jax.random.split(key, 6)
-    h, d_in = cfg.mamba_heads, cfg.d_inner
-    # dt_bias: the inverse softplus of a log-uniform step in [min, max]
-    dt = jnp.exp(jax.random.uniform(k[2], (h,), jnp.float32) * (
+def _dt_bias(cfg: HybridConfig, key, width: int):
+    """The inverse softplus of a log-uniform step in [time_step_min,
+    time_step_max], floored at time_step_floor, ``(width,)``."""
+    dt = jnp.exp(jax.random.uniform(key, (width,), jnp.float32) * (
         np.log(cfg.time_step_max) - np.log(cfg.time_step_min))
         + np.log(cfg.time_step_min))
     dt = jnp.maximum(dt, cfg.time_step_floor)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _init_mamba(cfg: HybridConfig, key) -> dict:
+    k = jax.random.split(key, 6)
+    h, d_in = cfg.mamba_heads, cfg.d_inner
     bound = cfg.conv_kernel ** -0.5
     return {
         "norm": jnp.ones((cfg.dim,), jnp.float32),
@@ -193,12 +214,37 @@ def _init_mamba(cfg: HybridConfig, key) -> dict:
             k[1], (cfg.conv_kernel, cfg.conv_dim), jnp.float32, -bound, bound),
         "conv_b": jax.random.uniform(
             k[3], (cfg.conv_dim,), jnp.float32, -bound, bound),
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "dt_bias": _dt_bias(cfg, k[2], h),
         "A_log": jnp.log(jax.random.uniform(
             k[4], (h,), jnp.float32, 1.0, 16.0)),
         "D": jnp.ones((h,), jnp.float32),
         "gate_norm": jnp.ones((d_in,), jnp.float32),
         "out_proj": _dense(k[5], (d_in, cfg.dim), d_in),
+    }
+
+
+def _init_kda(cfg: HybridConfig, key) -> dict:
+    k = jax.random.split(key, 11)
+    dim, rank = cfg.dim, cfg.kda_gate_rank
+    width = cfg.kda_heads * cfg.kda_head_dim
+    bound = cfg.conv_kernel ** -0.5
+    return {
+        "norm": jnp.ones((dim,), jnp.float32),
+        "w_q": _dense(k[0], (dim, width), dim),
+        "w_k": _dense(k[1], (dim, width), dim),
+        "w_v": _dense(k[2], (dim, width), dim),
+        "conv_w": jax.random.uniform(  # q, k, v; no bias
+            k[3], (3, cfg.conv_kernel, width), jnp.float32, -bound, bound),
+        "w_fa": _dense(k[4], (dim, rank), dim),
+        "w_fb": _dense(k[5], (rank, width), rank),
+        "A_log": jnp.log(jax.random.uniform(
+            k[6], (cfg.kda_heads,), jnp.float32, 1.0, 16.0)),
+        "dt_bias": _dt_bias(cfg, k[7], width),  # one a key channel
+        "w_b": _dense(k[8], (dim, cfg.kda_heads), dim),
+        "w_ga": _dense(k[9], (dim, rank), dim),
+        "w_gb": _dense(jax.random.fold_in(k[9], 1), (rank, width), rank),
+        "o_norm": jnp.ones((cfg.kda_head_dim,), jnp.float32),
+        "w_o": _dense(k[10], (width, dim), width),
     }
 
 
@@ -259,8 +305,8 @@ def _init_attention(cfg: HybridConfig, key) -> dict:
     return lyr
 
 
-_INIT = {"M": _init_mamba, "E": _init_experts, "D": _init_dense,
-         "*": _init_attention, "W": _init_attention}
+_INIT = {"M": _init_mamba, "K": _init_kda, "E": _init_experts,
+         "D": _init_dense, "*": _init_attention, "W": _init_attention}
 
 
 def _init_block(cfg: HybridConfig, kind: str, key) -> dict:
@@ -272,9 +318,10 @@ def _init_block(cfg: HybridConfig, kind: str, key) -> dict:
 
 
 def init_params(cfg: HybridConfig, key: jax.Array) -> dict:
-    """fp32 masters: matrices as ``llama.init_params``; the scan's ``A_log``
-    = log U[1, 16], ``D`` = 1, ``dt_bias`` from the configuration's
-    ``time_step_*`` keys, so that the decays are a real model's."""
+    """fp32 masters: matrices as ``llama.init_params``; the scan's and the
+    delta rule's ``A_log`` = log U[1, 16], ``D`` = 1, ``dt_bias`` from the
+    configuration's ``time_step_*`` keys, so that the decays are a real
+    model's."""
     ks = jax.random.split(key, 2 + len(cfg.pattern))
     return {
         "embed": _dense(ks[0], (cfg.vocab_size, cfg.dim), cfg.vocab_size),
@@ -336,14 +383,48 @@ def mamba_mixer(h, lyr, cfg: HybridConfig, pos_in_doc, seg_rows):
     return y @ lyr["out_proj"].astype(dt_)
 
 
-def _refuse_cp(attn_key: DistAttnRuntimeKey) -> None:
+def kda_mixer(h, lyr, cfg: HybridConfig, pos_in_doc, seg_rows):
+    """The gated delta-rule mixer on ``h`` ``(T, dim)``, the block's normed
+    input, tokens in natural order, up to ``W_o``: the projections in region
+    ``attn_qkv``, everything from the convolutions to the gated output norm
+    in region ``kda``."""
+    dt_, t = h.dtype, h.shape[0]
+    heads, d = cfg.kda_heads, cfg.kda_head_dim
+    with profile_scope(REGION.attn_qkv):
+        q, k, v = (h @ lyr[w].astype(dt_) for w in ("w_q", "w_k", "w_v"))
+        f = (h @ lyr["w_fa"].astype(dt_)) @ lyr["w_fb"].astype(dt_)
+        b = h @ lyr["w_b"].astype(dt_)
+        gate = (h @ lyr["w_ga"].astype(dt_)) @ lyr["w_gb"].astype(dt_)
+    with profile_scope(REGION.kda):
+        q, k, v = (causal_conv_silu(x, w, 0.0, pos_in_doc).reshape(t, heads, d)
+                   for x, w in zip((q, k, v), lyr["conv_w"]))
+
+        def l2(x):  # per head, float32, eps 1e-6 under the root
+            x = x.astype(jnp.float32)
+            return (x * jax.lax.rsqrt(jnp.sum(
+                x * x, axis=-1, keepdims=True) + 1e-6)).astype(dt_)
+
+        g = -jnp.exp(lyr["A_log"])[:, None] * jax.nn.softplus(
+            f.astype(jnp.float32) + lyr["dt_bias"]).reshape(t, heads, d)
+        beta = jax.nn.sigmoid(b.astype(jnp.float32)) * (
+            2.0 if cfg.kda_neg_eigval else 1.0)
+        o = kda.kda_scan(l2(q), l2(k), v, g, beta, seg_rows)
+        o = _rms_norm(o.astype(jnp.float32), lyr["o_norm"], cfg.norm_eps)
+        return (o.reshape(t, heads * d) * jax.nn.sigmoid(
+            gate.astype(jnp.float32))).astype(dt_)
+
+
+def _refuse_cp(cfg: HybridConfig, attn_key: DistAttnRuntimeKey) -> None:
     if attn_key.cp_size > 1:
+        mixers = " and ".join(f"{RECURRENT[m]} ('{m}')" for m in RECURRENT
+                              if m in cfg.pattern)
         raise NotImplementedError(
-            f"hybrid.forward at cp = {attn_key.cp_size}: the scan's state "
-            "and the convolution's taps follow a document in natural order, "
-            "and dispatch permutes the sequence's chunks over the ranks; "
-            "carrying the state across that permutation is not built "
-            "(ROADMAP B1). Plan the key for a one-device cp axis.")
+            f"hybrid.forward at cp = {attn_key.cp_size}: pattern "
+            f"{cfg.pattern!r} has {mixers}, whose state and convolution "
+            "taps follow a document in natural order, and dispatch permutes "
+            "the sequence's chunks over the ranks; carrying the state "
+            "across that permutation is not built (ROADMAP B1). Plan the "
+            "key for a one-device cp axis.")
 
 
 def _check_window_key(cfg, attn_key, window_key) -> None:
@@ -380,11 +461,15 @@ def forward(
     x = embed_dispatched(
         params["embed"], tokens, attn_key, dt, scale=cfg.embed_scale)
     pos = get_position_ids(attn_key)
-    if "M" in cfg.pattern:
-        _refuse_cp(attn_key)
-        with profile_scope(REGION.ssm):
+    if "M" in cfg.pattern or "K" in cfg.pattern:
+        _refuse_cp(cfg, attn_key)
+        with profile_scope(REGION.ssm if "M" in cfg.pattern else REGION.kda):
             starts = get_document_starts(attn_key)
-            pos_in_doc, seg_rows = pos - starts, ssd.segment_rows(starts)
+            pos_in_doc = pos - starts
+            if "M" in cfg.pattern:
+                seg_rows = ssd.segment_rows(starts)
+            if "K" in cfg.pattern:  # the carry flags of the other chunk
+                kda_rows = ssd.segment_rows(starts, chunk=kda.CHUNK)
     if cfg.latent is not None:  # attn_block: a token's place in its document
         pos = pos - get_document_starts(attn_key)
 
@@ -398,6 +483,13 @@ def forward(
             h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
             return joined(
                 x, mamba_mixer(h, lyr, cfg, pos_in_doc, seg_rows), lyr), None
+
+    def delta_rule(x, lyr):
+        with profile_scope(REGION.attn_qkv):
+            h = _rms_norm(x, lyr["norm"], cfg.norm_eps)
+        y = kda_mixer(h, lyr, cfg, pos_in_doc, kda_rows)
+        with profile_scope(REGION.attn_out):
+            return joined(x, y @ lyr["w_o"].astype(dt), lyr), None
 
     def experts(x, lyr):
         # the block's norm and its way back to the residual stream are with
@@ -421,7 +513,7 @@ def forward(
         return lambda x, lyr: (attn_block(
             x, lyr, cfg, pos, key, rope=kind in cfg.rope_in), None)
 
-    blocks = {"M": mamba, "E": experts, "D": dense,
+    blocks = {"M": mamba, "K": delta_rule, "E": experts, "D": dense,
               "*": attention("*", attn_key), "W": attention("W", window_key)}
     if cfg.remat:  # an E block's chosen experts are saved, never recomputed
         blocks = {kind: jax.checkpoint(fn, policy=ROUTES_SAVED)

@@ -161,14 +161,18 @@ MODEL_REGIONS = (
     "embed", "attn_qkv", "attn_out", "mlp", "ssm", "moe_route", "moe_rows",
     "moe_experts", "moe_shared", "head_loss", "update",
 )
-# Regions INSIDE one of those, opened only where a family's leaves bring the
-# work (a step without such leaves names none of them): ``mla_assemble``, in
+# Regions inside a block, opened only where a family's leaves or blocks bring
+# the work (a step without them names none of them): ``mla_assemble``, in
 # ``attn_qkv``, is what a latent-attention block does between its projections
 # and ``calc_attn`` — the rotation of the rotary channels, the one rotary key
 # laid out for every head, the concatenations into q and k, q's scales.
 # :func:`region_of` gives an instruction to the innermost, so a sum over
-# ``attn_qkv`` holds the projections and not this.
-NESTED_REGIONS = ("mla_assemble",)
+# ``attn_qkv`` holds the projections and not this. ``kda``, in a gated
+# delta-rule block (``models/hybrid.py``, ``K``) between its ``attn_qkv``
+# (the norm and the projections) and its ``attn_out`` (``W_o`` and the
+# residual): the convolutions, the L2 norms, the decay and beta, the
+# recurrence's kernels and the gated output norm.
+NESTED_REGIONS = ("mla_assemble", "kda")
 # ``with profile_scope(REGION.mlp)``: the names as attributes, so that a model
 # file spells none and a misspelt one fails where it is written
 REGION = SimpleNamespace(

@@ -194,8 +194,9 @@ def test_no_pallas_call_site_passes_a_name():
                     # every site goes through the helper
                     assert ast.unparse(node.func) == "_named.pallas_call", (
                         path.name, node.lineno)
-    # 9 FFA + 3 paged decode + 2 block sparse + 2 scan + 2 grouped matmul + helper
-    assert sites == 19
+    # 9 FFA + 3 paged decode + 2 block sparse + 2 scan + 2 grouped matmul
+    # + 2 gated delta rule + helper
+    assert sites == 21
 
 
 def test_the_benchmarks_kernel_report_is_unchanged(bare_env):

@@ -190,7 +190,8 @@ def test_cp_above_one_is_refused_by_name(toy):
     params = jax.eval_shape(
         lambda: hybrid.init_params(mcfg, jax.random.PRNGKey(0)))
     with pytest.raises(NotImplementedError, match=(
-            r"cp = 2: the scan's state .* dispatch permutes")):
+            r"cp = 2: pattern 'ME\*M' has the Mamba-2 scan \('M'\), whose "
+            r"state .* dispatch permutes .*ROADMAP B1")):
         jax.eval_shape(
             lambda p, t: hybrid.forward(p, mcfg, t, key), params,
             jax.ShapeDtypeStruct((512,), jnp.int32))

@@ -177,7 +177,8 @@ def test_kernel_audit_report_round_trip(tmp_path, monkeypatch, capsys):
     ka = agg["kernel_audit"]
     assert ka["runs"] == 1
     # 9 ffa + 3 paged-decode + 2 block-sparse + 2 scan + 2 grouped matmul
-    assert ka["kernels"] == 18
+    # + 2 gated delta rule
+    assert ka["kernels"] == 20
     assert ka["configs"] >= 1
     assert ka["rules_run"] == ["K1", "K2", "K3", "K4", "K5"]
     assert ka["errors_total"] == 0 and ka["warnings_total"] == 0
