@@ -43,12 +43,18 @@ saved states are float32.
 
 Two Pallas calls, bound by ``_named.pallas_call`` so that a device trace
 shows ``magi_kda_fwd_kernel`` and ``magi_kda_bwd_kernel``. The grid is
-``(heads, chunks)``, chunks innermost and sequential, one head's chunk a
-grid step. The backward is a ``custom_vjp``: the forward then also writes
-every chunk's incoming state, and the backward walks the chunks in reverse
-carrying the state's gradient, recomputing the chunk's terms. The running
-sum of ``g`` is the caller's (plain XLA, differentiated by JAX), as are the
-convolution, the L2 norm, the gates and the softplus of the block.
+``(heads // hb, chunks)``, chunks innermost and sequential: a grid step
+holds one chunk of ``hb`` heads (:func:`heads_per_step`), builds the chunk's
+segment masks once for all of them and runs the heads' arithmetic over a
+leading head axis (:func:`_over_heads`: a head's arithmetic under
+``jax.vmap``, each head's ``(C, D)`` slice of the ``(C, hb * D)`` blocks
+stacked). The heads of a step are independent, so the compiler schedules
+their work together. The backward is a ``custom_vjp``: the forward then
+also writes every chunk's incoming state, and the backward walks the chunks
+in reverse carrying the state's gradient, recomputing the chunk's terms.
+The running sum of ``g`` is the caller's (plain XLA, differentiated by
+JAX), as are the convolution, the L2 norm, the gates and the softplus of
+the block.
 """
 
 from __future__ import annotations
@@ -67,6 +73,11 @@ from .ssd import SEG_ROWS
 
 CHUNK = 64
 SUB = 16  # rows of a sub-chunk: its diagonal block is built pair by pair
+# A grid step holds at most four heads' 128 x 128 states: four heads of 128
+# are the fastest step on a v5e (PERF.md, section 6), and the compiler finds
+# no room in VMEM for a forward step of eight heads of 128 or a backward step
+# of two heads of 256.
+STEP_STATE = 4 * 128 * 128
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -151,25 +162,53 @@ def _unit_lower_inverse(a):
     return x
 
 
-def _chunk_terms(q_ref, k_ref, v_ref, g_ref, b_ref, sc_ref, sr_ref):
-    """What forward and backward both need of one grid step, float32."""
-    q, k, v = (r[...].astype(_F32) for r in (q_ref, k_ref, v_ref))
-    g, beta = g_ref[...], b_ref[...]                    # (C, D), (C, 1)
+def _segments(sc_ref, sr_ref):
+    """The masks of one chunk's documents, shared by the heads of a step."""
     sc, sr = sc_ref[...], sr_ref[...]                   # (C, 8), (8, C)
-    c = q.shape[0]
-    seg_c, seg_r, ok = sc[:, 0:1], sr[0:1, :], sc[:, 1:2]
+    c = sc.shape[0]
+    seg_c, seg_r = sc[:, 0:1], sr[0:1, :]
     rows, cols = _iota((c, c), 0), _iota((c, c), 1)
     same = seg_c == seg_r
+    return dict(
+        ok=sc[:, 1:2], strict=same & (rows > cols), incl=same & (rows >= cols),
+        to_end=(seg_c == seg_r[:, c - 1:c]).astype(_F32),   # (C, 1)
+        carry_end=sc[c - 1:c, 1:2],
+    )
+
+
+def _chunk_terms(seg, q, k, v, g, beta):
+    """What forward and backward both need of one head's chunk, float32:
+    ``q``, ``k``, ``v``, ``g`` ``(C, D)``, ``beta`` ``(C, 1)``."""
+    q, k, v = (x.astype(_F32) for x in (q, k, v))
+    c, ok = q.shape[0], seg["ok"]
     g_end = g[c - 1:c]                                  # (1, D)
-    to_end = (seg_c == seg_r[:, c - 1:c]).astype(_F32)  # (C, 1)
     e_g = jnp.exp(g)
     return dict(
         q=q, k=k, v=v, g=g, beta=beta, ok=ok,
-        strict=same & (rows > cols), incl=same & (rows >= cols),
+        strict=seg["strict"], incl=seg["incl"],
         e_g=e_g, kd=k * e_g * ok, qd=q * e_g * ok,
-        e_end=jnp.exp(g_end - g) * to_end,
-        dec=jnp.exp(g_end) * sc[c - 1:c, 1:2],          # (1, D)
+        e_end=jnp.exp(g_end - g) * seg["to_end"],
+        dec=jnp.exp(g_end) * seg["carry_end"],          # (1, D)
     )
+
+
+def _by_head(ref, d):
+    """A ``(C, hb * D)`` block as ``(hb, C, D)``: each head's own lanes."""
+    return jnp.stack([ref[:, lo:lo + d] for lo in range(0, ref.shape[1], d)])
+
+
+def _over_heads(head):
+    """``head``, the arithmetic of one head's chunk, run over a step's heads
+    as a leading axis (the segment masks, its first argument, shared). A
+    head's arithmetic is traced and lowered once whatever ``hb`` is, where
+    ``hb`` copies of it would cost a program's set-up ``hb`` times as much
+    (the jit lets the primal and the ``custom_vjp`` forward share one
+    trace), and the compiler sees each operation over ``hb`` heads."""
+    @partial(jax.jit, static_argnames="scale")
+    def run(seg, *per_head, scale):
+        return jax.vmap(partial(head, scale=scale),
+                        in_axes=(None,) + (0,) * len(per_head))(seg, *per_head)
+    return run
 
 
 def _wy(t, st):
@@ -195,15 +234,26 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sc_ref, sr_ref, *rest,
     def _():
         st_ref[...] = jnp.zeros(st_ref.shape, _F32)
 
-    st = st_ref[...]                                    # S0^T, (D, D)
+    d = st_ref.shape[-1]
+    st = st_ref[...]                                    # S0^T, (hb, D, D)
     if save:
         hin_ref[...] = st
-    t = _chunk_terms(q_ref, k_ref, v_ref, g_ref, b_ref, sc_ref, sr_ref)
+    o, st_end = _fwd_heads(
+        _segments(sc_ref, sr_ref),
+        *(_by_head(r, d) for r in (q_ref, k_ref, v_ref, g_ref)), b_ref[...],
+        st, scale=scale)
+    for h in range(o.shape[0]):
+        o_ref[:, h * d:(h + 1) * d] = o[h].astype(o_ref.dtype)
+    st_ref[...] = st_end
+
+
+def _fwd_head(seg, q, k, v, g, beta, st, *, scale):
+    """``(o, S_C^T)`` of one head's chunk from its blocks and ``S0^T``."""
+    t = _chunk_terms(seg, q, k, v, g, beta)
     _, _, _, vp, p = _wy(t, st)
     o = scale * (_dot(t["qd"], st, _NT) + _dot(p, vp))
-    o_ref[...] = o.astype(o_ref.dtype)
     ke = t["k"] * t["e_end"]
-    st_ref[...] = st * t["dec"] + _dot(vp, ke, _TN)
+    return o, st * t["dec"] + _dot(vp, ke, _TN)
 
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sc_ref, sr_ref, do_ref,
@@ -213,12 +263,29 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sc_ref, sr_ref, do_ref,
     def _():
         dst_ref[...] = jnp.zeros(dst_ref.shape, _F32)
 
-    t = _chunk_terms(q_ref, k_ref, v_ref, g_ref, b_ref, sc_ref, sr_ref)
-    st, dst1 = hin_ref[...], dst_ref[...]               # S0^T, dS_C^T
+    d = dst_ref.shape[-1]
+    dq, dk, dv, dg, db, dst0 = _bwd_heads(
+        _segments(sc_ref, sr_ref),
+        *(_by_head(r, d) for r in (q_ref, k_ref, v_ref, g_ref, do_ref)),
+        b_ref[...], hin_ref[...], dst_ref[...], scale=scale)
+    dst_ref[...] = dst0
+    for h in range(dq.shape[0]):
+        lanes = slice(h * d, (h + 1) * d)
+        dq_ref[:, lanes] = dq[h].astype(dq_ref.dtype)
+        dk_ref[:, lanes] = dk[h].astype(dk_ref.dtype)
+        dv_ref[:, lanes] = dv[h].astype(dv_ref.dtype)
+        dg_ref[:, lanes] = dg[h]
+    db_ref[...] = db
+
+
+def _bwd_head(seg, q, k, v, g, do, beta, st, dst1, *, scale):
+    """``(dq, dk, dv, dg, dbeta, dS0^T)`` of one head's chunk from its
+    blocks, the output's cotangent, ``S0^T`` and ``dS_C^T``."""
+    t = _chunk_terms(seg, q, k, v, g, beta)
     q, k, v, g, beta = t["q"], t["k"], t["v"], t["g"], t["beta"]
     m_kk, tt, w, vp, p = _wy(t, st)
     c = q.shape[0]
-    dos = scale * do_ref[...].astype(_F32)
+    dos = scale * do.astype(_F32)
     # o = scale (qd S0 + P v')
     dqd = _dot(dos, st)
     dst0 = _dot(dos, t["qd"], _TN)
@@ -257,17 +324,15 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, sc_ref, sr_ref, do_ref,
     dq = dq + dqd * t["e_g"] * t["ok"]
     dg = dg + dkd * t["kd"] + dqd * t["qd"]
     dg = dg + jnp.where(_iota(dg.shape, 0) == c - 1, dg_end, 0.0)
-    dst_ref[...] = dst0
-    dq_ref[...] = dq.astype(dq_ref.dtype)
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
-    dg_ref[...] = dg
-    db_ref[...] = db
+    return dq, dk, dv, dg, db, dst0
 
 
-def _specs(d, n_chunks, reverse):
-    """BlockSpecs for grid step ``(head, i)``: the chunk is ``i``, or
-    ``n_chunks - 1 - i`` walking backwards."""
+_fwd_heads, _bwd_heads = _over_heads(_fwd_head), _over_heads(_bwd_head)
+
+
+def _specs(d, hb, n_chunks, reverse):
+    """BlockSpecs for grid step ``(head group, i)`` of ``hb`` heads: the
+    chunk is ``i``, or ``n_chunks - 1 - i`` walking backwards."""
     def chunk(i):
         return n_chunks - 1 - i if reverse else i
 
@@ -275,12 +340,20 @@ def _specs(d, n_chunks, reverse):
         return pl.BlockSpec(block, index, memory_space=pltpu.VMEM)
 
     return dict(
-        wide=vm((CHUNK, d), lambda h, i: (chunk(i), h)),       # (T, H*D)
-        beta=vm((None, None, CHUNK, 1), lambda h, i: (h, chunk(i), 0, 0)),
+        wide=vm((CHUNK, hb * d), lambda h, i: (chunk(i), h)),  # (T, H*D)
+        beta=vm((hb, None, CHUNK, 1), lambda h, i: (h, chunk(i), 0, 0)),
         sc=vm((None, CHUNK, SEG_ROWS), lambda h, i: (chunk(i), 0, 0)),
         sr=vm((None, SEG_ROWS, CHUNK), lambda h, i: (chunk(i), 0, 0)),
-        state=vm((None, None, d, d), lambda h, i: (chunk(i), h, 0, 0)),
+        state=vm((None, hb, d, d), lambda h, i: (chunk(i), h, 0, 0)),
     )
+
+
+def heads_per_step(heads: int, d: int) -> int:
+    """Heads a grid step: the largest divisor of ``heads`` whose ``D x D``
+    states together hold at most :data:`STEP_STATE` numbers, 1 at the
+    least."""
+    return max(hb for hb in range(1, heads + 1)
+               if heads % hb == 0 and (hb == 1 or hb * d * d <= STEP_STATE))
 
 
 def _compiler_params():
@@ -296,7 +369,8 @@ def _interpret() -> bool:
 
 def _fwd_call(q2, k2, v2, g2, b4, sc, sr, d: int, save: bool):
     heads, n_chunks = b4.shape[:2]
-    s = _specs(d, n_chunks, reverse=False)
+    hb = heads_per_step(heads, d)
+    s = _specs(d, hb, n_chunks, reverse=False)
     out_specs = [s["wide"]]
     out_shape = [jax.ShapeDtypeStruct(q2.shape, q2.dtype)]
     if save:
@@ -304,10 +378,10 @@ def _fwd_call(q2, k2, v2, g2, b4, sc, sr, d: int, save: bool):
         out_shape.append(jax.ShapeDtypeStruct((n_chunks, heads, d, d), _F32))
     return _named.pallas_call(
         partial(_kda_fwd_kernel, scale=d ** -0.5, save=save),
-        grid=(heads, n_chunks),
+        grid=(heads // hb, n_chunks),
         in_specs=[s["wide"]] * 4 + [s["beta"], s["sc"], s["sr"]],
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((d, d), _F32)],
+        scratch_shapes=[pltpu.VMEM((hb, d, d), _F32)],
         interpret=_interpret(), compiler_params=_compiler_params(),
     )(q2, k2, v2, g2, b4, sc, sr)
 
@@ -329,10 +403,11 @@ def _kda_core_fwd(q2, k2, v2, g2, b4, sc, sr, d):
 def _kda_core_bwd(d, res, do):
     q2, k2, v2, g2, b4, sc, sr, hin = res
     heads, n_chunks = b4.shape[:2]
-    s = _specs(d, n_chunks, reverse=True)
+    hb = heads_per_step(heads, d)
+    s = _specs(d, hb, n_chunks, reverse=True)
     dq, dk, dv, dg, db = _named.pallas_call(
         partial(_kda_bwd_kernel, scale=d ** -0.5),
-        grid=(heads, n_chunks),
+        grid=(heads // hb, n_chunks),
         in_specs=[s["wide"]] * 4 + [s["beta"], s["sc"], s["sr"], s["wide"],
                                     s["state"]],
         out_specs=[s["wide"]] * 4 + [s["beta"]],
@@ -343,7 +418,7 @@ def _kda_core_bwd(d, res, do):
             jax.ShapeDtypeStruct(g2.shape, _F32),
             jax.ShapeDtypeStruct(b4.shape, _F32),
         ],
-        scratch_shapes=[pltpu.VMEM((d, d), _F32)],
+        scratch_shapes=[pltpu.VMEM((hb, d, d), _F32)],
         interpret=_interpret(), compiler_params=_compiler_params(),
     )(q2, k2, v2, g2, b4, sc, sr, do.astype(q2.dtype), hin)
     return dq, dk, dv, dg, db, jnp.zeros_like(sc), jnp.zeros_like(sr)
@@ -352,13 +427,14 @@ def _kda_core_bwd(d, res, do):
 _kda_core.defvjp(_kda_core_fwd, _kda_core_bwd)
 
 
-def _record_plan(seg_ids, *, tokens: int, heads: int) -> None:
+def _record_plan(seg_ids, *, tokens: int, heads: int, hb: int) -> None:
     ids = np.asarray(seg_ids)
     rows = np.arange(ids.shape[0])
     starts = (ids == rows) & (rows % CHUNK != 0)
     telemetry.record_event(
         "kda_plan", chunk=CHUNK, sub_chunk=SUB, heads=heads,
-        chunks=tokens // CHUNK, resets_inside_chunks=int(starts.sum()))
+        heads_per_step=hb, chunks=tokens // CHUNK,
+        resets_inside_chunks=int(starts.sum()))
 
 
 def kda_scan(q, k, v, g, beta, seg_rows) -> jax.Array:
@@ -389,10 +465,12 @@ def kda_scan(q, k, v, g, beta, seg_rows) -> jax.Array:
                            constant_values=-1.0).at[1, t:].set(0.0)
     tp = t + pad
     n = tp // CHUNK
+    hb = heads_per_step(h, d)
     registry.note_choice("kda", (tp, h, d, CHUNK), "pallas_chunked", "default")
+    registry.note_choice("kda_heads", (h, d), f"heads{hb}", "shape_rule")
     if telemetry.enabled():
         jax.debug.callback(
-            partial(_record_plan, tokens=tp, heads=h), seg_rows[0])
+            partial(_record_plan, tokens=tp, heads=h, hb=hb), seg_rows[0])
     g2 = jnp.cumsum(g.astype(_F32).reshape(n, CHUNK, h * d), axis=1)
     b4 = beta.astype(_F32).reshape(n, CHUNK, h).transpose(2, 0, 1)[..., None]
     sr = seg_rows.reshape(SEG_ROWS, n, CHUNK).transpose(1, 0, 2)
@@ -404,8 +482,9 @@ def kda_scan(q, k, v, g, beta, seg_rows) -> jax.Array:
 
 # Contracts of the two pallas_call sites for analysis/kernel_check.py, as
 # ``ssd.py`` declares its own: a scan is no accumulate-and-flush kernel;
-# every grid step writes its own output blocks and the scratch is the state
-# carried from chunk to chunk (zeroed at a head's first chunk). The carried
+# every grid step writes its own output blocks (every lane of a head group's
+# chunk) and the scratch is the states of the step's ``hb`` heads carried
+# from chunk to chunk (zeroed at a head group's first chunk). The carried
 # state is held to the recurrence by tests/test_attn/test_kda.py.
 PALLAS_CONTRACTS: dict = {
     "_kda_fwd_kernel": dict(

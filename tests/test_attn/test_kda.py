@@ -33,6 +33,9 @@ CASES = {
     "beta_near_two_steep_decay": dict(lens=(192,), heads=1, beta2=True,
                                       steep=True),
 }
+# eight heads: the rule takes four a grid step, two groups; two chunks, a
+# document start at 40 inside the first
+GROUPED = dict(lens=(40, 88), heads=8)
 
 
 def _inputs(lens, heads, beta2=False, steep=False, seed=0):
@@ -55,13 +58,20 @@ def _inputs(lens, heads, beta2=False, steep=False, seed=0):
     return (q, k, v, g, beta), do, starts
 
 
+@jax.jit
+def _scan_vjp(q, k, v, g, beta, do, rows):
+    """The output and the five gradients of :data:`NAMES`; one compile a
+    shape, shared by the tests of this file."""
+    out, pull = jax.vjp(lambda *a: kda.kda_scan(*a, rows), q, k, v, g, beta)
+    return (out, *pull(do))
+
+
 @functools.lru_cache(maxsize=None)
 def _case(name):
     """``(program, reference)``, each ``{name: array}`` of :data:`NAMES`."""
     (q, k, v, g, beta), do, starts = _inputs(**CASES[name])
     rows = ssd.segment_rows(jnp.asarray(starts), chunk=kda.CHUNK)
-    out, pull = jax.vjp(lambda *a: kda.kda_scan(*a, rows), q, k, v, g, beta)
-    got = dict(zip(NAMES, (out, *pull(do))))
+    got = dict(zip(NAMES, _scan_vjp(q, k, v, g, beta, do, rows)))
     ref = reference_solar_open2.timed(
         q, k, v, g, beta, do, jnp.asarray(starts == np.arange(len(starts))))
     return jax.device_get(got), jax.device_get(ref)
@@ -102,3 +112,32 @@ def test_head_dim_not_a_lane_multiple_is_refused():
     with pytest.raises(ValueError, match="head_dim 64"):
         kda.kda_scan(x, x, x, jnp.zeros((64, 1, 64)), jnp.zeros((64, 1)),
                      ssd.segment_rows(jnp.zeros((64,), jnp.int32), chunk=64))
+
+
+def test_a_head_in_a_group_is_that_head_alone():
+    """Each of eight heads, four a grid step, reads what it reads through
+    ``kda_scan`` alone (one head, one a step): the output and the five
+    gradients, across a document start inside the first chunk and the
+    states each head carries into the next. In and out in float32: a bf16
+    result would flip a rounding where the grouped form orders a sum
+    otherwise."""
+    (q, k, v, g, beta), do, starts = _inputs(**GROUPED)
+    q, k, v, do = (x.astype(jnp.float32) for x in (q, k, v, do))
+    rows = ssd.segment_rows(jnp.asarray(starts), chunk=kda.CHUNK)
+    together = jax.device_get(_scan_vjp(q, k, v, g, beta, do, rows))
+    assert registry.last_choice("kda_heads") == "heads4"
+    assert registry.last_source("kda_heads") == "shape_rule"
+    for h in range(GROUPED["heads"]):
+        alone = jax.device_get(_scan_vjp(
+            *(x[:, h:h + 1] for x in (q, k, v, g, beta, do)), rows))
+        for name, got, ref in zip(NAMES, together, alone):
+            assert _rel(got[:, h:h + 1], ref) <= 1e-6, (h, name)
+
+
+@pytest.mark.parametrize("heads, d, hb", [
+    (64, 128, 4), (6, 128, 3), (2, 128, 2), (1, 128, 1), (9, 128, 3),
+    # two heads of 256 hold the states of eight of 128
+    (64, 256, 1),
+])
+def test_heads_a_grid_step(heads, d, hb):
+    assert kda.heads_per_step(heads, d) == hb

@@ -1,9 +1,10 @@
 """The chip's compiler takes the gated delta rule's two bodies at the Solar
 cell's shapes, and the benchmark sees them under their own names.
 
-``kernels/kda.py`` is one head's chunk a grid step, every intermediate in
-float32 with matmuls at ``HIGHEST`` and its pair matrices and forward
-substitution unrolled; this file lowers the forward and the backward for one
+``kernels/kda.py`` runs a chunk of ``kda.heads_per_step`` heads a grid step
+(4 of the cell's 64) over a leading head axis, every intermediate in float32
+with matmuls at ``HIGHEST`` and its pair matrices and forward substitution
+unrolled; this file lowers the forward and the backward for one
 chip of a described ``v5e:2x2`` from the CPU, as
 ``test_grouped_matmul_compiles.py`` does (VMEM and SMEM are checked there
 and cost no chip time), at the cell's 16384 tokens and 64 heads of 128.
@@ -23,6 +24,22 @@ from magiattention_tpu.kernels import kda
 
 TOKENS, HEADS, D = 16384, 64, 128
 BODIES = {"_kda_fwd_kernel", "_kda_bwd_kernel"}
+
+
+def _grids(jaxpr) -> dict:
+    """``{kernel body name: grid}`` of every ``pallas_call`` in a jaxpr."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["jaxpr"].debug_info.func_name
+            found[name] = tuple(eqn.params["grid_mapping"].grid)
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                inner = getattr(sub, "jaxpr", sub)
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found.update(_grids(inner))
+    return found
 
 
 @pytest.fixture()
@@ -62,9 +79,14 @@ def test_both_bodies_compile_for_v5e_under_the_names_the_benchmark_reads(
         (shape, jnp.bfloat16), (shape, jnp.bfloat16), (shape, jnp.bfloat16),
         (shape, jnp.float32), ((TOKENS, HEADS), jnp.float32),
         ((8, TOKENS), jnp.float32))]
+    jaxpr = fn.trace(*args).jaxpr
     # neither body interpreted, in the traced program
-    assert family_llama.pallas_kernels(fn.trace(*args).jaxpr) == dict.fromkeys(
-        BODIES, False)
+    assert family_llama.pallas_kernels(jaxpr) == dict.fromkeys(BODIES, False)
+    # each call a grid of (head groups, chunks), hb heads a step by the rule
+    hb = kda.heads_per_step(HEADS, D)
+    assert hb == 4
+    assert _grids(jaxpr.jaxpr) == dict.fromkeys(
+        BODIES, (HEADS // hb, TOKENS // kda.CHUNK))
     compiled = fn.lower(*args).compile()  # fits VMEM and SMEM, or raises
     calls = [
         trace_reduce.parse_hlo(line.strip().removeprefix("ROOT "))
