@@ -427,6 +427,7 @@ PIN_KEYS: dict[str, tuple[str, ...]] = {
     "nsa_slc": ("MAGI_ATTENTION_BACKEND_NSA_SLC",),
     # one backend each and no pin: the call site notes its choice
     "ssd": (),
+    "ssd_heads": (),  # a rule, no key
     "moe_grouped": (),
     "moe_grouped_tiles": (),
     "moe_row_buffer": (),

@@ -54,19 +54,45 @@ LAYOUTS = {
 }
 
 
-@pytest.mark.parametrize("lens", LAYOUTS.values(), ids=LAYOUTS.keys())
-def test_forward_is_the_recurrence(lens):
-    args, starts = make(lens)
+# (layout, heads, groups, the bound lowered to this many heads' step): the
+# layouts at two groups of four heads, one grid step a group; a group of
+# four over two grid steps; groups of a single head
+CASES = [pytest.param(lens, 8, 2, None, id=name)
+         for name, lens in LAYOUTS.items()] + [
+    pytest.param(LAYOUTS["1-127-128-129-300"], 8, 2, 2,
+                 id="two-grid-steps-a-group"),
+    pytest.param(LAYOUTS["1-127-128-129-300"], 4, 4, None,
+                 id="one-head-a-group"),
+]
+
+
+def heads_a_step(heads, groups, lowered, monkeypatch, p=64, n=32):
+    """The heads a grid step takes, the rule's bound lowered to ``lowered``
+    heads of the cases' shape where it is given."""
+    if lowered:
+        monkeypatch.setattr(ssd, "STEP_WORDS", lowered * (
+            3 * ssd.CHUNK ** 2 + (ssd.CHUNK + n) * (ssd.CHUNK + p)))
+    return lowered or heads // groups
+
+
+@pytest.mark.parametrize("lens,heads,groups,lowered", CASES)
+def test_forward_is_the_recurrence(lens, heads, groups, lowered, monkeypatch):
+    hb = heads_a_step(heads, groups, lowered, monkeypatch)
+    args, starts = make(lens, heads=heads, groups=groups)
     y = ssd.ssd_scan(*args, ssd.segment_rows(starts))
     assert y.shape == args[0].shape
     # the kernel's matmuls take bf16 operands: one rounding is 2e-3
     assert rel(y, recurrence(*args, starts)) < 5e-3
     assert registry.last_choice("ssd") == "pallas_chunked"
+    assert registry.last_choice("ssd_heads") == f"heads{hb}"
+    assert registry.last_source("ssd_heads") == "shape_rule"
 
 
-@pytest.mark.parametrize("lens", LAYOUTS.values(), ids=LAYOUTS.keys())
-def test_backward_is_the_recurrences_gradient(lens):
-    args, starts = make(lens, seed=1)
+@pytest.mark.parametrize("lens,heads,groups,lowered", CASES)
+def test_backward_is_the_recurrences_gradient(
+        lens, heads, groups, lowered, monkeypatch):
+    hb = heads_a_step(heads, groups, lowered, monkeypatch)
+    args, starts = make(lens, heads=heads, groups=groups, seed=1)
     seg = ssd.segment_rows(starts)
     weight = jax.random.normal(jax.random.PRNGKey(7), args[0].shape)
     got = jax.grad(
@@ -80,6 +106,21 @@ def test_backward_is_the_recurrences_gradient(lens):
         # bf16 operands; ``a``'s gradient is 8 numbers, each a sum with
         # cancellation over every token
         assert rel(g, r) < (2e-2 if name == "a" else 6e-3), name
+    assert registry.last_choice("ssd_heads") == f"heads{hb}"
+
+
+@pytest.mark.parametrize("heads,p,n,hb", [
+    (8, 64, 128, 8),     # the hybrid cell's group: one step
+    (16, 64, 128, 8),    # wider than the bound: two steps a group
+    (128, 64, 128, 8),   # one group of 128 heads: sixteen steps
+    (80, 64, 128, 10),   # one group of 80
+    (12, 64, 128, 12),
+    (8, 256, 256, 4),    # wide heads and states: fewer a step
+    (30, 64, 128, 10),   # 15 would fit, but 960 lanes are no whole vregs
+    (1, 64, 128, 1),     # a single head, 64 lanes, is the whole group
+])
+def test_heads_a_grid_step(heads, p, n, hb):
+    assert ssd.heads_per_step(heads, ssd.CHUNK, p, n) == hb
 
 
 def test_a_document_start_cuts_the_state_and_nothing_else():
